@@ -21,6 +21,8 @@ import argparse
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -30,6 +32,7 @@ def main() -> None:
                     help="comma list: tables,seq,piggyback,dist,randomx,"
                          "kernels,hotpath,comm,d2,pipeline,serve,roofline")
     args = ap.parse_args()
+    use_compile_cache()
     fast = not args.full
     from benchmarks import (bench_comm, bench_d2, bench_distributed,
                             bench_hotpath, bench_kernels, bench_piggyback,

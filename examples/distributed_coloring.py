@@ -35,9 +35,9 @@ for preset in (presets.speed(), presets.quality(x=10)):
 
 # real sharded execution if the process has multiple devices
 if len(jax.devices()) >= P:
-    from repro.compat import make_mesh
+    from jax.sharding import AxisType
     from repro.core import ColorConfig, color_graph_sharded, compute_order, ordering
-    mesh = make_mesh((P,), ("workers",))
+    mesh = jax.make_mesh((P,), ("workers",), axis_types=(AxisType.Auto,))
     order = compute_order(pg, ordering.INTERNAL_FIRST)
     view, stats = color_graph_sharded(pg, order,
                                       ColorConfig(max_colors=1024,

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 AXIS = "workers"        # default shard (graph-partition) mesh axis
 BATCH_AXIS = "batch"    # graph-batch mesh axis of 2D batch×shard meshes
@@ -265,8 +264,10 @@ def run_sharded(fn, mesh, sharded_args: tuple, broadcast_args: tuple = (),
 
     in_specs = tuple(P(axis) for _ in sharded_args) + tuple(
         P() for _ in broadcast_args)
-    return compat.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(axis), check=False)(
+    # check_vma=False: loop carries start replicated and become
+    # worker-varying after the first exchange
+    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(axis), check_vma=False)(
                                 *sharded_args, *broadcast_args)
 
 
@@ -304,8 +305,8 @@ def run_sharded_many(fn, mesh, sharded_args: tuple, lane_args: tuple = (),
 
     in_specs = tuple(P(axis, baxis) for _ in sharded_args) + tuple(
         P(baxis) for _ in lane_args)
-    return compat.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(axis, baxis), check=False)(
+    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(axis, baxis), check_vma=False)(
                                 *sharded_args, *lane_args)
 
 

@@ -644,6 +644,18 @@ def pipeline_sim(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
     (adaptive stop included).  ``pipeline_sharded`` is the
     bitwise-identical ``workers``-mesh variant.
     """
+    fn, args = pipeline_sim_program(pg, order, cfg, marked=marked,
+                                    color_key=color_key,
+                                    recolor_key=recolor_key)
+    return _pipeline_result(*fn(*args))
+
+
+def pipeline_sim_program(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
+                         marked=None, color_key=None, recolor_key=None):
+    """The cached jitted program ``pipeline_sim`` dispatches, with its
+    arguments: ``(fn, args)``.  ``fn.lower(*args).compile()`` compiles
+    exactly what ``pipeline_sim`` runs (compile timing, HLO inspection,
+    compiling for a described device from the arguments' shapes)."""
     assert cfg.color is not None, "pipeline_sim needs cfg.color"
     cfg = resolve_pipeline_cfg(pg, cfg)
     arrs = _pipeline_arrays(pg, cfg)
@@ -658,8 +670,7 @@ def pipeline_sim(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
             lambda arrs, order, ck, rk: run_sim(fn, P, (arrs, order),
                                                 (ck, rk))))
 
-    out = _PROGRAMS.get(sig, build)(arrs, jnp.asarray(order), ck, rk)
-    return _pipeline_result(*out)
+    return _PROGRAMS.get(sig, build), (arrs, jnp.asarray(order), ck, rk)
 
 
 def pipeline_sharded(pg: PartitionedGraph, order, cfg: PipelineConfig, mesh,

@@ -3,22 +3,30 @@
 The recoloring step's compute kernel is: for a tile of vertices, build the
 forbidden-color set from neighbour colors and pick a color (First Fit,
 Random-X Fit §3.2, or Staggered First Fit via a per-row offset operand).
-On TPU we tile vertices onto VPU lanes and keep the
-forbidden set as a uint32 *bitset* — ``max_colors / 32`` words per vertex —
-resident in VMEM/VREGs:
+On TPU we tile vertices onto the 128 VPU lanes and keep the forbidden set
+as a uint32 *bitset* — ``max_colors / 32`` words per vertex, one word per
+sublane row — resident in VMEM/VREGs:
 
-  HBM  : neighbour-color tile (TILE_V, MAXD) int32, streamed per grid step
-  VMEM : (TILE_V, MAXD) input block + (TILE_V, W) bitset working set
-  VPU  : MAXD-step reduction of one-hot word ORs; find-first-zero via
-         bit tricks + population_count (no scalar loops over vertices)
+  HBM  : transposed neighbour-color tile (MAXD, V) int32 — one ELL slot per
+         row, one vertex per lane — streamed as (MAXD, TILE_V) blocks
+  VMEM : (MAXD, TILE_V) input block + (W, TILE_V) bitset working set
+  VPU  : MAXD-step reduction of one-hot word ORs (slot ``d`` is a dynamic
+         *sublane* read); find-first-zero via masked sublane reductions +
+         population_count (no scalar loops over vertices)
 
 This is the TPU-native rethink of the paper's per-vertex sequential scan:
 the sequential dependency *within* a color class does not exist (the class is
 an independent set), so the whole tile colors in parallel — exactly why
 synchronous recoloring suits wide SIMD hardware.
 
-Grid: (ceil(V / TILE_V),). MAXD is the (padded) max degree of the tile's
-vertices. Typical VMEM use at TILE_V=256, MAXD=128, W=32: ~160 KiB.
+Every construct here is one Mosaic lowers: no scatters, no dynamic
+lane-axis slices, no ``take_along_axis`` and no reductions over unsigned
+integers (word picks go through an int32 bitcast).  Per-vertex operands are
+``(1, V)`` rows so every block is 2-D and lane-aligned.
+
+Grid: (V / TILE_V,).  VMEM of the widest kernel (distance-2 conflict, four
+(MAXD, TILE_V) int32 tiles, double-buffered) is ``2 * 4 * MAXD * TILE_V * 4``
+bytes: 2.6 MiB at MAXD=678, under v5e's 16 MiB default scoped VMEM.
 """
 from __future__ import annotations
 
@@ -29,47 +37,68 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-TILE_V = 256  # vertices per grid step; multiple of 8 (f32 sublane) x 128 lanes
+TILE_V = 128  # vertices per grid step: one vreg of lanes
 
 _U1 = np.uint32(1)
 _FULL = np.uint32(0xFFFFFFFF)
+_I32_MIN = np.int32(np.iinfo(np.int32).min)
 
 
-def _forbidden_words(nbr_ref, n_words: int) -> jnp.ndarray:
-    """(TILE_V, MAXD) neighbour colors -> (TILE_V, W) forbidden bitset."""
-    tile_v, maxd = nbr_ref.shape
-    words = jnp.zeros((tile_v, n_words), jnp.uint32).at[:, 0].set(_U1)
-    warange = jnp.arange(n_words, dtype=jnp.int32)[None, :]
+def _rows_iota(shape) -> jnp.ndarray:
+    """Row index of every element of a 2-D (rows, V) array."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0)
 
-    def body(d, words):
-        c = nbr_ref[:, d]                                   # (TILE_V,)
-        ok = (c > 0) & (c < n_words * 32)
-        cc = jnp.clip(c, 0, n_words * 32 - 1)
-        w = (cc >> 5)[:, None]                              # (TILE_V, 1)
-        bit = (_U1 << (cc & 31).astype(jnp.uint32))[:, None]
-        hit = (warange == w) & ok[:, None]
-        return words | jnp.where(hit, bit, jnp.uint32(0))
 
-    return jax.lax.fori_loop(0, maxd, body, words)
+def _bit(c: jnp.ndarray) -> jnp.ndarray:
+    """uint32 word with bit ``c mod 32`` set."""
+    return _U1 << (c & 31).astype(jnp.uint32)
+
+
+def forbidden_words(slots, n_words: int, v: int) -> jnp.ndarray:
+    """OR neighbour colors into a (W, V) forbidden bitset.
+
+    ``slots`` is a sequence of ``(row, maxd)``: ``row(d)`` returns ELL slot
+    ``d``'s neighbour colors as a (1, V) row.  Bit 0 (color 0, uncolored)
+    is always set; colors outside ``[1, 32W)`` are ignored.
+    """
+    wrow = _rows_iota((n_words, v))
+    words = jnp.where(wrow == 0, _U1, jnp.uint32(0))
+
+    def body(row, d, words):
+        c = row(d)                                          # (1, V)
+        hit = (wrow == (c >> 5)) & (c > 0) & (c < n_words * 32)
+        return words | jnp.where(hit, _bit(c), jnp.uint32(0))
+
+    for row, maxd in slots:
+        words = jax.lax.fori_loop(0, maxd, functools.partial(body, row),
+                                  words)
+    return words
+
+
+def _pick_row(vals: jnp.ndarray, rows: jnp.ndarray, idx: jnp.ndarray):
+    """``vals[idx[v], v]`` of an int32 (rows, V) array as a (1, V) row."""
+    sel = jnp.where(rows == idx, vals, _I32_MIN)
+    return jnp.max(sel, axis=0, keepdims=True)
 
 
 def _find_first_zero(words: jnp.ndarray) -> jnp.ndarray:
-    """(TILE_V, W) bitset -> (TILE_V,) lowest zero bit below the sentinel.
+    """(W, V) bitset -> (1, V) lowest zero bit below the sentinel.
 
     Bit ``32W-1`` is reserved as a saturation sentinel (never reported free),
     so a result of ``32W-1`` unambiguously means "no permissible color" —
     mirrors ``core.selection.find_first_zero``.
     """
-    tile_v, n_words = words.shape
-    top = jnp.where(jnp.arange(n_words, dtype=jnp.int32)[None, :]
-                    == n_words - 1, ~jnp.uint32(0x7FFFFFFF), jnp.uint32(0))
+    n_words, v = words.shape
+    wrow = _rows_iota((n_words, v))
+    top = jnp.where(wrow == n_words - 1, ~jnp.uint32(0x7FFFFFFF),
+                    jnp.uint32(0))
     free = ~(words | top)
-    has = free != jnp.uint32(0)
-    iota = jnp.broadcast_to(jnp.arange(n_words, dtype=jnp.int32)[None, :],
-                            (tile_v, n_words))
-    widx = jnp.min(jnp.where(has, iota, n_words), axis=1)
+    widx = jnp.min(jnp.where(free != 0, wrow, n_words), axis=0,
+                   keepdims=True)
     widx_c = jnp.minimum(widx, n_words - 1)
-    word = jnp.take_along_axis(free, widx_c[:, None], axis=1)[:, 0]
+    word = jax.lax.bitcast_convert_type(
+        _pick_row(jax.lax.bitcast_convert_type(free, jnp.int32), wrow,
+                  widx_c), jnp.uint32)
     lsb = word & (~word + _U1)
     bit = jax.lax.population_count(lsb - _U1).astype(jnp.int32)
     out = widx_c * 32 + bit
@@ -77,200 +106,134 @@ def _find_first_zero(words: jnp.ndarray) -> jnp.ndarray:
 
 
 def _set_bits(words: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    """Set per-row bit `c` in the (TILE_V, W) bitset."""
-    n_words = words.shape[1]
-    warange = jnp.arange(n_words, dtype=jnp.int32)[None, :]
-    w = (c >> 5)[:, None]
-    bit = (_U1 << (c & 31).astype(jnp.uint32))[:, None]
-    return words | jnp.where(warange == w, bit, jnp.uint32(0))
+    """Set per-vertex bit ``c`` (1, V) in the (W, V) bitset."""
+    wrow = _rows_iota(words.shape)
+    return words | jnp.where(wrow == (c >> 5), _bit(c), jnp.uint32(0))
 
 
 def _mask_below_rows(words: jnp.ndarray, off: jnp.ndarray) -> jnp.ndarray:
-    """Copy of the (V, W) bitset with all bits < off[v] additionally set."""
-    n_words = words.shape[1]
-    warange = jnp.arange(n_words, dtype=jnp.int32)[None, :]
-    widx = (off >> 5)[:, None]
-    rem = (off & 31).astype(jnp.uint32)[:, None]
-    partial = jnp.where(warange == widx, (_U1 << rem) - _U1, jnp.uint32(0))
-    return words | jnp.where(warange < widx, _FULL, jnp.uint32(0)) | partial
+    """Copy of the (W, V) bitset with all bits < off[v] additionally set."""
+    wrow = _rows_iota(words.shape)
+    widx = off >> 5
+    partial = jnp.where(wrow == widx, _bit(off) - _U1, jnp.uint32(0))
+    return words | jnp.where(wrow < widx, _FULL, jnp.uint32(0)) | partial
 
 
 def select_from_words(words, rand_u32, offset, *, x: int, staggered: bool):
-    """(V, W) forbidden bitset -> (V,) colors.
+    """(W, V) forbidden bitset -> (1, V) colors.
 
     The one tile-parallel selection routine: First Fit (x=0), Random-X Fit
     (x>0, uniform among the X smallest free colors via ``rand_u32``) and
-    Staggered First Fit (first fit from per-row ``offset``, wrapping to plain
-    first fit when exhausted). Shared verbatim by the Pallas tile kernel and
-    the vectorized XLA backend in ``kernels.ops`` — they differ only in how
-    tiles reach the VPU, never in the math.
+    Staggered First Fit (first fit from per-vertex ``offset``, wrapping to
+    plain first fit when exhausted).  ``rand_u32``/``offset`` are (1, V).
+    Shared verbatim by the Pallas tile kernel and the vectorized XLA backend
+    in ``kernels.ops`` — they differ only in how tiles reach the VPU, never
+    in the math.
     """
     if staggered:
         c = _find_first_zero(_mask_below_rows(words, offset))
-        full = c >= words.shape[1] * 32 - 1
+        full = c >= words.shape[0] * 32 - 1
         return jnp.where(full, _find_first_zero(words), c)
     if x == 0:
         return _find_first_zero(words)
-    mc = words.shape[1] * 32
-    tile_v = words.shape[0]
-    cands = jnp.full((tile_v, x), mc - 1, jnp.int32)
+    mc = words.shape[0] * 32
+    krow = _rows_iota((x, words.shape[1]))
+    cands = jnp.full(krow.shape, mc - 1, jnp.int32)
 
     def body(k, carry):
         words, cands = carry
         c = _find_first_zero(words)
-        cands = cands.at[:, k].set(c)
-        return _set_bits(words, c), cands
+        return _set_bits(words, c), jnp.where(krow == k, c, cands)
 
     _, cands = jax.lax.fori_loop(0, x, body, (words, cands))
-    n_free = jnp.sum((cands < mc - 1).astype(jnp.uint32), axis=1)
-    n_free = jnp.maximum(n_free, _U1)
+    n_free = jnp.sum((cands < mc - 1).astype(jnp.int32), axis=0,
+                     keepdims=True)
+    n_free = jnp.maximum(n_free, 1).astype(jnp.uint32)
     idx = (rand_u32 % n_free).astype(jnp.int32)
-    return jnp.take_along_axis(cands, idx[:, None], axis=1)[:, 0]
+    return _pick_row(cands, krow, idx)
 
 
-def _select_kernel(nbr_ref, active_ref, rand_ref, off_ref, out_ref, *,
-                   n_words: int, x: int, staggered: bool):
-    """x == 0 -> First Fit; x > 0 -> Random-X Fit; staggered -> offset FF."""
-    words = _forbidden_words(nbr_ref[...], n_words)
+def _select_kernel(*refs, n_tiles: int, n_words: int, x: int,
+                   staggered: bool):
+    """x == 0 -> First Fit; x > 0 -> Random-X Fit; staggered -> offset FF.
+
+    ``n_tiles`` neighbour tiles (1: distance 1; 2: one-hop + two-hop) are
+    ORed into one forbidden bitset.
+    """
+    tiles = refs[:n_tiles]
+    active_ref, rand_ref, off_ref, out_ref = refs[n_tiles:]
+    slots = [(lambda d, t=t: t[pl.ds(d, 1), :], t.shape[0]) for t in tiles]
+    words = forbidden_words(slots, n_words, out_ref.shape[1])
     color = select_from_words(words, rand_ref[...], off_ref[...], x=x,
                               staggered=staggered)
-    out_ref[...] = jnp.where(active_ref[...] != 0, color, 0).astype(jnp.int32)
+    out_ref[...] = jnp.where(active_ref[...] != 0, color, 0)
 
 
-def _select_kernel_d2(nbr_ref, nbr2_ref, active_ref, rand_ref, off_ref,
-                      out_ref, *, n_words: int, x: int, staggered: bool):
-    """Distance-2 selection: OR the 1-hop and 2-hop forbidden bitsets."""
-    words = (_forbidden_words(nbr_ref[...], n_words)
-             | _forbidden_words(nbr2_ref[...], n_words))
-    color = select_from_words(words, rand_ref[...], off_ref[...], x=x,
-                              staggered=staggered)
-    out_ref[...] = jnp.where(active_ref[...] != 0, color, 0).astype(jnp.int32)
+def _conflict_kernel(*refs, n_tiles: int):
+    """Row loses iff a neighbour in any (colors, prio) tile pair holds the
+    same nonzero color with strictly higher priority."""
+    myc_ref, myp_ref = refs[:2]
+    tiles = refs[2:2 + 2 * n_tiles]
+    active_ref, out_ref = refs[2 + 2 * n_tiles:]
+    myc, myp = myc_ref[...], myp_ref[...]
+    lose = jnp.zeros(myc.shape, jnp.int32)
+    for c_ref, p_ref in zip(tiles[::2], tiles[1::2]):
+        hit = (c_ref[...] == myc) & (myc > 0) & (p_ref[...] > myp)
+        lose = jnp.maximum(lose, jnp.max(hit.astype(jnp.int32), axis=0,
+                                         keepdims=True))
+    out_ref[...] = jnp.where(active_ref[...] != 0, lose, 0)
 
 
-def _lose_against(myc, myp, nbrc, nbrp):
-    same = (nbrc == myc) & (myc > 0)
-    return (same & (nbrp > myp)).any(axis=1)
+def _specs(tiles, n_rows: int):
+    tile = lambda t: pl.BlockSpec((t.shape[0], TILE_V), lambda i: (0, i))
+    row = pl.BlockSpec((1, TILE_V), lambda i: (0, i))
+    return [tile(t) for t in tiles], [row] * n_rows, row
 
 
-def _conflict_kernel(myc_ref, myp_ref, nbrc_ref, nbrp_ref, active_ref,
-                     out_ref):
-    lose = _lose_against(myc_ref[...][:, None], myp_ref[...][:, None],
-                         nbrc_ref[...], nbrp_ref[...])
-    out_ref[...] = (lose & (active_ref[...] != 0)).astype(jnp.int32)
-
-
-def _conflict_kernel_d2(myc_ref, myp_ref, nbrc_ref, nbrp_ref, nbr2c_ref,
-                        nbr2p_ref, active_ref, out_ref):
-    """Distance-2 conflicts: lose against any 1-hop OR 2-hop neighbour."""
-    myc = myc_ref[...][:, None]
-    myp = myp_ref[...][:, None]
-    lose = (_lose_against(myc, myp, nbrc_ref[...], nbrp_ref[...])
-            | _lose_against(myc, myp, nbr2c_ref[...], nbr2p_ref[...]))
-    out_ref[...] = (lose & (active_ref[...] != 0)).astype(jnp.int32)
-
-
-def color_select_pallas(nbr_colors, active, rand_u32, offset=None, *,
-                        max_colors: int, x: int = 0, staggered: bool = False,
+def color_select_pallas(tiles, active, rand_u32, offset, *, max_colors: int,
+                        x: int = 0, staggered: bool = False,
                         interpret: bool = False):
-    """Tile-parallel color selection. V must be a multiple of TILE_V.
+    """Tile-parallel color selection.
 
-    nbr_colors (V, MAXD) int32, active (V,) int32/bool, rand_u32 (V,) uint32,
-    offset (V,) int32 (staggered start color; ignored unless ``staggered``).
-    Returns (V,) int32 chosen colors (0 where inactive).
+    ``tiles`` — one or two transposed neighbour-color tiles (MAXD_i, V)
+    int32; ``active``/``rand_u32``/``offset`` — (1, V) int32/uint32/int32.
+    V must be a multiple of TILE_V.  Returns (1, V) int32 chosen colors
+    (0 where inactive).
     """
     assert max_colors % 32 == 0
-    v, maxd = nbr_colors.shape
+    v = tiles[0].shape[1]
     assert v % TILE_V == 0, f"V={v} not a multiple of {TILE_V}"
-    if offset is None:
-        offset = jnp.zeros((v,), jnp.int32)
-    n_words = max_colors // 32
-    grid = (v // TILE_V,)
-    kernel = functools.partial(_select_kernel, n_words=n_words, x=x,
+    kernel = functools.partial(_select_kernel, n_tiles=len(tiles),
+                               n_words=max_colors // 32, x=x,
                                staggered=staggered)
-    vec = pl.BlockSpec((TILE_V,), lambda i: (i,))
+    tile_specs, row_specs, out_spec = _specs(tiles, 3)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_V, maxd), lambda i: (i, 0)),
-            vec, vec, vec,
-        ],
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((v,), jnp.int32),
+        grid=(v // TILE_V,),
+        in_specs=tile_specs + row_specs,
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((1, v), jnp.int32),
         interpret=interpret,
-    )(nbr_colors, active.astype(jnp.int32), rand_u32,
-      offset.astype(jnp.int32))
+    )(*tiles, active, rand_u32, offset)
 
 
-def color_select_pallas_d2(nbr_colors, nbr2_colors, active, rand_u32,
-                          offset=None, *, max_colors: int, x: int = 0,
-                          staggered: bool = False, interpret: bool = False):
-    """Distance-2 tile-parallel selection. V must be a multiple of TILE_V.
-
-    Same contract as ``color_select_pallas`` with a second padded neighbour
-    tile ``nbr2_colors`` (V, MAXD2) — the strict two-hop colors; the kernel
-    ORs both forbidden bitsets before the find-first-zero.
-    """
-    assert max_colors % 32 == 0
-    v, maxd = nbr_colors.shape
-    _, maxd2 = nbr2_colors.shape
-    assert v % TILE_V == 0, f"V={v} not a multiple of {TILE_V}"
-    if offset is None:
-        offset = jnp.zeros((v,), jnp.int32)
-    n_words = max_colors // 32
-    grid = (v // TILE_V,)
-    kernel = functools.partial(_select_kernel_d2, n_words=n_words, x=x,
-                               staggered=staggered)
-    vec = pl.BlockSpec((TILE_V,), lambda i: (i,))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE_V, maxd), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_V, maxd2), lambda i: (i, 0)),
-            vec, vec, vec,
-        ],
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((v,), jnp.int32),
-        interpret=interpret,
-    )(nbr_colors, nbr2_colors, active.astype(jnp.int32), rand_u32,
-      offset.astype(jnp.int32))
-
-
-def conflict_pallas(my_color, my_prio, nbr_colors, nbr_prio, active, *,
+def conflict_pallas(my_color, my_prio, tiles, active, *,
                     interpret: bool = False):
-    """Tile-parallel conflict detection. Returns (V,) int32 (1 = recolor)."""
-    v, maxd = nbr_colors.shape
-    assert v % TILE_V == 0, f"V={v} not a multiple of {TILE_V}"
-    grid = (v // TILE_V,)
-    vec = pl.BlockSpec((TILE_V,), lambda i: (i,))
-    mat = pl.BlockSpec((TILE_V, maxd), lambda i: (i, 0))
-    return pl.pallas_call(
-        _conflict_kernel,
-        grid=grid,
-        in_specs=[vec, vec, mat, mat, vec],
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((v,), jnp.int32),
-        interpret=interpret,
-    )(my_color, my_prio, nbr_colors, nbr_prio, active.astype(jnp.int32))
+    """Tile-parallel conflict detection.
 
-
-def conflict_pallas_d2(my_color, my_prio, nbr_colors, nbr_prio, nbr2_colors,
-                       nbr2_prio, active, *, interpret: bool = False):
-    """Distance-2 conflict detection over both neighbour tiles."""
-    v, maxd = nbr_colors.shape
-    _, maxd2 = nbr2_colors.shape
+    ``tiles`` — ``(colors, prio)`` transposed (MAXD_i, V) pairs, flattened
+    (two tiles at distance 1, four at distance 2); ``my_color``/``my_prio``
+    /``active`` — (1, V).  Returns (1, V) int32 (1 = recolor).
+    """
+    v = my_color.shape[1]
     assert v % TILE_V == 0, f"V={v} not a multiple of {TILE_V}"
-    grid = (v // TILE_V,)
-    vec = pl.BlockSpec((TILE_V,), lambda i: (i,))
-    mat = pl.BlockSpec((TILE_V, maxd), lambda i: (i, 0))
-    mat2 = pl.BlockSpec((TILE_V, maxd2), lambda i: (i, 0))
+    assert len(tiles) % 2 == 0
+    tile_specs, row_specs, out_spec = _specs(tiles, 3)
     return pl.pallas_call(
-        _conflict_kernel_d2,
-        grid=grid,
-        in_specs=[vec, vec, mat, mat, mat2, mat2, vec],
-        out_specs=vec,
-        out_shape=jax.ShapeDtypeStruct((v,), jnp.int32),
+        functools.partial(_conflict_kernel, n_tiles=len(tiles) // 2),
+        grid=(v // TILE_V,),
+        in_specs=row_specs[:2] + tile_specs + row_specs[2:],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((1, v), jnp.int32),
         interpret=interpret,
-    )(my_color, my_prio, nbr_colors, nbr_prio, nbr2_colors, nbr2_prio,
-      active.astype(jnp.int32))
+    )(my_color, my_prio, *tiles, active)

@@ -29,9 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .firstfit import (TILE_V, _forbidden_words, color_select_pallas,
-                       color_select_pallas_d2, conflict_pallas,
-                       conflict_pallas_d2, select_from_words)
+from .firstfit import (TILE_V, color_select_pallas, conflict_pallas,
+                       forbidden_words, select_from_words)
 
 # Strategy names, mirroring repro.core.selection (string-equal; duplicated
 # here so kernels never import core and the layering stays one-way).
@@ -55,27 +54,74 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
-def _pad_v(x, v_pad, fill=0):
-    pad = [(0, v_pad - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
-    return jnp.pad(x, pad, constant_values=fill)
+def _flatten(tiles, rows: dict):
+    """Flatten leading batch dims onto the row axis.
 
-
-def _flat_call(fn, lead, v, rows: dict, tiles: dict, **kw):
-    """Flatten leading batch dims onto the row axis and call the 2-D entry.
-
-    ``rows`` — (…, V) operands (None and scalars broadcast); ``tiles`` —
-    (…, V, D) operands.  Rows of a (B, V, D) multi-graph tile land
-    contiguously on the flat row axis: the XLA twin vectorizes the flat
-    tile directly, the Pallas grid just tiles the extra rows (a per-graph
-    grid axis) — one kernel launch per batch.  Returns (…, V).
+    ``tiles`` — (…, V, D) operands; ``rows`` — (…, V) operands (scalars
+    broadcast).  Rows of a (B, V, D) multi-graph tile land contiguously on
+    the flat row axis: the XLA twin vectorizes the flat tile directly, the
+    Pallas grid just tiles the extra rows (a per-graph grid axis) — one
+    kernel launch per batch.  Returns ``(tiles, rows, unflatten)``.
     """
-    flat = lambda a: jnp.reshape(
-        jnp.broadcast_to(jnp.asarray(a), lead + (v,)), (-1,))
-    args = {k: (None if a is None else flat(a)) for k, a in rows.items()}
-    args.update({k: jnp.reshape(jnp.asarray(a),
-                                (-1,) + jnp.shape(a)[-1:])
-                 for k, a in tiles.items()})
-    return fn(**args, **kw).reshape(lead + (v,))
+    tiles = tuple(jnp.asarray(t) for t in tiles)
+    lead, v = tiles[0].shape[:-2], tiles[0].shape[-2]
+    flat_t = tuple(jnp.reshape(t, (-1, t.shape[-1])) for t in tiles)
+    flat_r = {k: jnp.reshape(jnp.broadcast_to(jnp.asarray(a), lead + (v,)),
+                             (-1,)) for k, a in rows.items()}
+    return flat_t, flat_r, lambda out: jnp.reshape(out, lead + (v,))
+
+
+def _kernel_operands(tiles, rows, fills):
+    """Pad rows to a TILE_V multiple and lay them out for the kernels.
+
+    Tiles (V, D) become transposed (D, V_pad) — one ELL slot per row, one
+    vertex per lane; per-vertex operands become (1, V_pad) rows.  ``fills``
+    gives each tile's padding value (rows past V are inactive either way).
+    """
+    v = rows[0].shape[0]
+    pad = -(-v // TILE_V) * TILE_V - v
+    t_out = tuple(jnp.pad(t.T, ((0, 0), (0, pad)), constant_values=f)
+                  for t, f in zip(tiles, fills))
+    r_out = tuple(jnp.pad(r, (0, pad))[None, :] for r in rows)
+    return t_out, r_out
+
+
+def _select(tiles, active, rand_u32, offset, *, max_colors: int,
+            selection: str, x: int, backend: str, interpret: bool | None):
+    """Shared body of ``select_colors`` (one tile) and ``select_colors_d2``
+    (one-hop + two-hop tiles, forbidden bitsets ORed)."""
+    if selection not in SELECTIONS:
+        raise ValueError(
+            f"unknown selection {selection!r}, want one of {SELECTIONS}")
+    assert max_colors % 32 == 0
+    backend = resolve_backend(backend)
+    tiles, rows, unflatten = _flatten(tiles, dict(
+        active=jnp.asarray(active) != 0,
+        rand=jnp.uint32(0) if rand_u32 is None else rand_u32,
+        offset=jnp.asarray(0 if offset is None else offset, jnp.int32)))
+    staggered = selection == STAGGERED
+    x_eff = x if selection == RANDOM_X else 0
+
+    if backend == "xla":
+        v = rows["active"].shape[0]
+        slots = [(lambda d, t=t.T: jax.lax.dynamic_slice_in_dim(t, d, 1),
+                  t.shape[1]) for t in tiles]
+        words = forbidden_words(slots, max_colors // 32, v)
+        color = select_from_words(words, rows["rand"][None],
+                                  rows["offset"][None], x=x_eff,
+                                  staggered=staggered)[0]
+        return unflatten(jnp.where(rows["active"], color, 0))
+
+    if interpret is None:
+        interpret = _default_interpret()
+    v = rows["active"].shape[0]
+    t_k, (act, rand, off) = _kernel_operands(
+        tiles, (rows["active"].astype(jnp.int32), rows["rand"],
+                rows["offset"]), (0,) * len(tiles))
+    out = color_select_pallas(t_k, act, rand, off, max_colors=max_colors,
+                              x=x_eff, staggered=staggered,
+                              interpret=interpret)
+    return unflatten(out[0, :v])
 
 
 def select_colors(nbr_colors, active, rand_u32=None, *, max_colors: int,
@@ -94,44 +140,9 @@ def select_colors(nbr_colors, active, rand_u32=None, *, max_colors: int,
     vectorizes the flat tile directly, and the Pallas grid simply tiles the
     extra rows (a per-graph grid axis) — one kernel launch per batch.
     """
-    if selection not in SELECTIONS:
-        raise ValueError(
-            f"unknown selection {selection!r}, want one of {SELECTIONS}")
-    assert max_colors % 32 == 0
-    backend = resolve_backend(backend)
-    nbr_colors = jnp.asarray(nbr_colors)
-    if nbr_colors.ndim > 2:
-        return _flat_call(
-            select_colors, nbr_colors.shape[:-2], nbr_colors.shape[-2],
-            rows=dict(active=active, rand_u32=rand_u32, offset=offset),
-            tiles=dict(nbr_colors=nbr_colors), max_colors=max_colors,
-            selection=selection, x=x, backend=backend, interpret=interpret)
-    v = nbr_colors.shape[0]
-    staggered = selection == STAGGERED
-    x_eff = x if selection == RANDOM_X else 0
-    if rand_u32 is None:
-        rand_u32 = jnp.zeros((v,), jnp.uint32)
-    if offset is None:
-        offset = jnp.zeros((v,), jnp.int32)
-    else:
-        offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (v,))
-    active = jnp.asarray(active)
-
-    if backend == "xla":
-        words = _forbidden_words(nbr_colors, max_colors // 32)
-        color = select_from_words(words, rand_u32, offset, x=x_eff,
-                                  staggered=staggered)
-        return jnp.where(active != 0, color, 0).astype(jnp.int32)
-
-    if interpret is None:
-        interpret = _default_interpret()
-    v_pad = -(-v // TILE_V) * TILE_V
-    out = color_select_pallas(
-        _pad_v(nbr_colors, v_pad), _pad_v(active, v_pad),
-        _pad_v(rand_u32, v_pad), _pad_v(offset, v_pad),
-        max_colors=max_colors, x=x_eff, staggered=staggered,
-        interpret=interpret)
-    return out[:v]
+    return _select((nbr_colors,), active, rand_u32, offset,
+                   max_colors=max_colors, selection=selection, x=x,
+                   backend=backend, interpret=interpret)
 
 
 def select_colors_d2(nbr_colors, nbr2_colors, active, rand_u32=None, *,
@@ -146,47 +157,34 @@ def select_colors_d2(nbr_colors, nbr2_colors, active, rand_u32=None, *,
     selecting, so a chosen color differs from every color within graph
     distance 2.
     """
-    if selection not in SELECTIONS:
-        raise ValueError(
-            f"unknown selection {selection!r}, want one of {SELECTIONS}")
-    assert max_colors % 32 == 0
+    return _select((nbr_colors, nbr2_colors), active, rand_u32, offset,
+                   max_colors=max_colors, selection=selection, x=x,
+                   backend=backend, interpret=interpret)
+
+
+def _detect(my_color, my_prio, pairs, active, *, backend: str,
+            interpret: bool | None):
+    """Shared body of ``detect_conflicts`` / ``detect_conflicts_d2``:
+    ``pairs`` is a tuple of (neighbour colors, neighbour prio) tiles."""
     backend = resolve_backend(backend)
-    nbr_colors = jnp.asarray(nbr_colors)
-    nbr2_colors = jnp.asarray(nbr2_colors)
-    if nbr_colors.ndim > 2:
-        return _flat_call(
-            select_colors_d2, nbr_colors.shape[:-2], nbr_colors.shape[-2],
-            rows=dict(active=active, rand_u32=rand_u32, offset=offset),
-            tiles=dict(nbr_colors=nbr_colors, nbr2_colors=nbr2_colors),
-            max_colors=max_colors, selection=selection, x=x,
-            backend=backend, interpret=interpret)
-    v = nbr_colors.shape[0]
-    staggered = selection == STAGGERED
-    x_eff = x if selection == RANDOM_X else 0
-    if rand_u32 is None:
-        rand_u32 = jnp.zeros((v,), jnp.uint32)
-    if offset is None:
-        offset = jnp.zeros((v,), jnp.int32)
-    else:
-        offset = jnp.broadcast_to(jnp.asarray(offset, jnp.int32), (v,))
-    active = jnp.asarray(active)
-
+    tiles = tuple(t for pair in pairs for t in pair)
+    tiles, rows, unflatten = _flatten(tiles, dict(
+        myc=my_color, myp=my_prio, active=jnp.asarray(active) != 0))
+    myc, myp = rows["myc"], rows["myp"]
     if backend == "xla":
-        words = (_forbidden_words(nbr_colors, max_colors // 32)
-                 | _forbidden_words(nbr2_colors, max_colors // 32))
-        color = select_from_words(words, rand_u32, offset, x=x_eff,
-                                  staggered=staggered)
-        return jnp.where(active != 0, color, 0).astype(jnp.int32)
-
+        lose = jnp.zeros(myc.shape, bool)
+        for c, p in zip(tiles[::2], tiles[1::2]):
+            lose |= ((c == myc[:, None]) & (myc[:, None] > 0)
+                     & (p > myp[:, None])).any(axis=1)
+        return unflatten(lose & rows["active"])
     if interpret is None:
         interpret = _default_interpret()
-    v_pad = -(-v // TILE_V) * TILE_V
-    out = color_select_pallas_d2(
-        _pad_v(nbr_colors, v_pad), _pad_v(nbr2_colors, v_pad),
-        _pad_v(active, v_pad), _pad_v(rand_u32, v_pad), _pad_v(offset, v_pad),
-        max_colors=max_colors, x=x_eff, staggered=staggered,
-        interpret=interpret)
-    return out[:v]
+    v = myc.shape[0]
+    t_k, (c_k, p_k, a_k) = _kernel_operands(
+        tiles, (myc, myp, rows["active"].astype(jnp.int32)),
+        (0, -1) * len(pairs))
+    out = conflict_pallas(c_k, p_k, t_k, a_k, interpret=interpret)
+    return unflatten(out[0, :v].astype(bool))
 
 
 def detect_conflicts(my_color, my_prio, nbr_colors, nbr_prio, active, *,
@@ -196,29 +194,8 @@ def detect_conflicts(my_color, my_prio, nbr_colors, nbr_prio, active, *,
     Traceable; same backend contract as ``select_colors``, leading batch
     dims accepted on every operand.
     """
-    backend = resolve_backend(backend)
-    my_color = jnp.asarray(my_color)
-    nbr_colors = jnp.asarray(nbr_colors)
-    if nbr_colors.ndim > 2:
-        return _flat_call(
-            detect_conflicts, nbr_colors.shape[:-2], nbr_colors.shape[-2],
-            rows=dict(my_color=my_color, my_prio=my_prio, active=active),
-            tiles=dict(nbr_colors=nbr_colors, nbr_prio=nbr_prio),
-            backend=backend, interpret=interpret)
-    active = jnp.asarray(active)
-    if backend == "xla":
-        same = (nbr_colors == my_color[:, None]) & (my_color[:, None] > 0)
-        lose = (same & (nbr_prio > my_prio[:, None])).any(axis=1)
-        return lose & (active != 0)
-    if interpret is None:
-        interpret = _default_interpret()
-    v = my_color.shape[0]
-    v_pad = -(-v // TILE_V) * TILE_V
-    out = conflict_pallas(
-        _pad_v(my_color, v_pad), _pad_v(my_prio, v_pad, fill=-1),
-        _pad_v(nbr_colors, v_pad), _pad_v(nbr_prio, v_pad, fill=-1),
-        _pad_v(active, v_pad), interpret=interpret)
-    return out[:v].astype(bool)
+    return _detect(my_color, my_prio, ((nbr_colors, nbr_prio),), active,
+                   backend=backend, interpret=interpret)
 
 
 def detect_conflicts_d2(my_color, my_prio, nbr_colors, nbr_prio, nbr2_colors,
@@ -229,34 +206,9 @@ def detect_conflicts_d2(my_color, my_prio, nbr_colors, nbr_prio, nbr2_colors,
     priority. Returns (V,) bool; same backend contract as ``select_colors``,
     leading batch dims accepted on every operand.
     """
-    backend = resolve_backend(backend)
-    my_color = jnp.asarray(my_color)
-    nbr_colors = jnp.asarray(nbr_colors)
-    if nbr_colors.ndim > 2:
-        return _flat_call(
-            detect_conflicts_d2, nbr_colors.shape[:-2], nbr_colors.shape[-2],
-            rows=dict(my_color=my_color, my_prio=my_prio, active=active),
-            tiles=dict(nbr_colors=nbr_colors, nbr_prio=nbr_prio,
-                       nbr2_colors=nbr2_colors, nbr2_prio=nbr2_prio),
-            backend=backend, interpret=interpret)
-    active = jnp.asarray(active)
-    if backend == "xla":
-        myc, myp = my_color[:, None], jnp.asarray(my_prio)[:, None]
-        lose = (((nbr_colors == myc) & (myc > 0) & (nbr_prio > myp))
-                .any(axis=1)
-                | ((nbr2_colors == myc) & (myc > 0) & (nbr2_prio > myp))
-                .any(axis=1))
-        return lose & (active != 0)
-    if interpret is None:
-        interpret = _default_interpret()
-    v = my_color.shape[0]
-    v_pad = -(-v // TILE_V) * TILE_V
-    out = conflict_pallas_d2(
-        _pad_v(my_color, v_pad), _pad_v(my_prio, v_pad, fill=-1),
-        _pad_v(nbr_colors, v_pad), _pad_v(nbr_prio, v_pad, fill=-1),
-        _pad_v(nbr2_colors, v_pad), _pad_v(nbr2_prio, v_pad, fill=-1),
-        _pad_v(active, v_pad), interpret=interpret)
-    return out[:v].astype(bool)
+    return _detect(my_color, my_prio,
+                   ((nbr_colors, nbr_prio), (nbr2_colors, nbr2_prio)),
+                   active, backend=backend, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("max_colors", "x", "interpret"))
@@ -272,12 +224,5 @@ def color_select(nbr_colors, active, rand_u32, *, max_colors: int, x: int = 0,
 def conflict(my_color, my_prio, nbr_colors, nbr_prio, active, *,
              interpret: bool | None = None):
     """Conflict detection over a dense neighbour tile. Returns (V,) bool."""
-    if interpret is None:
-        interpret = _default_interpret()
-    v = nbr_colors.shape[0]
-    v_pad = -(-v // TILE_V) * TILE_V
-    out = conflict_pallas(
-        _pad_v(my_color, v_pad), _pad_v(my_prio, v_pad, fill=-1),
-        _pad_v(nbr_colors, v_pad), _pad_v(nbr_prio, v_pad, fill=-1),
-        _pad_v(active, v_pad), interpret=interpret)
-    return out[:v].astype(bool)
+    return detect_conflicts(my_color, my_prio, nbr_colors, nbr_prio, active,
+                            backend="pallas", interpret=interpret)

@@ -29,7 +29,6 @@ from pathlib import Path
 
 import jax
 
-from repro import compat
 from repro.configs import SHAPES, get_arch, list_archs, shape_applicable
 from repro.launch.mesh import (make_coloring_mesh, make_production_mesh,
                                make_worker_mesh)
@@ -61,7 +60,7 @@ def dryrun_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
     n_chips = mesh.devices.size
     t0 = time.time()
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args = input_specs(arch, shape, mesh)
             lowered = jax.jit(fn).lower(*args)
             t_lower = time.time() - t0

@@ -14,8 +14,6 @@ graph lanes of the batched pipeline shard over ``batch``.
 
 Functions, not module constants: importing this module never touches JAX
 device state (the dry-run must set XLA_FLAGS before first device init).
-Mesh construction goes through ``repro.compat`` so the same code runs on
-old jax (no ``AxisType``) and new.
 """
 from __future__ import annotations
 
@@ -23,7 +21,6 @@ import dataclasses
 
 import jax
 
-from repro import compat
 from repro.core.comm import AXIS, BATCH_AXIS
 
 
@@ -74,7 +71,9 @@ class MeshSpec:
         return n
 
     def build(self):
-        return compat.make_mesh(self.shape, self.axes)
+        return jax.make_mesh(
+            self.shape, self.axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(self.axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import get_arch, plan_for_mesh, smoke_of
 from repro.launch.mesh import make_local_mesh
 from repro.models import decode_step, param_defs, prefill
@@ -47,7 +46,7 @@ def serve(arch, mesh, plan, *, batch: int, prompt_len: int, gen: int,
     prefill_fn = jax.jit(lambda p, b: prefill(p, b, arch, plan, prompt_len))
     step_fn = jax.jit(lambda p, c, t: decode_step(p, c, t, arch, plan))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         cache, logits = prefill_fn(params, batch_in)
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]

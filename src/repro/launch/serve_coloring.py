@@ -61,6 +61,7 @@ from repro.core import (ColorConfig, Graph, PipelineConfig, RecolorConfig,
                         pipeline_sim, plan_fits, plan_signature,
                         program_cache_contains, program_cache_stats,
                         remap_plan_arrays, resolve_pipeline_cfg, rmat)
+from repro.compile_cache import use_compile_cache
 from repro.core.pipeline import _history_to_host
 from repro.core.speculative import _apply_partial
 from repro.launch.mesh import engine_lanes
@@ -958,6 +959,7 @@ def main():
                     default="continuous")
     ap.add_argument("--lanes", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     graphs = _traffic(args.graphs, args.scale_min, args.scale_max, args.seed)
     svc = ColoringService(

@@ -15,9 +15,10 @@ the post-optimization HLO text instead:
      condition's comparison constant; dynamic ``while_loop``s get 1× and are
      flagged)
 
-Terms (TPU v5e per chip): compute = FLOPs / 197e12, memory = bytes / 819e9,
-collective = bytes / 50e9 per link (all-reduce counted 2×: reduce-scatter +
-all-gather phases). All quantities are per-device (the compiled module is the
+Terms (per chip, peaks from ``PEAKS`` by ``device_kind``): compute = FLOPs
+/ peak FLOP/s, memory = bytes / HBM bandwidth, collective = bytes / link
+bandwidth per link (all-reduce counted 2×: reduce-scatter + all-gather
+phases). All quantities are per-device (the compiled module is the
 per-device program), so terms are directly per-chip seconds.
 """
 from __future__ import annotations
@@ -26,10 +27,26 @@ import dataclasses
 import re
 from typing import Any
 
-PEAK_FLOPS = 197e12        # bf16 TFLOP/s per chip (TPU v5e)
-HBM_BW = 819e9             # B/s per chip
-LINK_BW = 50e9             # B/s per ICI link
-HBM_BYTES = 16e9           # HBM capacity per chip (TPU v5e)
+V5E = "TPU v5 lite"        # jax.Device.device_kind of a TPU v5e chip
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect (4 links of
+# 50 GB/s).
+PEAKS = {
+    V5E: dict(flops=197e12, hbm_bw=819e9, link_bw=50e9, hbm_bytes=16e9),
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; unknown kinds are an error
+    (a default would silently price another chip as a v5e)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to roofline.PEAKS "
+                         f"with their source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -254,14 +271,16 @@ def analyze_hlo(text: str) -> dict[str, Any]:
     return totals
 
 
-def roofline_terms(analysis: dict[str, Any], *, n_links: int = 4) -> dict:
+def roofline_terms(analysis: dict[str, Any], *, n_links: int = 4,
+                   device_kind: str = V5E) -> dict:
     """Three per-chip roofline terms (seconds) from analyze_hlo output."""
+    pk = peaks(device_kind)
     coll = analysis["coll_bytes"]
     coll_eff = sum(v * (_AR_FACTOR if k == "all-reduce" else 1.0)
                    for k, v in coll.items())
-    compute_s = analysis["dot_flops"] / PEAK_FLOPS
-    memory_s = analysis["dot_bytes"] / HBM_BW
-    collective_s = coll_eff / (LINK_BW * n_links)
+    compute_s = analysis["dot_flops"] / pk["flops"]
+    memory_s = analysis["dot_bytes"] / pk["hbm_bw"]
+    collective_s = coll_eff / (pk["link_bw"] * n_links)
     terms = dict(compute_s=compute_s, memory_s=memory_s,
                  collective_s=collective_s,
                  collective_bytes=coll_eff, flops=analysis["dot_flops"],
@@ -277,7 +296,8 @@ def roofline_terms(analysis: dict[str, Any], *, n_links: int = 4) -> dict:
 def coloring_memory_projection(n_global: int, P: int, maxd: int, *,
                                maxd2: int = 0, ghost_frac: float = 0.5,
                                boundary_frac: float = 0.5,
-                               batch: int = 1) -> dict:
+                               batch: int = 1,
+                               device_kind: str = V5E) -> dict:
     """Per-shard device bytes of the coloring layout under the id policy.
 
     Projects the ``PartitionedGraph.arrays()`` footprint for a graph of
@@ -296,7 +316,8 @@ def coloring_memory_projection(n_global: int, P: int, maxd: int, *,
     local block (0.5 matches the repo's RMAT partitions at CPU scale;
     structured meshes sit far lower).  ``batch`` multiplies the working
     views (the batched pipeline holds one view per lane).  Returns the
-    per-array byte dict plus totals and the HBM occupancy fraction.
+    per-array byte dict plus totals and the HBM occupancy fraction of one
+    ``device_kind`` chip.
     """
     import numpy as np                       # lazy: keep roofline import-light
 
@@ -324,6 +345,7 @@ def coloring_memory_projection(n_global: int, P: int, maxd: int, *,
         views=n_slots * 4 * lanes,          # working color views per lane
     )
     total = sum(per.values())
+    hbm = peaks(device_kind)["hbm_bytes"]
     # what the same layout would cost if ids stayed int32 (the gap is the
     # whole price of the int64 promotion)
     extra = (n_slots * (id_b - 4)) * 2 if pol.promoted else 0
@@ -334,7 +356,7 @@ def coloring_memory_projection(n_global: int, P: int, maxd: int, *,
         ell_dtype=np.dtype(pol.ell_dtype).name,
         promoted=pol.promoted, promoted_extra_bytes=int(extra),
         per_shard_bytes=per, total_per_shard=int(total),
-        hbm_fraction=total / HBM_BYTES, fits_hbm=total <= HBM_BYTES)
+        hbm_fraction=total / hbm, fits_hbm=total <= hbm)
 
 
 def model_flops(arch, shape) -> float:

@@ -15,7 +15,6 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro import compat
 from repro.configs.base import ArchConfig, ShardingPlan
 from repro.data.pipeline import DataConfig, DataLoader
 from repro.models import param_defs
@@ -78,7 +77,7 @@ class Trainer:
 
     # -- state ------------------------------------------------------------
     def init_state(self):
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             params = init_params_sharded(self.pdefs, self.mesh,
                                          self.param_specs, self.tcfg.seed)
             opt_state = init_opt_state(params, self.opt_cfg)
@@ -112,7 +111,7 @@ class Trainer:
                 if self.injector:
                     self.injector.maybe_fail(step)
                 batch = next(loader)
-                with compat.set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     params, opt_state, metrics = self._step_fn(
                         params, opt_state, batch)
                 step += 1

@@ -6,7 +6,11 @@ environment (jax + numpy + pytest only).
 Goldens below were captured from the seed implementation (scalar greedy
 chunks; dense-occupancy recolor steps) before the ELL/bitset rework, so they
 pin "parallel_chunk=False == seed behavior" and "chunked recolor == seed
-recolor" bitwise for fixed seeds.
+recolor" bitwise for fixed seeds.  The two rows that draw random numbers
+(Random-X selection, RAND recoloring) were re-derived under jax 0.9, whose
+partitionable threefry changed every random stream; each test checks the
+coloring valid and equal across both kernel backends (the XLA twin and the
+Pallas kernels) before comparing the pin.
 """
 import hashlib
 
@@ -150,7 +154,7 @@ def test_select_rejects_unknowns():
 
 SEED_GOLD = {
     selection.FIRST_FIT: (13, "800e80e743f3eb16"),
-    selection.RANDOM_X: (31, "ff78aa0d5bd44635"),
+    selection.RANDOM_X: (27, "25d0a8a5f10c1690"),
     selection.STAGGERED: (196, "159b9ed81e9a13e6"),
 }
 
@@ -159,10 +163,15 @@ SEED_GOLD = {
 def test_sequential_mode_is_seed_behavior(graph, pgraph, selname):
     """parallel_chunk=False reproduces the pre-rework coloring bitwise."""
     order = compute_order(pgraph, ordering.NATURAL)
-    cfg = ColorConfig(max_colors=512, superstep=64, selection=selname,
-                      random_x=10, seed=0, parallel_chunk=False)
-    view, st = color_graph_sim(pgraph, order, cfg)
+    runs = [color_graph_sim(pgraph, order, ColorConfig(
+        max_colors=512, superstep=64, selection=selname, random_x=10, seed=0,
+        parallel_chunk=False, backend=b)) for b in ("xla", "pallas")]
+    (view, st), (view_p, st_p) = runs
+    np.testing.assert_array_equal(np.asarray(view), np.asarray(view_p))
+    assert st["n_colors"] == st_p["n_colors"]
     colors = colors_from_views(pgraph, np.asarray(view))
+    assert_valid(graph, colors, what=f"sequential-{selname}")
+    assert st["n_colors"] == colors.max()
     want_nc, want_hash = SEED_GOLD[selname]
     assert st["n_colors"] == want_nc
     assert _hash(colors) == want_hash
@@ -187,7 +196,7 @@ def test_parallel_mode_valid_and_backends_agree(graph, pgraph, selname):
 RC_GOLD = {
     "nd": (11, 13, "f578174af31ddb61"),
     "rv": (11, 13, "b9f1ceb928314ffc"),
-    "rand": (12, 13, "94da33bfa39399a0"),
+    "rand": (12, 13, "740a3e16be12a2d7"),
 }
 
 
@@ -201,11 +210,16 @@ def seed_view(pgraph):
 
 
 @pytest.mark.parametrize("perm", list(RC_GOLD))
-def test_recolor_chunked_is_seed_behavior(pgraph, seed_view, perm):
+def test_recolor_chunked_is_seed_behavior(graph, pgraph, seed_view, perm):
     """Chunked ELL/bitset recolor == the seed dense-occupancy recolor."""
-    v2, st = recolor_sim(pgraph, seed_view, perm, RecolorConfig(max_colors=512),
-                         key=jax.random.key(11))
+    runs = [recolor_sim(pgraph, seed_view, perm,
+                        RecolorConfig(max_colors=512, backend=b),
+                        key=jax.random.key(11)) for b in ("xla", "pallas")]
+    (v2, st), (v_p, st_p) = runs
+    np.testing.assert_array_equal(np.asarray(v2), np.asarray(v_p))
+    assert st == st_p
     colors = colors_from_views(pgraph, np.asarray(v2))
+    assert_valid(graph, colors, what=f"recolor-{perm}")
     want_nc, want_ex, want_hash = RC_GOLD[perm]
     assert st["n_colors"] == want_nc
     assert st["n_exchanges"] == want_ex

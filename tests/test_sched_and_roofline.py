@@ -78,3 +78,9 @@ class TestRooflineParser:
         assert t["compute_s"] == pytest.approx(1.0)
         assert t["collective_s"] == pytest.approx(1.0)  # 2x AR / 4 links
         assert t["bottleneck"] in ("compute", "collective")
+
+    def test_unknown_device_kind_raises(self):
+        analysis = dict(dot_flops=1.0, dot_bytes=1.0, coll_bytes={},
+                        coll_count={}, dynamic_whiles=0, while_trips=[])
+        with pytest.raises(ValueError, match="no published peaks"):
+            roofline_terms(analysis, device_kind="cpu")

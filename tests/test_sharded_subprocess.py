@@ -31,13 +31,13 @@ def test_sharded_coloring_equals_sim():
                                 color_graph_sharded, RecolorConfig,
                                 recolor_sim, recolor_sharded,
                                 colors_from_views, assert_valid, ordering)
-        from repro.compat import make_mesh
+        from jax.sharding import AxisType
         g = rmat.grid2d(32, 32, 9)
         pg = partition_graph(g, 8)
         order = compute_order(pg, ordering.SMALLEST_LAST)
         cfg = ColorConfig(max_colors=64, superstep=64)
         v_sim, s_sim = color_graph_sim(pg, order, cfg)
-        mesh = make_mesh((8,), ("workers",))
+        mesh = jax.make_mesh((8,), ("workers",), axis_types=(AxisType.Auto,))
         v_sh, s_sh = color_graph_sharded(pg, order, cfg, mesh)
         assert (np.asarray(v_sim) == np.asarray(v_sh)).all(), "views differ"
         rcfg = RecolorConfig(max_colors=64)
@@ -56,11 +56,11 @@ def test_color_many_sharded_equals_sim():
     """Batched multi-graph pipeline on a real workers mesh == sim executor
     (the graph batch axis rides inside each shard via vmap)."""
     print(run_sub("""
-        import numpy as np
+        import numpy as np, jax
         from repro.core import (rmat, partition_graph, ColorConfig,
                                 RecolorConfig, PipelineConfig, color_many,
                                 color_many_sharded)
-        from repro.compat import make_mesh
+        from jax.sharding import AxisType
         graphs = [rmat.rmat_good(6, 8, seed=1), rmat.rmat_bad(6, 8, seed=2),
                   rmat.grid2d(16, 16, 9)]
         pgs = [partition_graph(g, 8) for g in graphs]
@@ -68,7 +68,7 @@ def test_color_many_sharded_equals_sim():
                              recolor=RecolorConfig(max_colors=64),
                              n_iters=3, patience=1)
         sim = color_many(pgs, cfg)
-        mesh = make_mesh((8,), ("workers",))
+        mesh = jax.make_mesh((8,), ("workers",), axis_types=(AxisType.Auto,))
         sh = color_many_sharded(pgs, cfg, mesh)
         for a, b in zip(sim, sh):
             assert np.array_equal(a["view"], b["view"]), "views differ"
@@ -85,10 +85,10 @@ def test_elastic_remesh_restore():
     print(run_sub("""
         import tempfile, numpy as np, jax
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import make_mesh
+        from jax.sharding import AxisType
         from repro.train import checkpoint as ckpt
-        mesh2 = make_mesh((2,), ("data",))
-        mesh4 = make_mesh((4,), ("data",))
+        mesh2 = jax.make_mesh((2,), ("data",), axis_types=(AxisType.Auto,))
+        mesh4 = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
         x = np.arange(64, dtype=np.float32).reshape(8, 8)
         tree = {"params": {"w": jax.device_put(
             x, NamedSharding(mesh2, P("data")))}}
@@ -111,10 +111,10 @@ def test_compressed_dp_train_step_sharded():
         import numpy as np, jax, jax.numpy as jnp
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax.sharding import AxisType
         from repro.train.compression import make_compressed_train_step
 
-        mesh = make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 
         def loss_fn(params, batch):
             pred = batch["x"] @ params["w"]
@@ -131,10 +131,10 @@ def test_compressed_dp_train_step_sharded():
         params = {"w": jnp.zeros((8, 1))}
         err = {"w": jnp.zeros((8, 1))}
         state = {}
-        smapped = jax.jit(shard_map(
+        smapped = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), P(), P(), P("data")),
-            out_specs=(P(), P(), P(), P()), check=False))
+            out_specs=(P(), P(), P(), P()), check_vma=False))
         r = np.random.default_rng(1)
         for i in range(60):
             x = r.normal(0, 1, (64, 8)).astype(np.float32)
@@ -152,7 +152,7 @@ def test_model_train_step_on_2x4_mesh():
     """Smoke arch train_step lowers + runs on a real (2,4) data×model mesh."""
     print(run_sub("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.compat import make_mesh, set_mesh
+        from jax.sharding import AxisType
         from repro.configs import get_arch, smoke_of, plan_for_mesh
         from repro.data.pipeline import DataConfig, host_batch, device_batch
         from repro.launch.steps import make_train_step
@@ -161,13 +161,14 @@ def test_model_train_step_on_2x4_mesh():
         from repro.train.optimizer import OptConfig, init_opt_state
         from repro.train.trainer import init_params_sharded
 
-        mesh = make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         plan = plan_for_mesh(mesh)
         arch = smoke_of(get_arch("moonshot_v1_16b_a3b"))
         pdefs = param_defs(arch)
         specs = jax.tree.map(lambda d: plan.spec(d.dims, d.shape), pdefs,
                              is_leaf=lambda t: isinstance(t, ParamDef))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = init_params_sharded(pdefs, mesh, specs, 0)
             opt_cfg = OptConfig(peak_lr=1e-3, warmup_steps=2)
             opt = init_opt_state(params, opt_cfg)
