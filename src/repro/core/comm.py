@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
+
 
 AXIS = "workers"        # default shard (graph-partition) mesh axis
 BATCH_AXIS = "batch"    # graph-batch mesh axis of 2D batch×shard meshes
@@ -422,6 +424,7 @@ def make_exchange(arrs, n_local_max: int, P_size: int, comm: AxisComm,
         # per-graph byte-accounting override on the shared round schedule
         byte_widths = arrs.get("round_widths")
 
+        @tracing.scope(tracing.EXCHANGE)
         def exchange(view, round_mask=None, apply_mask=None):
             return exchange_sparse(
                 view, arrs["send_slot"], arrs["ghost_shift"],
@@ -440,6 +443,7 @@ def make_exchange(arrs, n_local_max: int, P_size: int, comm: AxisComm,
         bytes_per_ex = jnp.int32(
             allgather_bytes_per_exchange(P_size, max_b, cfg.itemsize))
 
+    @tracing.scope(tracing.EXCHANGE)
     def exchange(view, round_mask=None, apply_mask=None):
         view = exchange_boundary(
             view, arrs["boundary"], arrs["ghost_owner"], arrs["ghost_slot"],

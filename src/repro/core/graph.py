@@ -42,7 +42,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 
+import jax
 import numpy as np
+
+from repro import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -394,6 +397,7 @@ class PartitionedGraph:
             out.update(self.comm_plan.arrays())
         return out
 
+    @tracing.span(tracing.TO_GLOBAL)
     def gather_global_colors(self, local_colors: np.ndarray) -> np.ndarray:
         """(P, n_slots) or (P, n_local_max) device views -> (n_global,) colors."""
         out = np.zeros(self.n_global, dtype=local_colors.dtype)
@@ -403,6 +407,7 @@ class PartitionedGraph:
         return out
 
 
+@tracing.span(tracing.PARTITION)
 def partition_graph(g: Graph, P: int, *, seed: int = 0,
                     permute: bool = False, halo: int = 1) -> PartitionedGraph:
     """Block-partition `g` onto P processors and build the device layout.
@@ -444,146 +449,151 @@ def partition_graph(g: Graph, P: int, *, seed: int = 0,
     n_local = (offs[1:] - offs[:-1]).astype(np.int32)
     n_local_max = int(n_local.max())
 
-    # pass 1: per-shard edge slices, halo sets (the remote vertices whose
-    # colors this shard reads) and, at halo=2, the strict two-hop pair lists
-    ghosts_of: list[np.ndarray] = []
-    edge_of: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
-    hop2: list[tuple[np.ndarray, np.ndarray, np.ndarray] | None] = []
-    for p in range(P):
-        lo, hi = int(offs[p]), int(offs[p + 1])
-        nl = hi - lo
-        nbrs = g.indices[g.indptr[lo] : g.indptr[hi]]
-        row = np.repeat(np.arange(nl, dtype=np.int32),
-                        np.diff(g.indptr[lo : hi + 1]).astype(np.int32))
-        remote = (nbrs < lo) | (nbrs >= hi)
-        edge_of.append((nbrs, row, remote))
-        if halo == 1:
-            # ghosts: unique remote neighbours (searchsorted-friendly order)
-            ghosts_of.append(np.unique(nbrs[remote]))
-            hop2.append(None)
-        else:
-            row2, nb2 = _two_hop_pairs(g, lo, row, nbrs)
-            rem2 = (nb2 < lo) | (nb2 >= hi)
-            ghosts_of.append(np.unique(np.concatenate(
-                [nbrs[remote], nb2[rem2]])))
-            hop2.append((row2, nb2, rem2))
+    with jax.profiler.TraceAnnotation(tracing.PARTITION_HALO):
+        # pass 1: per-shard edge slices, halo sets (the remote vertices whose
+        # colors this shard reads) and, at halo=2, the strict two-hop pair lists
+        ghosts_of: list[np.ndarray] = []
+        edge_of: list[tuple[np.ndarray, np.ndarray, np.ndarray]] = []
+        hop2: list[tuple[np.ndarray, np.ndarray, np.ndarray] | None] = []
+        for p in range(P):
+            lo, hi = int(offs[p]), int(offs[p + 1])
+            nl = hi - lo
+            nbrs = g.indices[g.indptr[lo] : g.indptr[hi]]
+            row = np.repeat(np.arange(nl, dtype=np.int32),
+                            np.diff(g.indptr[lo : hi + 1]).astype(np.int32))
+            remote = (nbrs < lo) | (nbrs >= hi)
+            edge_of.append((nbrs, row, remote))
+            if halo == 1:
+                # ghosts: unique remote neighbours (searchsorted-friendly order)
+                ghosts_of.append(np.unique(nbrs[remote]))
+                hop2.append(None)
+            else:
+                row2, nb2 = _two_hop_pairs(g, lo, row, nbrs)
+                rem2 = (nb2 < lo) | (nb2 >= hi)
+                ghosts_of.append(np.unique(np.concatenate(
+                    [nbrs[remote], nb2[rem2]])))
+                hop2.append((row2, nb2, rem2))
 
-    # boundary = local vertices some other shard reads, i.e. members of
-    # another shard's halo set.  At halo=1 this is exactly "has a remote
-    # neighbour" (the adjacency is symmetric); at halo=2 it widens to the
-    # two-hop fringe.
-    read_remote = np.zeros(g.n, dtype=bool)
-    for gh in ghosts_of:
-        read_remote[gh] = True
+        # boundary = local vertices some other shard reads, i.e. members of
+        # another shard's halo set.  At halo=1 this is exactly "has a remote
+        # neighbour" (the adjacency is symmetric); at halo=2 it widens to the
+        # two-hop fringe.
+        read_remote = np.zeros(g.n, dtype=bool)
+        for gh in ghosts_of:
+            read_remote[gh] = True
 
-    rows_indptr, rows_indices, rows_src = [], [], []
-    rows_boundary, rows_gowner = [], []
-    rows_internal, rows_degree = [], []
-    n_ghost = np.zeros(P, dtype=np.int32)
-    n_boundary = np.zeros(P, dtype=np.int32)
+        rows_indptr, rows_indices, rows_src = [], [], []
+        rows_boundary, rows_gowner = [], []
+        rows_internal, rows_degree = [], []
+        n_ghost = np.zeros(P, dtype=np.int32)
+        n_boundary = np.zeros(P, dtype=np.int32)
 
-    for p in range(P):
-        lo, hi = int(offs[p]), int(offs[p + 1])
-        nl = hi - lo
-        nbrs, row, remote = edge_of[p]
-        gh = ghosts_of[p]
-        slots = np.where(remote, 0, nbrs - lo).astype(np.int32)
-        if remote.any():
-            slots[remote] = (n_local_max
-                             + np.searchsorted(gh, nbrs[remote])).astype(
-                                 np.int32)
-        is_bnd = read_remote[lo:hi].copy()
-        bnd = np.nonzero(is_bnd)[0].astype(np.int32)
-        n_boundary[p] = len(bnd)
-        n_ghost[p] = len(gh)
+        for p in range(P):
+            lo, hi = int(offs[p]), int(offs[p + 1])
+            nl = hi - lo
+            nbrs, row, remote = edge_of[p]
+            gh = ghosts_of[p]
+            slots = np.where(remote, 0, nbrs - lo).astype(np.int32)
+            if remote.any():
+                slots[remote] = (n_local_max
+                                 + np.searchsorted(gh, nbrs[remote])).astype(
+                                     np.int32)
+            is_bnd = read_remote[lo:hi].copy()
+            bnd = np.nonzero(is_bnd)[0].astype(np.int32)
+            n_boundary[p] = len(bnd)
+            n_ghost[p] = len(gh)
 
-        rows_indptr.append(np.diff(g.indptr[lo : hi + 1]).astype(np.int32))
-        rows_indices.append(slots)
-        rows_src.append(row)
-        rows_boundary.append(bnd)
-        gowner = owner_of[gh].astype(np.int32) if len(gh) else np.zeros(0, np.int32)
-        rows_gowner.append(gowner)
-        rows_internal.append(~is_bnd)
-        rows_degree.append(np.diff(g.indptr[lo : hi + 1]).astype(np.int32))
+            rows_indptr.append(np.diff(g.indptr[lo : hi + 1]).astype(np.int32))
+            rows_indices.append(slots)
+            rows_src.append(row)
+            rows_boundary.append(bnd)
+            gowner = (owner_of[gh].astype(np.int32) if len(gh)
+                      else np.zeros(0, np.int32))
+            rows_gowner.append(gowner)
+            rows_internal.append(~is_bnd)
+            rows_degree.append(np.diff(g.indptr[lo : hi + 1]).astype(np.int32))
 
-    # Resolve ghost -> (owner, slot-in-owner-boundary-payload) via one global
-    # boundary-slot table (vectorized; P=512 × millions of edges stays fast).
-    bslot_global = np.full(g.n, -1, dtype=np.int32)
-    for p in range(P):
-        lo = int(offs[p])
-        bslot_global[rows_boundary[p] + lo] = np.arange(
-            len(rows_boundary[p]), dtype=np.int32)
-    gslot_rows = [bslot_global[gh] for gh in ghosts_of]
-
-    max_ghost = max(1, int(n_ghost.max()))
-    max_boundary = max(1, int(n_boundary.max()))
-    m_local_max = max(1, max(len(r) for r in rows_indices))
-    n_slots = n_local_max + max_ghost + 1
-    sentinel = n_slots - 1
-
-    indptr = np.zeros((P, n_local_max + 1), dtype=np.int32)
-    gvid = np.full((P, n_slots), -1, dtype=id_dt)
-    prio = np.full((P, n_slots), -1, dtype=id_dt)
-    is_internal = np.zeros((P, n_local_max), dtype=bool)
-    degree = np.zeros((P, n_local_max), dtype=np.int32)
-    for p in range(P):
-        nl = int(n_local[p])
-        indptr[p, 1 : nl + 1] = np.cumsum(rows_indptr[p])
-        indptr[p, nl + 1 :] = indptr[p, nl]
-        gh, lo = ghosts_of[p], int(offs[p])
-        gvid[p, :nl] = np.arange(lo, lo + nl, dtype=id_dt)
-        gvid[p, n_local_max : n_local_max + len(gh)] = gh
-        prio[p, :nl] = prio_global[lo : lo + nl]
-        prio[p, n_local_max : n_local_max + len(gh)] = prio_global[gh]
-        is_internal[p, :nl] = rows_internal[p]
-        degree[p, :nl] = rows_degree[p]
-
-    # remap ghost slot-ids in `indices` (they were built against per-p ghost
-    # numbering which already starts at n_local_max) and pad
-    indices = _pad2(rows_indices, m_local_max, sentinel)
-    edge_src = _pad2(rows_src, m_local_max, n_local_max)
-
-    # ELL form of the same adjacency: nbr[p, v, k] = k-th neighbour slot of v,
-    # padded with the sentinel (color 0, ignored by the selection kernels).
-    maxd = max(1, max(int(r.max(initial=0)) for r in rows_indptr))
-    id_policy(g.n, n_local_max, maxd)  # before the ELL allocation: raises
-                                       # only past the int64 ceiling
-    nbr = np.full((P, n_local_max, maxd), sentinel, dtype=np.int32)
-    for p in range(P):
-        deg_p = rows_indptr[p].astype(np.int64)
-        starts = np.concatenate([[0], np.cumsum(deg_p)])[:-1]
-        row = rows_src[p].astype(np.int64)
-        col = np.arange(len(row), dtype=np.int64) - starts[row]
-        nbr[p, row, col] = rows_indices[p]
-    boundary = _pad2(rows_boundary, max_boundary, sentinel)
-    ghost_owner = _pad2(rows_gowner, max_ghost, 0)
-    ghost_slot = _pad2(gslot_rows, max_ghost, 0)
-
-    # strict two-hop ELL (halo=2): nbr2[p, v, k] = k-th distance-2 slot of v.
-    # Rows come pre-sorted by (v, global id) from _two_hop_pairs, so each
-    # vertex's entries are one contiguous run.
-    maxd2, nbr2 = 0, None
-    if halo == 2:
-        slot2_rows = []
+        # Resolve ghost -> (owner, slot-in-owner-boundary-payload) via one
+        # global boundary-slot table (vectorized; P=512 × millions of edges
+        # stays fast).
+        bslot_global = np.full(g.n, -1, dtype=np.int32)
         for p in range(P):
             lo = int(offs[p])
-            row2, nb2, rem2 = hop2[p]
-            slot2 = np.where(rem2, 0, nb2 - lo).astype(np.int32)
-            if rem2.any():
-                slot2[rem2] = (n_local_max + np.searchsorted(
-                    ghosts_of[p], nb2[rem2])).astype(np.int32)
-            slot2_rows.append((row2, slot2))
-            cnt = np.bincount(row2, minlength=1)
-            maxd2 = max(maxd2, int(cnt.max(initial=0)))
-        maxd2 = max(1, maxd2)
-        id_policy(g.n, n_local_max, maxd, maxd2)
-        nbr2 = np.full((P, n_local_max, maxd2), sentinel, dtype=np.int32)
+            bslot_global[rows_boundary[p] + lo] = np.arange(
+                len(rows_boundary[p]), dtype=np.int32)
+        gslot_rows = [bslot_global[gh] for gh in ghosts_of]
+
+    with jax.profiler.TraceAnnotation(tracing.PARTITION_ELL):
+        max_ghost = max(1, int(n_ghost.max()))
+        max_boundary = max(1, int(n_boundary.max()))
+        m_local_max = max(1, max(len(r) for r in rows_indices))
+        n_slots = n_local_max + max_ghost + 1
+        sentinel = n_slots - 1
+
+        indptr = np.zeros((P, n_local_max + 1), dtype=np.int32)
+        gvid = np.full((P, n_slots), -1, dtype=id_dt)
+        prio = np.full((P, n_slots), -1, dtype=id_dt)
+        is_internal = np.zeros((P, n_local_max), dtype=bool)
+        degree = np.zeros((P, n_local_max), dtype=np.int32)
         for p in range(P):
-            row2, slot2 = slot2_rows[p]
-            cnt = np.bincount(row2, minlength=n_local_max).astype(np.int64)
-            starts2 = np.concatenate([[0], np.cumsum(cnt)])[:-1]
-            col = np.arange(len(row2), dtype=np.int64) - starts2[row2]
-            nbr2[p, row2, col] = slot2
+            nl = int(n_local[p])
+            indptr[p, 1 : nl + 1] = np.cumsum(rows_indptr[p])
+            indptr[p, nl + 1 :] = indptr[p, nl]
+            gh, lo = ghosts_of[p], int(offs[p])
+            gvid[p, :nl] = np.arange(lo, lo + nl, dtype=id_dt)
+            gvid[p, n_local_max : n_local_max + len(gh)] = gh
+            prio[p, :nl] = prio_global[lo : lo + nl]
+            prio[p, n_local_max : n_local_max + len(gh)] = prio_global[gh]
+            is_internal[p, :nl] = rows_internal[p]
+            degree[p, :nl] = rows_degree[p]
+
+        # remap ghost slot-ids in `indices` (they were built against per-p ghost
+        # numbering which already starts at n_local_max) and pad
+        indices = _pad2(rows_indices, m_local_max, sentinel)
+        edge_src = _pad2(rows_src, m_local_max, n_local_max)
+
+        # ELL form of the same adjacency: nbr[p, v, k] = k-th neighbour slot
+        # of v, padded with the sentinel (color 0, ignored by the selection
+        # kernels).
+        maxd = max(1, max(int(r.max(initial=0)) for r in rows_indptr))
+        id_policy(g.n, n_local_max, maxd)  # before the ELL allocation: raises
+                                           # only past the int64 ceiling
+        nbr = np.full((P, n_local_max, maxd), sentinel, dtype=np.int32)
+        for p in range(P):
+            deg_p = rows_indptr[p].astype(np.int64)
+            starts = np.concatenate([[0], np.cumsum(deg_p)])[:-1]
+            row = rows_src[p].astype(np.int64)
+            col = np.arange(len(row), dtype=np.int64) - starts[row]
+            nbr[p, row, col] = rows_indices[p]
+        boundary = _pad2(rows_boundary, max_boundary, sentinel)
+        ghost_owner = _pad2(rows_gowner, max_ghost, 0)
+        ghost_slot = _pad2(gslot_rows, max_ghost, 0)
+
+        # strict two-hop ELL (halo=2): nbr2[p, v, k] = k-th distance-2 slot
+        # of v.  Rows come pre-sorted by (v, global id) from _two_hop_pairs,
+        # so each vertex's entries are one contiguous run.
+        maxd2, nbr2 = 0, None
+        if halo == 2:
+            slot2_rows = []
+            for p in range(P):
+                lo = int(offs[p])
+                row2, nb2, rem2 = hop2[p]
+                slot2 = np.where(rem2, 0, nb2 - lo).astype(np.int32)
+                if rem2.any():
+                    slot2[rem2] = (n_local_max + np.searchsorted(
+                        ghosts_of[p], nb2[rem2])).astype(np.int32)
+                slot2_rows.append((row2, slot2))
+                cnt = np.bincount(row2, minlength=1)
+                maxd2 = max(maxd2, int(cnt.max(initial=0)))
+            maxd2 = max(1, maxd2)
+            id_policy(g.n, n_local_max, maxd, maxd2)
+            nbr2 = np.full((P, n_local_max, maxd2), sentinel, dtype=np.int32)
+            for p in range(P):
+                row2, slot2 = slot2_rows[p]
+                cnt = np.bincount(row2, minlength=n_local_max).astype(np.int64)
+                starts2 = np.concatenate([[0], np.cumsum(cnt)])[:-1]
+                col = np.arange(len(row2), dtype=np.int64) - starts2[row2]
+                nbr2[p, row2, col] = slot2
 
     return PartitionedGraph(
         P=P, n_global=g.n, n_local_max=n_local_max, max_ghost=max_ghost,
@@ -855,6 +865,7 @@ def bucket_graphs(pgs, *, round_pow2: bool = True) -> list:
     return buckets
 
 
+@tracing.span(tracing.COMM_PLAN)
 def build_comm_plan(pg: PartitionedGraph, *,
                     quantize: bool | None = None) -> CommPlan:
     """Derive the sparse neighbour-to-neighbour schedule from the ghosts.

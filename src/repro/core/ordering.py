@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import tracing
+
 from .graph import PartitionedGraph
 
 NATURAL = "natural"
@@ -58,6 +60,7 @@ def _sl_local(pg: PartitionedGraph, p: int) -> np.ndarray:
     return order
 
 
+@tracing.span(tracing.ORDER)
 def compute_order(pg: PartitionedGraph, kind: str, *, seed: int = 0) -> np.ndarray:
     """(P, n_local_max) int32 visit order of local slots, padded with -1."""
     P, nmax = pg.P, pg.n_local_max

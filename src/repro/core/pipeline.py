@@ -56,6 +56,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
+
 from . import ordering
 from .comm import (ALLGATHER, AUTO, AXIS, SPARSE, AxisComm,
                    allgather_bytes_per_exchange, batch_axis_of,
@@ -234,6 +236,7 @@ def recolor_carry_init(arrs, view, cfg: PipelineConfig,
             jnp.int32(0), hist0, sizes0, oor0)
 
 
+@tracing.scope(tracing.RECOLOR)
 def recolor_loop_spmd(arrs, view, key, cfg: PipelineConfig,
                       P_size: int | None = None, plan_static=None,
                       axis: str = AXIS, lane_axes: tuple = ()):
@@ -285,6 +288,7 @@ def pipeline_carry_spmd(arrs, order, color_key, cfg: PipelineConfig,
     return carry, cstats
 
 
+@tracing.scope(tracing.RECOLOR)
 def pipeline_step_spmd(arrs, carry, key, cfg: PipelineConfig, chunk: int,
                        P_size: int | None = None, plan_static=None,
                        axis: str = AXIS, lane_axes: tuple = ()):
@@ -369,8 +373,9 @@ def _pipeline_arrays(pg: PartitionedGraph, cfg: PipelineConfig) -> dict:
     cache = pg.__dict__.setdefault("_device_arrays", {})
     sparse = cfg.needs_sparse_plan
     if sparse not in cache:
-        cache[sparse] = {k: jnp.asarray(v)
-                         for k, v in pg.arrays(sparse=sparse).items()}
+        with jax.profiler.TraceAnnotation(tracing.UPLOAD):
+            cache[sparse] = {k: jnp.asarray(v)
+                             for k, v in pg.arrays(sparse=sparse).items()}
     return cache[sparse]
 
 
@@ -462,6 +467,13 @@ def program_cache_stats() -> dict:
 def program_cache_clear() -> None:
     """Drop every cached program and zero the counters (tests/benchmarks)."""
     _PROGRAMS.clear()
+
+
+def _dispatch(sig: PlanSignature, build, *args):
+    """Look the program up (building it on a miss) and dispatch it; its
+    first call compiles, inside the span."""
+    with jax.profiler.TraceAnnotation(tracing.PROGRAM):
+        return _PROGRAMS.get(sig, build)(*args)
 
 
 def _count_traces(fn):
@@ -606,9 +618,9 @@ def recolor_loop_sim(pg: PartitionedGraph, view, cfg: PipelineConfig,
         return jax.jit(_count_traces(
             lambda arrs, view, key: run_sim(fn, P, (arrs, view), (key,))))
 
-    view, hist, n_run = _PROGRAMS.get(sig, build)(arrs, jnp.asarray(view),
-                                                  key)
-    hist, n_run = jax.device_get((hist, n_run))     # one host transfer
+    view, hist, n_run = _dispatch(sig, build, arrs, jnp.asarray(view), key)
+    with jax.profiler.TraceAnnotation(tracing.WAIT):
+        hist, n_run = jax.device_get((hist, n_run))  # one host transfer
     return view, _history_to_host(hist), int(np.max(n_run))
 
 
@@ -624,7 +636,8 @@ def _pipeline_result(view, cstats, hist, n_run):
     # shard-max the stats on device, then cross to the host once: stats,
     # history and iteration count ride a single device_get
     cmax = {k: jnp.max(v) for k, v in cstats.items()}
-    cmax, hist, n_run = jax.device_get((cmax, hist, n_run))
+    with jax.profiler.TraceAnnotation(tracing.WAIT):
+        cmax, hist, n_run = jax.device_get((cmax, hist, n_run))
     return view, dict(color={k: int(v) for k, v in cmax.items()},
                       history=_history_to_host(hist),
                       n_iters_run=int(np.max(n_run)))
@@ -644,10 +657,9 @@ def pipeline_sim(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
     (adaptive stop included).  ``pipeline_sharded`` is the
     bitwise-identical ``workers``-mesh variant.
     """
-    fn, args = pipeline_sim_program(pg, order, cfg, marked=marked,
-                                    color_key=color_key,
-                                    recolor_key=recolor_key)
-    return _pipeline_result(*fn(*args))
+    sig, build, args = _pipeline_sim_parts(pg, order, cfg, marked,
+                                           color_key, recolor_key)
+    return _pipeline_result(*_dispatch(sig, build, *args))
 
 
 def pipeline_sim_program(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
@@ -656,6 +668,13 @@ def pipeline_sim_program(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
     arguments: ``(fn, args)``.  ``fn.lower(*args).compile()`` compiles
     exactly what ``pipeline_sim`` runs (compile timing, HLO inspection,
     compiling for a described device from the arguments' shapes)."""
+    sig, build, args = _pipeline_sim_parts(pg, order, cfg, marked,
+                                           color_key, recolor_key)
+    return _PROGRAMS.get(sig, build), args
+
+
+def _pipeline_sim_parts(pg, order, cfg, marked, color_key, recolor_key):
+    """``(signature, build, args)`` of ``pipeline_sim``'s program."""
     assert cfg.color is not None, "pipeline_sim needs cfg.color"
     cfg = resolve_pipeline_cfg(pg, cfg)
     arrs = _pipeline_arrays(pg, cfg)
@@ -670,7 +689,7 @@ def pipeline_sim_program(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
             lambda arrs, order, ck, rk: run_sim(fn, P, (arrs, order),
                                                 (ck, rk))))
 
-    return _PROGRAMS.get(sig, build), (arrs, jnp.asarray(order), ck, rk)
+    return sig, build, (arrs, jnp.asarray(order), ck, rk)
 
 
 def pipeline_sharded(pg: PartitionedGraph, order, cfg: PipelineConfig, mesh,
@@ -694,7 +713,7 @@ def pipeline_sharded(pg: PartitionedGraph, order, cfg: PipelineConfig, mesh,
             lambda a, o, k1, k2: run_sharded(fn, mesh, (a, o), (k1, k2),
                                              axis=axis)))
 
-    out = _PROGRAMS.get(sig, build)(arrs, jnp.asarray(order), ck, rk)
+    out = _dispatch(sig, build, arrs, jnp.asarray(order), ck, rk)
     return _pipeline_result(*out)
 
 

@@ -58,6 +58,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.kernels import ops
 
 from .comm import (AUTO, AXIS, DEFAULT_SCHEME, SCHEME_CHOICES, SCHEMES,
@@ -387,10 +388,13 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
         active = jnp.arange(chunk, dtype=jnp.int32) < local_sizes[t] - j * chunk
         rows = jax.lax.dynamic_slice(sorted_pad, (pos,), (chunk,))
         rows = jnp.where(active, rows, 0)
-        nbr_colors = new_view[nbr[rows]]                 # (chunk, maxd) gather
+        with jax.named_scope(tracing.NBR_GATHER):
+            nbr_colors = new_view[nbr[rows]]             # (chunk, maxd)
+            if cfg.distance == 2:
+                nbr2_colors = new_view[arrs["nbr2"][rows]]
         if cfg.distance == 2:
             colors = ops.select_colors_d2(
-                nbr_colors, new_view[arrs["nbr2"][rows]], active,
+                nbr_colors, nbr2_colors, active,
                 max_colors=mc, selection=ops.FIRST_FIT, backend=cfg.backend)
         else:
             colors = ops.select_colors(nbr_colors, active, max_colors=mc,
@@ -438,6 +442,7 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
     return new_view, stats
 
 
+@tracing.scope(tracing.RECOLOR)
 def recolor_spmd(arrs, view, key, perm_kind: str, cfg: RecolorConfig,
                  P_size: int | None = None, plan_static=None,
                  axis: str = AXIS):

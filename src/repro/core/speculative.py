@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.kernels import ops
 
 from . import selection as sel
@@ -233,10 +234,13 @@ def _parallel_chunk(view, usage, order_pad, rand_u32, start, arrs, p_idx,
         chunk = jax.lax.dynamic_slice(order_pad, (start + ti * tile,), (tile,))
         v_safe = jnp.maximum(chunk, 0)
         active = (chunk >= 0) & (view[v_safe] == 0)
-        nbr_colors = view[arrs["nbr"][v_safe]]       # (tile, maxd)
+        with jax.named_scope(tracing.NBR_GATHER):
+            nbr_colors = view[arrs["nbr"][v_safe]]   # (tile, maxd)
+            if cfg.distance == 2:
+                nbr2_colors = view[arrs["nbr2"][v_safe]]
         if cfg.distance == 2:
             colors = ops.select_colors_d2(
-                nbr_colors, view[arrs["nbr2"][v_safe]], active,
+                nbr_colors, nbr2_colors, active,
                 rand_u32[v_safe], max_colors=cfg.max_colors,
                 selection=cfg.selection, x=cfg.random_x, offset=offset,
                 backend=cfg.backend)
@@ -281,15 +285,16 @@ def _detect_conflicts_frontier(view, arrs, order_pad, n_steps, n_need,
         pos = si * superstep + jnp.arange(superstep, dtype=jnp.int32)
         active = (rows >= 0) & (pos < n_need)
         r_safe = jnp.maximum(rows, 0)
+        with jax.named_scope(tracing.NBR_GATHER):
+            tiles = (view[nbr[r_safe]], prio[nbr[r_safe]])
+            if distance == 2:
+                nbr2 = arrs["nbr2"]
+                tiles += (view[nbr2[r_safe]], prio[nbr2[r_safe]])
         if distance == 2:
-            nbr2 = arrs["nbr2"]
-            conf = ops.detect_conflicts_d2(
-                view[r_safe], prio[r_safe], view[nbr[r_safe]],
-                prio[nbr[r_safe]], view[nbr2[r_safe]], prio[nbr2[r_safe]],
-                active, backend=backend)
+            conf = ops.detect_conflicts_d2(view[r_safe], prio[r_safe],
+                                           *tiles, active, backend=backend)
         else:
-            conf = ops.detect_conflicts(view[r_safe], prio[r_safe],
-                                        view[nbr[r_safe]], prio[nbr[r_safe]],
+            conf = ops.detect_conflicts(view[r_safe], prio[r_safe], *tiles,
                                         active, backend=backend)
         idx = jnp.where(conf, r_safe, n_slots - 1)   # sentinel stays 0
         new_view = new_view.at[idx].set(0)
@@ -314,6 +319,7 @@ def _compact_order(order, view):
     return order[perm], jnp.sum(needs, dtype=jnp.int32)
 
 
+@tracing.scope(tracing.SPEC_COLOR)
 def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
                plan_static=None, axis: str = AXIS, lane_axes: tuple = ()):
     """Per-shard SPMD speculative coloring. Returns (view, stats dict).

@@ -29,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 from .firstfit import (TILE_V, color_select_pallas, conflict_pallas,
                        forbidden_words, select_from_words)
 
@@ -71,6 +73,7 @@ def _flatten(tiles, rows: dict):
     return flat_t, flat_r, lambda out: jnp.reshape(out, lead + (v,))
 
 
+@tracing.scope(tracing.NBR_GATHER)
 def _kernel_operands(tiles, rows, fills):
     """Pad rows to a TILE_V multiple and lay them out for the kernels.
 
