@@ -6,9 +6,11 @@ shipped entry points at P=2 and asserts on the traced program itself:
 1. **Shard-uniform collective sequence** — the ordered list of collective
    primitives (psum/pmax/ppermute/all_gather/...) in the per-shard
    program must be exactly the list in the vmapped (``run_sim``) and
-   graph-batched (``color_many`` inner) programs, for every exchange
-   scheme.  A shard- or lane-dependent collective would show up as a
-   sequence mismatch — the static moral equivalent of a deadlock.
+   graph-batched (``color_many``) programs, for every exchange scheme,
+   less the graph-batched program's reductions over its named lane axis
+   (the lane-uniform control values).  A shard- or lane-dependent
+   collective would show up as a sequence mismatch — the static moral
+   equivalent of a deadlock.
 2. **Scheme resolution** — ``scheme="auto"`` must trace to bitwise the
    program of whichever concrete scheme ``resolve_scheme`` picks: same
    collective sequence, nothing else.
@@ -96,9 +98,28 @@ def prim_sequence(closed_jaxpr) -> tuple:
     return tuple(out)
 
 
-def collective_sequence(closed_jaxpr) -> tuple:
-    return tuple(p for p in prim_sequence(closed_jaxpr)
-                 if p in COLLECTIVE_PRIMS)
+def _eqn_axes(eqn) -> tuple:
+    axes = eqn.params.get("axes", eqn.params.get("axis_name", ()))
+    return tuple(axes) if isinstance(axes, (tuple, list)) else (axes,)
+
+
+def _walk_collectives(jaxpr, skip: frozenset, out: list) -> None:
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in COLLECTIVE_PRIMS:
+            axes = set(_eqn_axes(eqn))
+            if not (axes and axes <= skip):
+                out.append(eqn.primitive.name)
+        for sub in _param_jaxprs(eqn.params):
+            _walk_collectives(sub, skip, out)
+
+
+def collective_sequence(closed_jaxpr, skip_axes: tuple = ()) -> tuple:
+    """Collective primitives in program order; those over ``skip_axes``
+    alone (by name) are left out."""
+    out: list = []
+    _walk_collectives(getattr(closed_jaxpr, "jaxpr", closed_jaxpr),
+                      frozenset(skip_axes), out)
+    return tuple(out)
 
 
 def callback_prims(closed_jaxpr) -> tuple:
@@ -121,13 +142,14 @@ def _entry_jaxprs(pg, cfg, P, jax):
 
     ``pipe`` / ``loop`` are the per-shard SPMD programs behind
     ``pipeline_sim`` / ``recolor_loop_sim``; ``pipe_vmap`` is the
-    ``run_sim`` lane-stacked program and ``many`` the graph-batched
-    ``color_many`` inner program — the sequence equality between them is
-    check (1).
+    ``run_sim`` lane-stacked program, ``lanes_vmap`` the same with the
+    graph-lane axis named (traced as one lane), and ``many`` the
+    graph-batched ``color_many`` program, which vmaps ``lanes_vmap`` over
+    that axis — the sequence equalities between them are check (1).
     """
     import numpy as np
 
-    from ..core.comm import AXIS, run_sim
+    from ..core.comm import AXIS, LANE_AXIS, run_sim
     from ..core.pipeline import (_plan_static, color_then_recolor,
                                  recolor_loop_spmd)
 
@@ -149,7 +171,12 @@ def _entry_jaxprs(pg, cfg, P, jax):
     stack = lambda s: jax.ShapeDtypeStruct((P,) + tuple(s.shape), s.dtype)
     full_arrs = {k: stack(v) for k, v in shard_arrs.items()}
     pipe_vmap = lambda a, o, ck, rk: run_sim(pipe, P, (a, o), (ck, rk))
-    many = jax.vmap(pipe_vmap, in_axes=(0, 0, 0, 0))
+    pipe_lanes = lambda a, o, ck, rk: color_then_recolor(
+        a, o, ck, rk, cfg=cfg, P_size=P, plan_static=ps,
+        lane_axes=(LANE_AXIS,))
+    lanes_vmap = lambda a, o, ck, rk: run_sim(pipe_lanes, P, (a, o),
+                                              (ck, rk))
+    many = jax.vmap(lanes_vmap, in_axes=(0, 0, 0, 0), axis_name=LANE_AXIS)
     batch = lambda s: jax.ShapeDtypeStruct((2,) + tuple(s.shape), s.dtype)
     keys2 = jax.numpy.stack([key, jax.random.key(1)])
 
@@ -160,6 +187,9 @@ def _entry_jaxprs(pg, cfg, P, jax):
             shard_arrs, view, key),
         "pipe_vmap": jax.make_jaxpr(pipe_vmap)(
             full_arrs, stack(order), key, key),
+        "lanes_vmap": jax.make_jaxpr(lanes_vmap,
+                                     axis_env=[(LANE_AXIS, 2)])(
+            full_arrs, stack(order), key, key),
         "many": jax.make_jaxpr(many)(
             {k: batch(v) for k, v in full_arrs.items()},
             batch(stack(order)), keys2, keys2),
@@ -169,7 +199,8 @@ def _entry_jaxprs(pg, cfg, P, jax):
 def _audit_collectives(audit: TraceAudit, pg, base_cfg, P, jax) -> None:
     import dataclasses as dc
 
-    from ..core.comm import ALLGATHER, AUTO, SPARSE, resolve_scheme
+    from ..core.comm import (ALLGATHER, AUTO, LANE_AXIS, SPARSE,
+                             resolve_scheme)
     from ..core.pipeline import resolve_pipeline_cfg
 
     def with_scheme(scheme):
@@ -182,6 +213,9 @@ def _audit_collectives(audit: TraceAudit, pg, base_cfg, P, jax) -> None:
     for scheme in (SPARSE, ALLGATHER, AUTO):
         jaxprs = _entry_jaxprs(pg, with_scheme(scheme), P, jax)
         seqs[scheme] = {n: collective_sequence(j) for n, j in jaxprs.items()}
+        # the lane program less its lane-uniform reductions
+        seqs[scheme]["lanes_vmap/shard"] = collective_sequence(
+            jaxprs["lanes_vmap"], skip_axes=(LANE_AXIS,))
         for name, j in jaxprs.items():
             cbs = callback_prims(j)
             detail = (f"{name}/{scheme}: callback-free fused program"
@@ -191,8 +225,9 @@ def _audit_collectives(audit: TraceAudit, pg, base_cfg, P, jax) -> None:
     # Under run_sim's lane-vmap, shuffles (ppermute/all_gather/axis_index)
     # lower into lane gathers; cross-shard *reductions* keep their
     # primitive.  So the shard-uniformity pin is: the ordered reduction
-    # subsequence survives batching bit-for-bit, and adding the graph
-    # batch axis (color_many) changes nothing at all.
+    # subsequence survives batching bit-for-bit; naming the graph-lane
+    # axis adds only reductions over that axis (the lane-uniform control
+    # values), and batching over it (color_many) adds nothing more.
     reductions = {"psum", "pmax", "pmin", "pmean"}
     red = lambda seq: tuple(p for p in seq if p in reductions)
     for scheme in (SPARSE, ALLGATHER):
@@ -209,13 +244,15 @@ def _audit_collectives(audit: TraceAudit, pg, base_cfg, P, jax) -> None:
                f"reduction(s))" if same else
                f"diverges: {red(seqs[scheme]['pipe_vmap'])[:8]} vs "
                f"{red(per_shard)[:8]}"))
-        same = seqs[scheme]["many"] == seqs[scheme]["pipe_vmap"]
-        audit.record(
-            "batch-invariant-sequence", same,
-            f"many/{scheme} collective sequence "
-            + ("identical to the single-graph lane program" if same else
-               f"diverges: {seqs[scheme]['many'][:8]} vs "
-               f"{seqs[scheme]['pipe_vmap'][:8]}"))
+        for name, want in (("lanes_vmap/shard", "pipe_vmap"),
+                           ("many", "lanes_vmap")):
+            same = seqs[scheme][name] == seqs[scheme][want]
+            audit.record(
+                "batch-invariant-sequence", same,
+                f"{name}/{scheme} collective sequence "
+                + (f"identical to {want}'s" if same else
+                   f"diverges: {seqs[scheme][name][:8]} vs "
+                   f"{seqs[scheme][want][:8]}"))
 
     resolved = resolve_scheme(AUTO, pg)
     for name in ("pipe", "loop", "pipe_vmap", "many"):

@@ -41,6 +41,7 @@ from repro import tracing
 
 AXIS = "workers"        # default shard (graph-partition) mesh axis
 BATCH_AXIS = "batch"    # graph-batch mesh axis of 2D batch×shard meshes
+LANE_AXIS = "lanes"     # vmap axis of the graph lanes one device holds
 
 ALLGATHER = "allgather"
 SPARSE = "sparse"
@@ -118,6 +119,10 @@ class AxisComm:
 
     def index(self):
         return jax.lax.axis_index(self.axis)
+
+    def size(self) -> int:
+        """Number of shards on the axis (static: no collective)."""
+        return jax.lax.axis_size(self.axis)
 
     def lane_uniform(self, x):
         """Max-reduce a shard-uniform control value over the lane axes.

@@ -25,9 +25,9 @@ Rokos et al. 2015) applied to the iteration loop itself:
   color count has failed to improve for ``patience`` consecutive iterations
   (the paper's time-quality knob; ``patience=0`` always runs all K);
 - per-iteration stats land in a device-resident ``(K, len(HISTORY_STATS))``
-  int32 history (colors, distinct colors, exchanges, supersteps, wire bytes,
-  out-of-range count, permutation id, ran flag), unpacked **once** at the
-  end — the only host sync of the whole run.
+  int32 history (colors, distinct colors, exchanges, gathered ELL slots,
+  supersteps, wire bytes, out-of-range count, permutation id, ran flag),
+  unpacked **once** at the end — the only host sync of the whole run.
 
 ``recolor_iterations`` is a thin wrapper over the recolor-only loop
 (``recolor_loop_sim``); the host loop survives behind ``fused=False`` as the
@@ -40,11 +40,13 @@ small-to-medium graphs (per-batch conflict graphs, per-tile sparsity
 patterns), not one giant one.  ``bucket_graphs`` pads the partitions into
 shape buckets; within a bucket the fused program is lifted over a leading
 graph axis with ``vmap`` — per-graph RNG keys, per-graph ``(K, n_stats)``
-histories, and a per-graph adaptive stop: ``vmap`` of ``lax.while_loop``
-runs while *any* graph's predicate holds and select-masks the body on
-finished lanes, so each lane's result is bitwise the solo run's
-(tests/test_serve.py pins this per graph, across bucket boundaries, both
-exchange schemes, distance 1 and 2).
+histories, and a per-graph adaptive stop.  The graph axis is a named vmap
+axis (``comm.LANE_AXIS``) in the program's ``lane_axes``, so its control
+values are lane-uniform as across a 2D mesh (DESIGN.md §10): the loops run
+while *any* graph's predicate holds, a finished lane freezes its carry or
+runs bitwise no-op bodies, and each lane's result is bitwise the solo
+run's (tests/test_serve.py pins this per graph, across bucket boundaries,
+both exchange schemes, distance 1 and 2).
 """
 from __future__ import annotations
 
@@ -59,7 +61,7 @@ import numpy as np
 from repro import tracing
 
 from . import ordering
-from .comm import (ALLGATHER, AUTO, AXIS, SPARSE, AxisComm,
+from .comm import (ALLGATHER, AUTO, AXIS, LANE_AXIS, SPARSE, AxisComm,
                    allgather_bytes_per_exchange, batch_axis_of,
                    batch_axis_size, mesh_axes, run_sharded, run_sharded_many,
                    run_sim, shard_axis_of, stats_to_host)
@@ -73,8 +75,8 @@ from .speculative import ColorConfig, _apply_partial, color_spmd, resolve_cfg
 # Column layout of the device-resident per-iteration history.  ``ran`` marks
 # rows the adaptive stop never reached (they stay zero).
 HISTORY_STATS = ("n_colors", "n_colors_distinct", "n_colors_before",
-                 "n_exchanges", "n_steps", "wire_bytes", "n_out_of_range",
-                 "perm_id", "ran")
+                 "n_exchanges", "gather_slots", "n_steps", "wire_bytes",
+                 "n_out_of_range", "perm_id", "ran")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,8 +201,9 @@ def _recolor_loop_fns(arrs, key, cfg: PipelineConfig,
                                       comm)
         nd_after = jnp.sum(sizes > 0).astype(jnp.int32)
         row = jnp.stack([st["n_colors"], nd_after, n_classes,
-                         st["n_exchanges"], st["n_steps"], st["wire_bytes"],
-                         n_oor, kid, jnp.int32(1)]).astype(jnp.int32)
+                         st["n_exchanges"], st["gather_slots"], st["n_steps"],
+                         st["wire_bytes"], n_oor, kid,
+                         jnp.int32(1)]).astype(jnp.int32)
         hist = jax.lax.dynamic_update_slice(hist, row[None],
                                             (it - 1, jnp.int32(0)))
         improved = nd_after < best
@@ -718,16 +721,28 @@ def pipeline_sharded(pg: PartitionedGraph, order, cfg: PipelineConfig, mesh,
 
 
 # ------------------------------------------- batched multi-graph pipeline --
+#
+# The graph lanes one device holds are a vmap named ``LANE_AXIS``, and the
+# lane programs carry it in ``lane_axes`` (with a 2D mesh's batch axis):
+# their control values (trip counts, exchange gates, the recolor chunk
+# width) are then lane-uniform, so each ``cond``/``switch`` stays one branch
+# taken instead of vmap's run-every-branch + select.
+
+def _mesh_lane_axes(mesh) -> tuple:
+    """``lane_axes`` of a lane program inside ``run_sharded_many``."""
+    baxis = batch_axis_of(mesh)
+    return ((baxis,) if baxis is not None else ()) + (LANE_AXIS,)
+
 
 def _many_sim_program(sig, P, cfg, plan_static):
     """One jitted program per signature: vmap over graphs of vmap over
     shards — reused across batches (and graphs) through ``_PROGRAMS``."""
     def build():
         fn = partial(color_then_recolor, cfg=cfg, P_size=P,
-                     plan_static=plan_static)
+                     plan_static=plan_static, lane_axes=(LANE_AXIS,))
         inner = lambda arrs, order, ck, rk: run_sim(fn, P, (arrs, order),
                                                     (ck, rk))
-        return jax.jit(_count_traces(jax.vmap(inner)))
+        return jax.jit(_count_traces(jax.vmap(inner, axis_name=LANE_AXIS)))
     return _PROGRAMS.get(sig, build)
 
 
@@ -742,11 +757,10 @@ def _many_sharded_program(sig, P, cfg, plan_static, mesh):
     a 1D mesh this degenerates to the classic vmap-inside-shard_map."""
     def build():
         axis = shard_axis_of(mesh)
-        baxis = batch_axis_of(mesh)
-        lane_axes = (baxis,) if baxis is not None else ()
         fn = jax.vmap(partial(color_then_recolor, cfg=cfg, P_size=P,
                               plan_static=plan_static, axis=axis,
-                              lane_axes=lane_axes))
+                              lane_axes=_mesh_lane_axes(mesh)),
+                      axis_name=LANE_AXIS)
         return jax.jit(_count_traces(
             lambda a, o, k1, k2: run_sharded_many(fn, mesh, (a, o),
                                                   (k1, k2), axis=axis)))
@@ -805,16 +819,16 @@ def engine_step_program(P: int, cfg: PipelineConfig, plan_static, arrs,
     def build():
         if mesh is None:
             fn = partial(pipeline_step_spmd, cfg=cfg, chunk=chunk, P_size=P,
-                         plan_static=plan_static)
+                         plan_static=plan_static, lane_axes=(LANE_AXIS,))
             inner = lambda a, c, k: run_sim(fn, P, (a, c), (k,))
-            return jax.jit(_count_traces(jax.vmap(inner)),
+            return jax.jit(_count_traces(jax.vmap(inner,
+                                                  axis_name=LANE_AXIS)),
                            donate_argnums=(1,))
         axis = shard_axis_of(mesh)
-        baxis = batch_axis_of(mesh)
-        lane_axes = (baxis,) if baxis is not None else ()
         fn = jax.vmap(partial(pipeline_step_spmd, cfg=cfg, chunk=chunk,
                               P_size=P, plan_static=plan_static, axis=axis,
-                              lane_axes=lane_axes))
+                              lane_axes=_mesh_lane_axes(mesh)),
+                      axis_name=LANE_AXIS)
         return jax.jit(_count_traces(
             lambda a, c, k: run_sharded_many(fn, mesh, (a, c), (k,),
                                              axis=axis)),

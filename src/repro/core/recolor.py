@@ -16,6 +16,19 @@ bytes a per-step dense occupancy would scatter.  Chunk counts per class are
 pmax-reduced, so every shard runs the same loop trip count and the collective
 schedule stays uniform (a shard_map requirement).
 
+Chunks are *degree-sorted and width-bucketed*: within its class a vertex
+sorts by ELL row length (``degree``, widest first), and each chunk gathers
+its rows only as wide as its widest row on any shard, rounded up to one of
+the static widths of ``gather_widths(maxd)`` (powers of two from 8, then
+``maxd``) and dispatched by ``lax.switch``.  On skewed graphs most rows are
+far shorter than the hub that sets ``maxd``, so most chunks gather a small
+fraction of the padded tile; entries past a row's degree are sentinels, so
+the colors are bitwise those of full-width chunks.  The width is pmax-ed
+over shards and ``lane_uniform``-ed over graph lanes, so the switch stays
+one branch taken under every vmap; ``gather_slots`` in the stats counts the
+slots gathered (rows × width over chunks and shards).  Distance 2 keeps the
+one full width.
+
 Color-class permutations (§3): RV (reverse), NI (non-increasing class size),
 ND (non-decreasing — the paper's best), RAND (Knuth shuffle), and the hybrid
 schedules ND-RAND%x / ND-RAND%2^i handled by `recolor_iterations`.
@@ -128,6 +141,21 @@ class RecolorConfig:
     @property
     def comm_config(self) -> CommConfig:
         return CommConfig(scheme=self.scheme, wire16=self.wire16)
+
+
+def gather_widths(maxd: int) -> tuple:
+    """The static widths a recolor chunk may gather its ELL rows at.
+
+    The powers of two from 8 below ``maxd``, then ``maxd`` itself: a chunk
+    takes the smallest that holds its widest row, so it gathers at most
+    twice its real width (or 8 slots) instead of the graph's maximum
+    degree.  ``maxd <= 8`` leaves the one width ``maxd``.
+    """
+    out, w = [], 8
+    while w < maxd:
+        out.append(w)
+        w *= 2
+    return tuple(out) + (maxd,)
 
 
 def class_sizes(view, n_local, n_local_max, max_colors, comm: AxisComm):
@@ -294,13 +322,15 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
     fused ``pipeline.color_then_recolor`` loop (kind resolved as a traced
     branch): everything from the step map through the chunked hot loop.
 
-    Hot loop: vertices are sorted by class step; each class is consumed as
-    <= ceil(pmax(class size)/chunk) fixed-size chunks.  A chunk gathers its
-    ELL neighbour rows, gathers their current colors, and first-fit-colors
-    the whole chunk at once through ``kernels.ops.select_colors`` — no dense
-    occupancy, no scatter over the edge list.  Chunk order within a class is
-    irrelevant (a class is an independent set), and the chunk schedule is
-    identical on every shard, so collectives stay uniform.
+    Hot loop: vertices are sorted by class step (then by degree, widest
+    first); each class is consumed as <= ceil(pmax(class size)/chunk)
+    fixed-size chunks.  A chunk gathers its ELL neighbour rows at the
+    chunk's width (``gather_widths``), gathers their current colors, and
+    first-fit-colors the whole chunk at once through
+    ``kernels.ops.select_colors`` — no dense occupancy, no scatter over the
+    edge list.  Order within a class is irrelevant (a class is an
+    independent set), and the chunk schedule is identical on every shard,
+    so collectives stay uniform.
 
     Exchanges route through ``comm.make_exchange``; under the sparse scheme
     the piggyback schedule additionally masks *which ppermute rounds* each
@@ -363,12 +393,23 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
 
     valid_local = jnp.arange(n_local_max) < n_local
     step_loc = step_of[:n_local_max]
+    maxd = nbr.shape[1]
+    # distance 2 gathers the two-hop rows too, so it keeps the full width
+    widths = gather_widths(maxd) if cfg.distance == 1 else (maxd,)
+    width_arr = jnp.asarray(widths, jnp.int32)
+    degree = arrs["degree"]                  # ELL row length (real entries)
 
     # Step-sorted visit order + per-class chunk schedule.  rank values of
     # present classes are contiguous 1..n_classes, so classes t=1..n_classes
     # each get >= 1 chunk (pmax over shards keeps the trip count uniform).
+    # With several widths, rows sort by degree within their class, so each
+    # chunk holds rows of similar length and gathers only as wide as its
+    # widest row (the order within a class changes no color).
     sort_key = jnp.where(valid_local, step_loc, jnp.int32(mc + 1))
-    sorted_rows = jnp.argsort(sort_key).astype(jnp.int32)
+    if len(widths) > 1:
+        sorted_rows = jnp.lexsort((-degree, sort_key)).astype(jnp.int32)
+    else:
+        sorted_rows = jnp.argsort(sort_key).astype(jnp.int32)
     sorted_pad = jnp.concatenate([sorted_rows, jnp.zeros((chunk,), jnp.int32)])
     local_sizes = jnp.zeros((mc + 2,), jnp.int32).at[sort_key].add(1)[:mc + 1]
     start_local = jnp.cumsum(local_sizes) - local_sizes   # exclusive cumsum
@@ -380,8 +421,38 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
         jnp.maximum(chunks_per_class, 1), 0)
     cum = jnp.cumsum(chunks_per_class)     # cum[t] = chunks through class t
 
+    def recolor_at(w):
+        """Gather the chunk's neighbour colors ``w`` slots wide, first-fit
+        them and write the chunk's colors into the view.
+
+        As a ``switch`` branch, the view goes in and comes out, so the
+        compiler keeps it in on-chip memory; read only, it is left in HBM
+        and the gather slows by half (v5e).  ``cols`` are the chunk's
+        whole ELL rows, cut here: a gather of part of each row
+        (``nbr[rows, :w]``) lowers to a loop over the rows, and the ELL
+        table as a branch operand would be copied."""
+        def branch(new_view, cols, rows, active):
+            with jax.named_scope(tracing.NBR_GATHER):
+                nbr_colors = new_view[cols[:, :w]]       # (chunk, w)
+                if cfg.distance == 2:
+                    nbr2_colors = new_view[arrs["nbr2"][rows]]
+            if cfg.distance == 2:
+                colors = ops.select_colors_d2(
+                    nbr_colors, nbr2_colors, active, max_colors=mc,
+                    selection=ops.FIRST_FIT, backend=cfg.backend)
+            else:
+                colors = ops.select_colors(nbr_colors, active, max_colors=mc,
+                                           selection=ops.FIRST_FIT,
+                                           backend=cfg.backend)
+            idx = jnp.where(active, rows, n_slots - 1)   # park writes on the
+            val = jnp.where(active, colors, 0)           # sentinel (stays 0)
+            return new_view.at[idx].set(val.astype(new_view.dtype))
+        return branch
+
+    branches = [recolor_at(w) for w in widths]
+
     def chunk_body(ci, carry):
-        new_view, n_ex, n_bytes = carry
+        new_view, n_ex, n_bytes, n_gathered = carry
         t = jnp.searchsorted(cum, ci, side="right").astype(jnp.int32)
         j = ci - (cum[t] - chunks_per_class[t])          # chunk # within class
         pos = start_local[t] + j * chunk
@@ -389,20 +460,23 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
         rows = jax.lax.dynamic_slice(sorted_pad, (pos,), (chunk,))
         rows = jnp.where(active, rows, 0)
         with jax.named_scope(tracing.NBR_GATHER):
-            nbr_colors = new_view[nbr[rows]]             # (chunk, maxd)
-            if cfg.distance == 2:
-                nbr2_colors = new_view[arrs["nbr2"][rows]]
-        if cfg.distance == 2:
-            colors = ops.select_colors_d2(
-                nbr_colors, nbr2_colors, active,
-                max_colors=mc, selection=ops.FIRST_FIT, backend=cfg.backend)
+            cols = nbr[rows]                             # (chunk, maxd)
+        # the chunk's width: its widest active row on any shard, rounded up
+        # to a static width; the switch index is shard- and lane-uniform,
+        # so it stays one branch taken (a vmap over an unnamed axis would
+        # run every branch instead)
+        if len(widths) > 1:
+            wid = comm.pmax(jnp.max(jnp.where(active, degree[rows], 0)))
+            b_own = jnp.sum(width_arr < wid, dtype=jnp.int32)  # first >= wid
+            new_view = jax.lax.switch(comm.lane_uniform(b_own), branches,
+                                      new_view, cols, rows, active)
         else:
-            colors = ops.select_colors(nbr_colors, active, max_colors=mc,
-                                       selection=ops.FIRST_FIT,
-                                       backend=cfg.backend)
-        idx = jnp.where(active, rows, n_slots - 1)       # park writes on the
-        val = jnp.where(active, colors, 0)               # sentinel (stays 0)
-        new_view = new_view.at[idx].set(val.astype(new_view.dtype))
+            b_own = jnp.int32(0)
+            new_view = branches[0](new_view, cols, rows, active)
+        # this lane's own slots (as in its solo run): chunks past its
+        # schedule, run only for a batch-row peer, are not counted
+        n_gathered = n_gathered + jnp.where(ci < cum[mc],
+                                            width_arr[b_own] * chunk, 0)
         is_last = (ci + 1) == cum[t]
         is_end = t == n_classes
         do_ex = is_last & (needed[jnp.minimum(t, mc)] | is_end)
@@ -422,20 +496,21 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
                                   lambda v: (v, jnp.int32(0)), new_view)
         new_view = jnp.where(do_ex, ex_view, new_view)
         return (new_view, n_ex + do_ex.astype(jnp.int32),
-                n_bytes + jnp.where(do_ex, b, 0))
+                n_bytes + jnp.where(do_ex, b, 0), n_gathered)
 
     new_view0 = jnp.zeros((n_slots,), jnp.int32)
     # mesh-wide trip count: chunks past this lane's cum[mc] visit no active
     # rows (and never gate an exchange), so they are exact no-ops
-    new_view, n_ex, n_bytes = jax.lax.fori_loop(
+    new_view, n_ex, n_bytes, n_gathered = jax.lax.fori_loop(
         0, comm.lane_uniform(cum[mc]), chunk_body,
-        (new_view0, jnp.int32(0), jnp.int32(0)))
+        (new_view0, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
 
     local_max = jnp.max(jnp.where(valid_local, new_view[:n_local_max], 0))
     stats = dict(
         n_colors=comm.pmax(local_max),
         n_colors_before=n_classes,
         n_exchanges=n_ex,
+        gather_slots=n_gathered * comm.size(),  # the same on every shard
         n_steps=n_classes,
         wire_bytes=n_bytes,
     )
@@ -523,8 +598,9 @@ def recolor_sim(pg: PartitionedGraph, view, perm_kind: str,
     defaults to a per-call-counter fold of ``cfg.seed`` (pass an explicit
     key for reproducible RAND permutations).  Returns ``(view, stats)``
     with python-int stats: ``n_colors`` (max id), ``n_colors_distinct``,
-    ``n_colors_before``, ``n_exchanges`` (executed), ``n_steps`` (= class
-    count), ``wire_bytes``, ``n_out_of_range``.  ``recolor_sharded`` is
+    ``n_colors_before``, ``n_exchanges`` (executed), ``gather_slots`` (ELL
+    slots gathered, all shards), ``n_steps`` (= class count),
+    ``wire_bytes``, ``n_out_of_range``.  ``recolor_sharded`` is
     the bitwise-identical ``workers``-mesh variant.
     """
     cfg = resolve_cfg(pg, cfg)

@@ -237,7 +237,7 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
         # P=256 (uses all 512 host devices); the multi-pod cell keeps
         # batch=1 (512 shards already occupy every device) but still runs
         # the 2D program structure.
-        from repro.core.comm import (batch_axis_of, mesh_axes,
+        from repro.core.comm import (LANE_AXIS, batch_axis_of, mesh_axes,
                                      run_sharded_many, shard_axis_of)
         Bm = 1 if multi_pod else 2
         mesh2d = make_coloring_mesh(P, batch=Bm)
@@ -249,7 +249,9 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
         keys_b = jax.random.split(key, B)
         pfn2 = jax.vmap(partial(color_then_recolor, cfg=pcfg, P_size=P,
                                 axis=axis2,
-                                lane_axes=(batch_axis_of(mesh2d),)))
+                                lane_axes=(batch_axis_of(mesh2d),
+                                           LANE_AXIS)),
+                        axis_name=LANE_AXIS)
         t_2d = time.time()
         compiled_2d = jax.jit(
             lambda a, o, k1, k2: run_sharded_many(

@@ -12,16 +12,18 @@ partitionable threefry changed every random stream; each test checks the
 coloring valid and equal across both kernel backends (the XLA twin and the
 Pallas kernels) before comparing the pin.
 """
+import functools
 import hashlib
 
 import jax
 import numpy as np
 import pytest
 
+from repro.analysis.trace_audit import _param_jaxprs
 from repro.core import (ColorConfig, RecolorConfig, assert_valid,
                         color_graph_sim, colors_from_views, compute_order,
-                        ordering, partition_graph, recolor_sim, rmat,
-                        select_colors, selection)
+                        ordering, pad_partition, partition_graph,
+                        recolor_sim, rmat, select_colors, selection)
 from repro.kernels import ops, ref
 
 
@@ -54,6 +56,19 @@ def test_ell_matches_csr(pgraph):
             assert (ell_row[e - s:] == pg.sentinel).all()
         # padded vertex rows are all-sentinel
         assert (pg.nbr[p, nl:] == pg.sentinel).all()
+
+
+@pytest.mark.parametrize("halo,pad", [(1, False), (2, False), (1, True)],
+                         ids=["halo1", "halo2", "padded"])
+def test_degree_is_ell_row_length(graph, halo, pad):
+    """``degree`` counts a row's real ELL entries, which come first: the
+    recolor chunk gathers only its rows' widest ``degree`` slots."""
+    pg = partition_graph(graph, 4, halo=halo)
+    if pad:
+        pg = pad_partition(pg, n_local_max=pg.n_local_max + 5,
+                           maxd=pg.maxd + 3)
+    real = np.arange(pg.maxd) < pg.degree[..., None]
+    np.testing.assert_array_equal(pg.nbr != pg.sentinel, real)
 
 
 # ------------------------------------------- select_colors backend switch --
@@ -193,37 +208,173 @@ def test_parallel_mode_valid_and_backends_agree(graph, pgraph, selname):
 
 # ------------------------------------------- recolor: chunked ELL vs seed --
 
-RC_GOLD = {
-    "nd": (11, 13, "f578174af31ddb61"),
-    "rv": (11, 13, "b9f1ceb928314ffc"),
-    "rand": (12, 13, "740a3e16be12a2d7"),
-}
+def _seed_coloring(pg):
+    order = compute_order(pg, ordering.NATURAL)
+    view, _ = color_graph_sim(
+        pg, order, ColorConfig(max_colors=512, superstep=64, seed=0,
+                               parallel_chunk=False))
+    return view
 
 
 @pytest.fixture(scope="module")
 def seed_view(pgraph):
-    order = compute_order(pgraph, ordering.NATURAL)
-    view, _ = color_graph_sim(
-        pgraph, order, ColorConfig(max_colors=512, superstep=64, seed=0,
-                                   parallel_chunk=False))
-    return view
+    return _seed_coloring(pgraph)
 
 
-@pytest.mark.parametrize("perm", list(RC_GOLD))
-def test_recolor_chunked_is_seed_behavior(graph, pgraph, seed_view, perm):
-    """Chunked ELL/bitset recolor == the seed dense-occupancy recolor."""
-    runs = [recolor_sim(pgraph, seed_view, perm,
-                        RecolorConfig(max_colors=512, backend=b),
+RC_GRAPHS = {
+    "rmat10": lambda: rmat.rmat_good(10, 8, seed=3),    # maxd 72
+    "rmat11": lambda: rmat.rmat_good(11, 8, seed=3),    # maxd 80
+    "grid10": lambda: rmat.grid3d(10, 10, 10),          # maxd 26
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _rc_input(name):
+    g = RC_GRAPHS[name]()
+    pg = partition_graph(g, 4)
+    return g, pg, _seed_coloring(pg)
+
+
+def _full_width_slots(pg, view, chunk):
+    """ELL slots an iteration gathers with every chunk at ``maxd``: the
+    chunks of each class (its largest shard share over ``chunk``, at least
+    one) times ``chunk`` rows, ``maxd`` slots and P shards."""
+    view = np.asarray(view)
+    chunk = min(chunk, pg.n_local_max)
+    n = int(view.max()) + 1
+    sizes = np.stack([np.bincount(view[p, :pg.n_local[p]], minlength=n)[1:]
+                      for p in range(pg.P)]).max(axis=0)
+    n_chunks = int(np.sum(-(-sizes[sizes > 0] // chunk)))
+    return n_chunks * chunk * pg.maxd * pg.P
+
+
+# Colorings captured from the full-width recolor (before width-bucketed
+# chunks): (graph, perm, chunk) -> (n_colors, n_exchanges, colors hash), and
+# the range of gather_slots as a share of the full-width count.  Chunks of
+# 16 rows split RMAT's classes into several chunks of falling degree, as
+# 256-row chunks do at the benchmark's scale; the regular grid's chunks
+# nearly all hold a degree-26 row.
+RC_GOLD = [
+    pytest.param("rmat10", "nd", 256, (11, 13, "f578174af31ddb61"), (0, 1),
+                 id="nd"),
+    pytest.param("rmat10", "rv", 256, (11, 13, "b9f1ceb928314ffc"), (0, 1),
+                 id="rv"),
+    pytest.param("rmat10", "rand", 256, (12, 13, "740a3e16be12a2d7"),
+                 (0, 1), id="rand"),
+    pytest.param("rmat11", "nd", 16, (12, 14, "97c86cd8bb31926e"),
+                 (0, 0.5), id="skewed-nd"),
+    pytest.param("grid10", "nd", 256, (12, 9, "224462954b2511da"),
+                 (0.98, 1), id="grid-nd"),
+    pytest.param("grid10", "rv", 256, (12, 9, "e534d9d8c1ccbfba"),
+                 (0.98, 1), id="grid-rv"),
+    pytest.param("grid10", "rand", 256, (12, 12, "348a4c38424faaa4"),
+                 (0.98, 1), id="grid-rand"),
+]
+
+
+@pytest.mark.parametrize("name,perm,chunk,gold,share", RC_GOLD)
+def test_recolor_chunked_is_seed_behavior(name, perm, chunk, gold, share):
+    """Chunked ELL/bitset recolor == the seed dense-occupancy recolor, and
+    width-bucketed chunks gather fewer slots only where degrees are skewed."""
+    g, pg, view = _rc_input(name)
+    runs = [recolor_sim(pg, view, perm,
+                        RecolorConfig(max_colors=512, chunk=chunk, backend=b),
                         key=jax.random.key(11)) for b in ("xla", "pallas")]
     (v2, st), (v_p, st_p) = runs
     np.testing.assert_array_equal(np.asarray(v2), np.asarray(v_p))
     assert st == st_p
-    colors = colors_from_views(pgraph, np.asarray(v2))
-    assert_valid(graph, colors, what=f"recolor-{perm}")
-    want_nc, want_ex, want_hash = RC_GOLD[perm]
+    colors = colors_from_views(pg, np.asarray(v2))
+    assert_valid(g, colors, what=f"recolor-{name}-{perm}")
+    want_nc, want_ex, want_hash = gold
     assert st["n_colors"] == want_nc
     assert st["n_exchanges"] == want_ex
     assert _hash(colors) == want_hash
+    lo, hi = share
+    full = _full_width_slots(pg, view, chunk)
+    assert lo * full <= st["gather_slots"] <= hi * full, (
+        st["gather_slots"], full)
+
+
+def _has_gather(jaxpr, tail) -> bool:
+    return any((e.primitive.name == "gather"
+                and tuple(e.outvars[0].aval.shape[-2:]) == tail)
+               or any(_has_gather(j, tail) for j in _param_jaxprs(e.params))
+               for e in jaxpr.eqns)
+
+
+def _width_dispatch(jaxpr, widths, chunk, found, stray, inside=False):
+    """Collect the ``cond``s with one branch per width, each gathering a
+    ``(chunk, width)`` tile, and any narrow tile gathered outside them or
+    cut from the ELL table's rows by the gather itself (which XLA lowers to
+    a loop over the rows)."""
+    narrow = {(chunk, w) for w in widths[:-1]}
+    for e in jaxpr.eqns:
+        if (e.primitive.name == "gather"
+                and e.invars[0].aval.shape[-1] == widths[-1]
+                and 1 < e.params["slice_sizes"][-1] < widths[-1]):
+            stray.append(e)
+        branches = e.params.get("branches", ())
+        if (e.primitive.name == "cond" and len(branches) == len(widths)
+                and all(_has_gather(b.jaxpr, (chunk, w))
+                        for b, w in zip(branches, widths))):
+            found.append(e)
+            inside_e = True
+        else:
+            inside_e = inside
+            if (e.primitive.name == "gather" and not inside
+                    and tuple(e.outvars[0].aval.shape[-2:]) in narrow):
+                stray.append(e)
+        for j in _param_jaxprs(e.params):
+            _width_dispatch(j, widths, chunk, found, stray, inside_e)
+
+
+def _fused_batch_jaxpr(pg, cfg):
+    from repro.core.pipeline import pipeline_sim_program
+    fn, args = pipeline_sim_program(pg, compute_order(pg, ordering.NATURAL),
+                                    cfg)
+    return jax.make_jaxpr(fn)(*args)
+
+
+def _engine_step_jaxpr(pg, cfg, B=2):
+    import jax.numpy as jnp
+
+    from repro.core import engine_init_program, engine_step_program
+    arrs = {k: jnp.asarray(v) for k, v in pg.arrays(sparse=False).items()}
+    order = jnp.asarray(compute_order(pg, ordering.NATURAL))
+    init = engine_init_program(pg.P, cfg, None, arrs)
+    carry, _ = jax.eval_shape(init, arrs, order, jax.random.key(0))
+    lanes = lambda x: jax.ShapeDtypeStruct((B,) + tuple(x.shape), x.dtype)
+    arrs_b = jax.tree.map(lanes, arrs)
+    step = engine_step_program(pg.P, cfg, None, arrs_b, B, 1)
+    return jax.make_jaxpr(step)(arrs_b, jax.tree.map(lanes, carry),
+                                jax.random.split(jax.random.key(0), B))
+
+
+@pytest.mark.parametrize("program", [_fused_batch_jaxpr, _engine_step_jaxpr],
+                         ids=["fused_batch", "engine_step"])
+def test_recolor_width_dispatch_is_one_cond(program):
+    """The chunk's width is one ``cond`` with a branch per width, on an
+    index that no vmap batches: no narrow gather runs outside it, as vmap's
+    run-every-branch lowering (gathers merged by ``select_n``) would."""
+    from repro.core import PipelineConfig
+    from repro.core.pipeline import program_cache_clear
+    from repro.core.recolor import gather_widths
+    pg = partition_graph(rmat.rmat_good(8, 8, seed=1), 2)
+    widths = gather_widths(pg.maxd)
+    assert len(widths) >= 3
+    chunk = 16
+    cfg = PipelineConfig(
+        color=ColorConfig(max_colors=64, superstep=16, scheme="allgather"),
+        recolor=RecolorConfig(max_colors=64, chunk=chunk,
+                              scheme="allgather"), n_iters=1)
+    try:
+        jx = program(pg, cfg)
+    finally:
+        program_cache_clear()
+    found, stray = [], []
+    _width_dispatch(jx.jaxpr, widths, chunk, found, stray)
+    assert len(found) == 1, len(found)
+    assert not stray
 
 
 def test_recolor_backends_agree(pgraph, seed_view):

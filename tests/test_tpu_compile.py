@@ -80,10 +80,13 @@ SELECTIONS = [("first_fit", {}), ("random_x", dict(x=10)),
               ("staggered", dict(offset=64))]
 
 
+# the recolor chunk's narrowest widths (``recolor.gather_widths``) and the
+# full ELL width
+@pytest.mark.parametrize("width", [8, 16, MAXD])
 @pytest.mark.parametrize("tiles", [1, 2], ids=["d1", "d2"])
 @pytest.mark.parametrize("selection,kw", SELECTIONS,
                          ids=[s for s, _ in SELECTIONS])
-def test_select_kernel_compiles(chip_compile, tiles, selection, kw):
+def test_select_kernel_compiles(chip_compile, tiles, selection, kw, width):
     entry = ops.select_colors if tiles == 1 else ops.select_colors_d2
 
     def fn(*args):
@@ -91,7 +94,7 @@ def test_select_kernel_compiles(chip_compile, tiles, selection, kw):
         return entry(*nbrs, active, rand, max_colors=MAX_COLORS,
                      selection=selection, backend="pallas", **kw)
 
-    chip_compile(fn, *[_spec((V, MAXD))] * tiles, _spec((V,), jnp.bool_),
+    chip_compile(fn, *[_spec((V, width))] * tiles, _spec((V,), jnp.bool_),
                  _spec((V,), jnp.uint32))
 
 
