@@ -39,8 +39,10 @@ into a leading-P axis for `SimComm`/`shard_map`):
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
+import os
 
 import jax
 import numpy as np
@@ -186,63 +188,96 @@ def _unique_pairs(a: np.ndarray, b: np.ndarray) -> tuple[np.ndarray, np.ndarray]
     return a[keep], b[keep]
 
 
-def _pair_diff(a2: np.ndarray, b2: np.ndarray, a1: np.ndarray,
-               b1: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Set-difference of *deduped* pair lists: (a2, b2) minus (a1, b1).
-
-    One lexsort over the concatenation with a membership tag: a pair of the
-    second list survives unless the (unique) copy from the first list sorts
-    immediately before it.  Output stays sorted by (a, b).
-    """
-    a = np.concatenate([a1, a2])
-    b = np.concatenate([b1, b2])
-    tag = np.concatenate([np.zeros(a1.shape[0], bool),
-                          np.ones(a2.shape[0], bool)])
-    order = np.lexsort((tag, b, a))
-    a, b, tag = a[order], b[order], tag[order]
-    dup = np.zeros(a.shape[0], bool)
-    dup[1:] = (a[1:] == a[:-1]) & (b[1:] == b[:-1])
-    keep = tag & ~dup
-    return a[keep], b[keep]
+#: raw two-hop paths expanded at once by one task of ``_two_hop_pairs``
+TWO_HOP_CHUNK = 1 << 19
+#: threads that expand the two-hop paths (numpy's sort and gathers release
+#: the GIL); at most this many, and never more than the host's cores
+TWO_HOP_WORKERS = 8
 
 
-def _two_hop_pairs(g: Graph, lo: int, row: np.ndarray, nbrs: np.ndarray,
-                   chunk_paths: int = 1 << 22
+def _two_hop_chunk(g: Graph, lo: int, a: int, b: int, key_dt
                    ) -> tuple[np.ndarray, np.ndarray]:
-    """Unique (local row, global id) pairs at graph distance exactly 2.
+    """Strict two-hop pairs of the block rows ``a .. b-1`` (local ids).
+
+    Every length-2 path v -> w -> u becomes one key ``(v - a) * n + u``;
+    one sort of the keys orders them by (row, global id), and a key equal
+    to its predecessor is a duplicate.  The pairs that are no two-hop pairs
+    (u adjacent to v, or u == v) are looked up in the sorted keys and
+    dropped.
+    """
+    n = key_dt(g.n)
+    e_lo, e_hi = int(g.indptr[lo + a]), int(g.indptr[lo + b])
+    w = g.indices[e_lo:e_hi]
+    d = g.indptr[w + 1] - g.indptr[w]
+    offs = np.cumsum(d) - d
+    src_dt = np.int32 if g.indptr[-1] < INT32_LIMIT else np.int64
+    src = np.repeat((g.indptr[w] - offs).astype(src_dt), d)
+    src += np.arange(src.shape[0], dtype=src_dt)
+    rows = np.arange(b - a, dtype=key_dt)
+    vkey = np.repeat(rows * n, np.diff(g.indptr[lo + a:lo + b + 1]))
+    key = np.repeat(vkey, d)
+    key += g.indices[src].astype(key_dt)
+    key.sort()
+    keep = np.empty(key.shape[0], dtype=bool)
+    keep[:1] = True
+    np.not_equal(key[1:], key[:-1], out=keep[1:])
+    drop = np.concatenate([vkey + w.astype(key_dt),
+                           rows * (n + key_dt(1)) + key_dt(lo + a)])
+    at = np.searchsorted(key, drop)
+    hit = at < key.shape[0]
+    at, drop = at[hit], drop[hit]
+    keep[at[key[at] == drop]] = False
+    key = key[keep]
+    return (key // n).astype(np.int32) + np.int32(a), (key % n).astype(np.int32)
+
+
+def _two_hop_pairs(g: Graph, offs: np.ndarray
+                   ) -> list[tuple[np.ndarray, np.ndarray]]:
+    """Per block ``[offs[p], offs[p+1])``: the unique (local row, global id)
+    pairs at graph distance exactly 2, sorted by (row, global id).
 
     Expands every length-2 path v -> w -> u from the block's vertices v (the
     middle vertex w may be local or remote), then drops u == v and the pairs
     already adjacent — the direct neighbourhood lives in ``nbr`` and the D2
     kernels OR both bitsets, so keeping strict two-hop rows only is what
-    bounds the ELL width.  The expansion is chunked (a hub of degree d
-    contributes d² raw paths) with an incremental dedup, so peak host memory
-    tracks the deduped two-hop set plus ``chunk_paths``, not the raw path
-    count.
+    bounds the ELL width.
+
+    One pass over each block in row chunks of about ``TWO_HOP_CHUNK`` raw
+    paths (a row is never split, so a hub of degree d brings its d² paths
+    into one chunk): each chunk is sorted and deduplicated on its own, and
+    the chunks' results, in row order, are the block's answer.  Host memory
+    tracks the chunks in flight plus the deduped pairs.  Keys are uint32
+    where ``rows * n`` fits, which caps a chunk's rows at ``2**32 // n``.
     """
-    deg = (g.indptr[nbrs + 1] - g.indptr[nbrs]).astype(np.int64)
-    cum = np.cumsum(deg)
-    row2 = np.empty(0, np.int64)
-    nb2 = np.empty(0, np.int64)
-    start = 0
-    while start < nbrs.shape[0]:
-        base = cum[start - 1] if start else 0
-        end = max(start + 1, int(np.searchsorted(cum, base + chunk_paths,
-                                                 side="right")))
-        end = min(end, nbrs.shape[0])
-        w, d = nbrs[start:end], deg[start:end]
-        starts = g.indptr[w].astype(np.int64)
-        offs2 = np.cumsum(d) - d
-        pos = np.arange(int(d.sum()), dtype=np.int64) - np.repeat(offs2, d)
-        u = g.indices[np.repeat(starts, d) + pos].astype(np.int64)
-        v = np.repeat(row[start:end].astype(np.int64), d)
-        keep = u != v + lo
-        row2, nb2 = _unique_pairs(np.concatenate([row2, v[keep]]),
-                                  np.concatenate([nb2, u[keep]]))
-        start = end
-    row2, nb2 = _pair_diff(row2, nb2, row.astype(np.int64),
-                           nbrs.astype(np.int64))
-    return row2.astype(np.int32), nb2.astype(np.int32)
+    rows_cap = (1 << 32) // max(g.n, 1)
+    key_dt = np.uint32 if rows_cap else np.uint64
+    tasks, per_block = [], []
+    for p in range(len(offs) - 1):
+        lo, hi = int(offs[p]), int(offs[p + 1])
+        e0 = int(g.indptr[lo])
+        w = g.indices[e0:int(g.indptr[hi])]
+        paths = np.zeros(w.shape[0] + 1, dtype=np.int64)
+        np.cumsum(g.indptr[w + 1] - g.indptr[w], out=paths[1:])
+        row_paths = paths[g.indptr[lo:hi + 1] - e0]   # paths before each row
+        a, n_tasks = 0, 0
+        while a < hi - lo:
+            last = min(hi - lo, a + rows_cap) if rows_cap else hi - lo
+            b = int(np.searchsorted(row_paths, row_paths[a] + TWO_HOP_CHUNK,
+                                    side="right")) - 1
+            b = max(a + 1, min(b, last))
+            tasks.append((lo, a, b))
+            a, n_tasks = b, n_tasks + 1
+        per_block.append(n_tasks)
+    workers = max(1, min(TWO_HOP_WORKERS, len(tasks), os.cpu_count() or 1))
+    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+        parts = iter(pool.map(lambda t: _two_hop_chunk(g, *t, key_dt), tasks))
+    out = []
+    for n_tasks in per_block:
+        mine = [next(parts) for _ in range(n_tasks)]
+        out.append(tuple(np.concatenate([m[i] for m in mine]
+                                        + [np.zeros(0, np.int32)])
+                         for i in (0, 1)))
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -449,6 +484,10 @@ def partition_graph(g: Graph, P: int, *, seed: int = 0,
     n_local = (offs[1:] - offs[:-1]).astype(np.int32)
     n_local_max = int(n_local.max())
 
+    if halo == 2:
+        with jax.profiler.TraceAnnotation(tracing.PARTITION_TWO_HOP):
+            pairs2 = _two_hop_pairs(g, offs)
+
     with jax.profiler.TraceAnnotation(tracing.PARTITION_HALO):
         # pass 1: per-shard edge slices, halo sets (the remote vertices whose
         # colors this shard reads) and, at halo=2, the strict two-hop pair lists
@@ -468,7 +507,7 @@ def partition_graph(g: Graph, P: int, *, seed: int = 0,
                 ghosts_of.append(np.unique(nbrs[remote]))
                 hop2.append(None)
             else:
-                row2, nb2 = _two_hop_pairs(g, lo, row, nbrs)
+                row2, nb2 = pairs2[p]
                 rem2 = (nb2 < lo) | (nb2 >= hi)
                 ghosts_of.append(np.unique(np.concatenate(
                     [nbrs[remote], nb2[rem2]])))
@@ -571,7 +610,8 @@ def partition_graph(g: Graph, P: int, *, seed: int = 0,
 
         # strict two-hop ELL (halo=2): nbr2[p, v, k] = k-th distance-2 slot
         # of v.  Rows come pre-sorted by (v, global id) from _two_hop_pairs,
-        # so each vertex's entries are one contiguous run.
+        # so each vertex's entries are one contiguous run, and a row-major
+        # mask of the first cnt[v] columns of each row takes them in order.
         maxd2, nbr2 = 0, None
         if halo == 2:
             slot2_rows = []
@@ -582,18 +622,15 @@ def partition_graph(g: Graph, P: int, *, seed: int = 0,
                 if rem2.any():
                     slot2[rem2] = (n_local_max + np.searchsorted(
                         ghosts_of[p], nb2[rem2])).astype(np.int32)
-                slot2_rows.append((row2, slot2))
-                cnt = np.bincount(row2, minlength=1)
+                cnt = np.bincount(row2, minlength=n_local_max)
+                slot2_rows.append((cnt, slot2))
                 maxd2 = max(maxd2, int(cnt.max(initial=0)))
             maxd2 = max(1, maxd2)
             id_policy(g.n, n_local_max, maxd, maxd2)
             nbr2 = np.full((P, n_local_max, maxd2), sentinel, dtype=np.int32)
             for p in range(P):
-                row2, slot2 = slot2_rows[p]
-                cnt = np.bincount(row2, minlength=n_local_max).astype(np.int64)
-                starts2 = np.concatenate([[0], np.cumsum(cnt)])[:-1]
-                col = np.arange(len(row2), dtype=np.int64) - starts2[row2]
-                nbr2[p, row2, col] = slot2
+                cnt, slot2 = slot2_rows[p]
+                nbr2[p][np.arange(maxd2) < cnt[:, None]] = slot2
 
     return PartitionedGraph(
         P=P, n_global=g.n, n_local_max=n_local_max, max_ghost=max_ghost,

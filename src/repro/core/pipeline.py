@@ -75,8 +75,8 @@ from .speculative import ColorConfig, _apply_partial, color_spmd, resolve_cfg
 # Column layout of the device-resident per-iteration history.  ``ran`` marks
 # rows the adaptive stop never reached (they stay zero).
 HISTORY_STATS = ("n_colors", "n_colors_distinct", "n_colors_before",
-                 "n_exchanges", "gather_slots", "n_steps", "wire_bytes",
-                 "n_out_of_range", "perm_id", "ran")
+                 "n_exchanges", "gather_slots", "gather2_slots", "n_steps",
+                 "wire_bytes", "n_out_of_range", "perm_id", "ran")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,7 +201,8 @@ def _recolor_loop_fns(arrs, key, cfg: PipelineConfig,
                                       comm)
         nd_after = jnp.sum(sizes > 0).astype(jnp.int32)
         row = jnp.stack([st["n_colors"], nd_after, n_classes,
-                         st["n_exchanges"], st["gather_slots"], st["n_steps"],
+                         st["n_exchanges"], st["gather_slots"],
+                         st["gather2_slots"], st["n_steps"],
                          st["wire_bytes"], n_oor, kid,
                          jnp.int32(1)]).astype(jnp.int32)
         hist = jax.lax.dynamic_update_slice(hist, row[None],

@@ -27,7 +27,8 @@ the colors are bitwise those of full-width chunks.  The width is pmax-ed
 over shards and ``lane_uniform``-ed over graph lanes, so the switch stays
 one branch taken under every vmap; ``gather_slots`` in the stats counts the
 slots gathered (rows × width over chunks and shards).  Distance 2 keeps the
-one full width.
+one full width, and ``gather2_slots`` counts what its chunks read through
+the two-hop ELL (rows × ``maxd2`` over chunks and shards; 0 at distance 1).
 
 Color-class permutations (§3): RV (reverse), NI (non-increasing class size),
 ND (non-decreasing — the paper's best), RAND (Knuth shuffle), and the hybrid
@@ -506,11 +507,14 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
         (new_view0, jnp.int32(0), jnp.int32(0), jnp.int32(0)))
 
     local_max = jnp.max(jnp.where(valid_local, new_view[:n_local_max], 0))
+    # every chunk of this lane's schedule reads whole two-hop rows
+    maxd2 = arrs["nbr2"].shape[1] if cfg.distance == 2 else 0
     stats = dict(
         n_colors=comm.pmax(local_max),
         n_colors_before=n_classes,
         n_exchanges=n_ex,
         gather_slots=n_gathered * comm.size(),  # the same on every shard
+        gather2_slots=cum[mc] * (chunk * maxd2 * comm.size()),
         n_steps=n_classes,
         wire_bytes=n_bytes,
     )
@@ -599,7 +603,8 @@ def recolor_sim(pg: PartitionedGraph, view, perm_kind: str,
     key for reproducible RAND permutations).  Returns ``(view, stats)``
     with python-int stats: ``n_colors`` (max id), ``n_colors_distinct``,
     ``n_colors_before``, ``n_exchanges`` (executed), ``gather_slots`` (ELL
-    slots gathered, all shards), ``n_steps`` (= class count),
+    slots gathered, all shards), ``gather2_slots`` (two-hop ELL slots
+    gathered, all shards; 0 at distance 1), ``n_steps`` (= class count),
     ``wire_bytes``, ``n_out_of_range``.  ``recolor_sharded`` is
     the bitwise-identical ``workers``-mesh variant.
     """

@@ -211,6 +211,12 @@ def _greedy_chunk(view, usage, order, rand_u32, start, count, arrs, p_idx,
     return jax.lax.fori_loop(start, start + count, body, (view, usage))
 
 
+def _tiles(cfg: ColorConfig, superstep: int) -> tuple[int, int]:
+    """(tile width, tiles a superstep) of the tile-parallel superstep."""
+    tile = min(cfg.tile, superstep)
+    return tile, -(-superstep // tile)
+
+
 def _parallel_chunk(view, usage, order_pad, rand_u32, start, arrs, p_idx,
                     cfg: ColorConfig, superstep: int):
     """Color one superstep as tile-parallel sub-tiles against the stale view.
@@ -225,8 +231,7 @@ def _parallel_chunk(view, usage, order_pad, rand_u32, start, arrs, p_idx,
     clamp into unvisited territory.
     """
     n_slots = view.shape[0]
-    tile = min(cfg.tile, superstep)
-    n_tiles = -(-superstep // tile)
+    tile, n_tiles = _tiles(cfg, superstep)
     offset = cfg.stagger_offset(p_idx)
 
     def tile_body(ti, carry):
@@ -365,8 +370,15 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
     view0 = jnp.zeros((n_slots,), jnp.int32)
     usage0 = jnp.zeros((cfg.max_colors,), jnp.int32)
 
+    # slots a superstep reads through the two-hop ELL: every gathered row
+    # of the tile-parallel coloring (the sequential path reads one row a
+    # vertex it colors), and two gathers (colors, priorities) a detected row
+    maxd2 = arrs["nbr2"].shape[1] if cfg.distance == 2 else 0
+    tile, n_tiles = _tiles(cfg, S)
+    rows2 = (tile * n_tiles if cfg.use_parallel_chunk else 0) + 2 * S
+
     def round_body(state):
-        view, usage, rnd, n_conf_in, n_ex, n_bytes, n_rnd = state
+        view, usage, rnd, n_conf_in, n_ex, n_bytes, n_rnd, n_g2 = state
         order_r, n_need = _compact_order(order, view)
         n_need_max = comm.pmax(n_need)
         n_steps = (n_need_max + S - 1) // S
@@ -421,21 +433,26 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
                                    no_ex, view)
         view = jnp.where(do_final, new_view, view)
         n_conf = comm.psum(n_conf)
+        # this lane's own supersteps, on every shard alike
+        g2 = n_steps * rows2 * comm.size()
+        if not cfg.use_parallel_chunk:
+            g2 = g2 + comm.psum(n_need)
         # per-lane round count: a converged lane riding out its batch-row
         # peers' extra rounds (no-op bodies) must not count them
         return (view, usage, rnd + 1, n_conf,
                 n_ex + do_final.astype(jnp.int32),
                 n_bytes + jnp.where(do_final, b, 0),
-                n_rnd + (n_conf_in > 0).astype(jnp.int32))
+                n_rnd + (n_conf_in > 0).astype(jnp.int32),
+                n_g2 + g2 * maxd2)
 
     def cond(state):
-        _, _, rnd, n_conf, _, _, _ = state
+        _, _, rnd, n_conf, _, _, _, _ = state
         return comm.lane_uniform(n_conf > 0) & (rnd < cfg.max_rounds)
 
     state0 = (view0, usage0, jnp.int32(0), jnp.int32(1), jnp.int32(0),
-              jnp.int32(0), jnp.int32(0))
+              jnp.int32(0), jnp.int32(0), jnp.int32(0))
     # round 0 must run: seed n_conf=1
-    view, usage, _, _, n_ex, n_bytes, n_rounds = jax.lax.while_loop(
+    view, usage, _, _, n_ex, n_bytes, n_rounds, n_g2 = jax.lax.while_loop(
         cond, round_body, state0)
 
     local_max = jnp.max(view[:n_local_max])
@@ -453,6 +470,7 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
         n_rounds=n_rounds,
         n_exchanges=n_ex,
         wire_bytes=n_bytes,
+        gather2_slots=n_g2,
     )
     return view, stats
 
@@ -512,8 +530,9 @@ def color_graph_sim(pg: PartitionedGraph, order, cfg: ColorConfig,
     sentinel slots after ``n_local_max``; ``colors_from_views`` flattens it
     to global ``(n,)`` colors) and ``stats`` are python ints — ``n_colors``
     (max id), ``n_colors_distinct`` (the quality metric), ``n_rounds``,
-    ``n_exchanges``, ``wire_bytes`` (measured, per-shard max).
-    ``color_graph_sharded`` is the bitwise-identical mesh variant.
+    ``n_exchanges``, ``wire_bytes`` (measured, per-shard max),
+    ``gather2_slots`` (slots read through the two-hop ELL, all shards; 0
+    at distance 1).  ``color_graph_sharded`` is the bitwise-identical mesh variant.
     """
     cfg = resolve_cfg(pg, cfg)
     arrs = {k: jnp.asarray(v) for k, v in

@@ -28,9 +28,10 @@ host work under it.  They cost about a microsecond each while no trace is
 taken.  A span's parent is the span open around it on the same thread.
 
 - ``repro.partition`` — ``graph.partition_graph``; its children
-  ``repro.partition.halo`` (per-shard edge slices, halo sets, slot ids,
-  boundary lists and ghost tables) and ``repro.partition.ell`` (padding to
-  the common maxima and the ELL build);
+  ``repro.partition.two_hop`` (the strict two-hop pairs of every shard,
+  ``halo=2`` only), ``repro.partition.halo`` (per-shard edge slices, halo
+  sets, slot ids, boundary lists and ghost tables) and
+  ``repro.partition.ell`` (padding to the common maxima and the ELL build);
 - ``repro.comm_plan`` — ``graph.build_comm_plan`` (built lazily, on the
   first use of a partition's sparse plan);
 - ``repro.order`` — ``ordering.compute_order``;
@@ -56,6 +57,7 @@ SCOPES = (SPEC_COLOR, RECOLOR, NBR_GATHER, EXCHANGE)
 
 SPAN_PREFIX = "repro."
 PARTITION = "repro.partition"
+PARTITION_TWO_HOP = "repro.partition.two_hop"
 PARTITION_HALO = "repro.partition.halo"
 PARTITION_ELL = "repro.partition.ell"
 COMM_PLAN = "repro.comm_plan"
@@ -64,8 +66,8 @@ UPLOAD = "repro.upload"
 PROGRAM = "repro.program"
 WAIT = "repro.wait"
 TO_GLOBAL = "repro.to_global"
-SPANS = (PARTITION, PARTITION_HALO, PARTITION_ELL, COMM_PLAN, ORDER, UPLOAD,
-         PROGRAM, WAIT, TO_GLOBAL)
+SPANS = (PARTITION, PARTITION_TWO_HOP, PARTITION_HALO, PARTITION_ELL,
+         COMM_PLAN, ORDER, UPLOAD, PROGRAM, WAIT, TO_GLOBAL)
 
 
 def scope(name: str):

@@ -21,9 +21,10 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import (ColorConfig, RecolorConfig, check_coloring,
-                        color_graph_sim, colors_from_views, compute_order,
-                        ordering, partition_graph, recolor_sim, rmat)
+from repro.core import (ColorConfig, PipelineConfig, RecolorConfig,
+                        check_coloring, color_graph_sim, colors_from_views,
+                        compute_order, ordering, partition_graph,
+                        pipeline_sim, recolor_sim, rmat)
 
 GRAPHS = {
     "grid2d": lambda: rmat.grid2d(12, 12, 9),
@@ -136,6 +137,34 @@ def test_d2_sequential_mode_valid():
     st = check_coloring(g, colors_from_views(pg, np.asarray(view)),
                         distance=2)
     assert st["valid"], st
+
+
+@pytest.mark.parametrize("distance", [1, 2])
+def test_gather2_slots_count_two_hop_rows(distance):
+    """``gather2_slots`` is rows × ``maxd2`` for every gather through the
+    two-hop ELL, over all shards, and 0 at distance 1.  With the superstep,
+    the tile and the recolor chunk at least a shard's rows, a speculative
+    round gathers every row once to color and twice (colors, priorities)
+    to detect, and a recolor iteration gathers every row once a class."""
+    g, P = _graph("grid3d"), 2
+    pg = partition_graph(g, P, halo=distance)
+    cfg = PipelineConfig(
+        color=ColorConfig(max_colors=512, superstep=512, tile=512,
+                          distance=distance, scheme="sparse"),
+        recolor=RecolorConfig(max_colors=512, chunk=512, distance=distance,
+                              scheme="sparse"),
+        n_iters=2)
+    view, res = pipeline_sim(pg, compute_order(pg, ordering.NATURAL), cfg)
+    assert check_coloring(g, colors_from_views(pg, np.asarray(view)),
+                          distance=distance)["valid"]
+    rows = pg.n_local_max
+    width = pg.maxd2 if distance == 2 else 0
+    assert res["color"]["gather2_slots"] == (
+        res["color"]["n_rounds"] * 3 * rows * width * P)
+    assert len(res["history"]) == 2
+    for h in res["history"]:
+        assert h["gather2_slots"] == h["n_colors_before"] * rows * width * P
+    assert (res["color"]["gather2_slots"] > 0) == (distance == 2)
 
 
 def test_d1_on_halo2_partition_matches_halo1():

@@ -5,6 +5,7 @@ pytest.importorskip("hypothesis")  # optional dep: property tests
 from hypothesis import given, settings, strategies as st
 
 from repro.core import Graph, partition_graph, rmat
+from repro.core import graph as graph_mod
 
 
 def random_graph(n, p, seed):
@@ -143,6 +144,34 @@ class TestPartition:
                 read = any(not lo <= u < hi for u in adj[lo + v] | d2[lo + v])
                 assert (v in bnd) == read
                 assert pg.is_internal[p, v] == (not read)
+
+    @pytest.mark.parametrize("P", [2, 4])
+    @pytest.mark.parametrize("chunk", [None, 64], ids=["one-chunk", "chunked"])
+    @pytest.mark.parametrize("gname", ["grid_slab", "grid_cube", "rmat_good",
+                                       "rmat_bad"])
+    def test_two_hop_pairs_match_brute_force(self, gname, chunk, P,
+                                             monkeypatch):
+        """The one-pass two-hop build == a set construction, row by row and
+        in order, on a 27-point slab and cube, RMAT-Good and the hub-heavy
+        RMAT-Bad; ``chunked`` splits each block into many row chunks."""
+        g = {"grid_slab": lambda: rmat.grid3d(12, 6, 6),
+             "grid_cube": lambda: rmat.grid3d(8, 8, 8),
+             "rmat_good": lambda: rmat.rmat_good(8, 8, seed=1),
+             "rmat_bad": lambda: rmat.rmat_bad(8, 8, seed=1)}[gname]()
+        if chunk is not None:
+            monkeypatch.setattr(graph_mod, "TWO_HOP_CHUNK", chunk)
+        adj = [set(g.indices[g.indptr[v]:g.indptr[v + 1]].tolist())
+               for v in range(g.n)]
+        offs = np.linspace(0, g.n, P + 1).astype(np.int64)
+        got = graph_mod._two_hop_pairs(g, offs)
+        assert len(got) == P
+        for p, (row2, nb2) in enumerate(got):
+            lo, hi = int(offs[p]), int(offs[p + 1])
+            want = [(v - lo, u) for v in range(lo, hi)
+                    for u in sorted(set().union(*(adj[w] for w in adj[v]))
+                                    - adj[v] - {v})]
+            assert row2.dtype == nb2.dtype == np.int32
+            assert list(zip(row2.tolist(), nb2.tolist())) == want
 
     @settings(max_examples=20, deadline=None)
     @given(n=st.integers(6, 40), p=st.floats(0.05, 0.5),

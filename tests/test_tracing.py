@@ -63,6 +63,25 @@ def test_lowered_program_carries_scopes(scheme):
             assert {tracing.SPEC_COLOR, tracing.RECOLOR} & c, p
 
 
+def test_two_hop_tiles_gather_in_nbr_gather():
+    """Every gather of a two-hop tile (rows x ``maxd2``), in both coloring
+    phases, lies inside ``nbr_gather``.  The piggyback schedule's scan of
+    the flattened two-hop ELL reads class steps, not colors for a kernel,
+    and stays in ``recolor``'s own time, as the CSR scan does at distance 1.
+    """
+    pg, order = _prepare(2)
+    fn, args = pipeline_sim_program(pg, order, _cfg("sparse", 2))
+    hlo = fn.lower(*args).as_text(dialect="hlo", debug_info=True)
+    tiles = [op for dims, op in re.findall(
+        r'= \w+\[([\d,]+)\][^ ]* gather\(.*op_name="([^"]*)"', hlo)
+        if dims.split(",")[-1] == str(pg.maxd2)]
+    assert pg.maxd2 not in (pg.maxd, pg.n_local_max)
+    for phase in (tracing.SPEC_COLOR, tracing.RECOLOR):
+        assert any(phase in _components(op) for op in tiles), phase
+    for op in tiles:
+        assert tracing.NBR_GATHER in _components(op), op
+
+
 def test_scope_names_are_no_primitive_names():
     from jax.extend.core import Primitive
 
@@ -104,7 +123,8 @@ def test_host_spans_nest(tmp_path):
         pg.gather_global_colors(np.asarray(view)[:, :pg.n_local_max])
     spans = _host_spans(tmp_path)
     names = [n for *_, n in spans]
-    assert set(names) == set(tracing.SPANS)
+    # every span but the two-hop build, which runs at halo=2 only
+    assert set(names) == set(tracing.SPANS) - {tracing.PARTITION_TWO_HOP}
     for name in (tracing.PARTITION, tracing.PARTITION_HALO,
                  tracing.PARTITION_ELL, tracing.ORDER, tracing.UPLOAD,
                  tracing.PROGRAM, tracing.WAIT, tracing.TO_GLOBAL):
@@ -120,6 +140,26 @@ def test_host_spans_nest(tmp_path):
     assert (start[tracing.PARTITION] < start[tracing.ORDER]
             < start[tracing.UPLOAD] < start[tracing.PROGRAM]
             < start[tracing.WAIT] < start[tracing.TO_GLOBAL])
+
+
+def test_two_hop_span_nests(tmp_path):
+    """At halo=2 the two-hop build is a child of the partition, before the
+    halo sets that read its pairs."""
+    g = rmat.rmat_good(8, 8, seed=3)
+    with jax.profiler.trace(str(tmp_path)):
+        partition_graph(g, 4, seed=12, halo=2)
+    spans = _host_spans(tmp_path)
+    names = [n for *_, n in spans]
+    assert sorted(names) == sorted([tracing.PARTITION,
+                                    tracing.PARTITION_TWO_HOP,
+                                    tracing.PARTITION_HALO,
+                                    tracing.PARTITION_ELL])
+    for child in (tracing.PARTITION_TWO_HOP, tracing.PARTITION_HALO,
+                  tracing.PARTITION_ELL):
+        assert _parents(spans, child) == {tracing.PARTITION}, child
+    span = {n: (s, e) for _, s, e, n in spans}
+    assert (span[tracing.PARTITION_TWO_HOP][1]
+            <= span[tracing.PARTITION_HALO][0])
 
 
 # (distance, scheme, backend) -> sha256[:16] of the global colors, recorded
