@@ -20,7 +20,8 @@ Chunks are *degree-sorted and width-bucketed*: within its class a vertex
 sorts by ELL row length (``degree``, widest first), and each chunk gathers
 its rows only as wide as its widest row on any shard, rounded up to one of
 the static widths of ``gather_widths(maxd)`` (powers of two from 8, then
-``maxd``) and dispatched by ``lax.switch``.  On skewed graphs most rows are
+``maxd``; defined in ``speculative.py``, whose tiles and detection chunks
+use the same widths) and dispatched by ``lax.switch``.  On skewed graphs most rows are
 far shorter than the hub that sets ``maxd``, so most chunks gather a small
 fraction of the padded tile; entries past a row's degree are sentinels, so
 the colors are bitwise those of full-width chunks.  The width is pmax-ed
@@ -81,7 +82,8 @@ from .comm import (AUTO, AXIS, DEFAULT_SCHEME, SCHEME_CHOICES, SCHEMES,
                    shard_uniform, stats_to_host)
 from .graph import PartitionedGraph
 from .speculative import (ColorConfig, _compact_order, _plan_static,
-                          color_spmd, resolve_cfg, validate_color_bounds)
+                          color_spmd, gather_widths, resolve_cfg,
+                          validate_color_bounds)
 
 RV = "rv"
 NI = "ni"
@@ -142,21 +144,6 @@ class RecolorConfig:
     @property
     def comm_config(self) -> CommConfig:
         return CommConfig(scheme=self.scheme, wire16=self.wire16)
-
-
-def gather_widths(maxd: int) -> tuple:
-    """The static widths a recolor chunk may gather its ELL rows at.
-
-    The powers of two from 8 below ``maxd``, then ``maxd`` itself: a chunk
-    takes the smallest that holds its widest row, so it gathers at most
-    twice its real width (or 8 slots) instead of the graph's maximum
-    degree.  ``maxd <= 8`` leaves the one width ``maxd``.
-    """
-    out, w = [], 8
-    while w < maxd:
-        out.append(w)
-        w *= 2
-    return tuple(out) + (maxd,)
 
 
 def class_sizes(view, n_local, n_local_max, max_colors, comm: AxisComm):

@@ -28,6 +28,25 @@ Chunk coloring has two modes (``ColorConfig.parallel_chunk``):
     involve boundary vertices).  Also used for Least-Used selection, whose
     running usage histogram is inherently sequential.
 
+Gathers are *width-bucketed*, as recolor's chunks are: a tile of the
+parallel mode, and a chunk of conflict detection, gathers its ELL rows only
+as wide as its widest row on any shard and lane, rounded up to one of the
+static widths of ``gather_widths(maxd)`` and dispatched by ``lax.switch``.
+Entries past a row's degree are sentinels (color 0, never forbidden), so
+each stays bitwise the full-width program:
+
+- coloring keeps the visit order, which decides the colors; the rows of a
+  tile color together against one view, so the tile's width may follow
+  its widest row;
+- detection is free of order (every chunk reads the pre-detection view),
+  so it reads the round's frontier sorted by degree, widest first, and
+  its chunks hold rows of similar length.
+
+The widths of a round's tiles and chunks ride the exchange gates' one pmax
+a round.  ``gather_slots`` in the stats counts the slots gathered (rows ×
+width over tiles, detection chunks and shards).  Distance 2 keeps the one
+full width.
+
 Communication scaling (this file + comm.py/graph.py, DESIGN.md §2):
 
 - exchanges route through ``comm.make_exchange`` — the broadcast all-gather
@@ -217,8 +236,56 @@ def _tiles(cfg: ColorConfig, superstep: int) -> tuple[int, int]:
     return tile, -(-superstep // tile)
 
 
+def gather_widths(maxd: int) -> tuple:
+    """The static widths a tile or chunk may gather its ELL rows at.
+
+    The powers of two from 8 below ``maxd``, then ``maxd`` itself: a tile
+    takes the smallest that holds its widest row, so it gathers at most
+    twice its real width (or 8 slots) instead of the graph's maximum
+    degree.  ``maxd <= 8`` leaves the one width ``maxd``.  The speculative
+    tiles and detection chunks here and recolor's chunks share them.
+    """
+    out, w = [], 8
+    while w < maxd:
+        out.append(w)
+        w *= 2
+    return tuple(out) + (maxd,)
+
+
+def _superstep_widths(row_deg, superstep: int, tile: int, n_tiles: int,
+                      n_chunks: int):
+    """Widest entry of each sub-tile of each superstep, ``(n_chunks, n_tiles)``.
+
+    ``row_deg`` holds one ELL row length per visit position (0 where no
+    row is to be colored or checked).  Sub-tile ``ti`` of superstep ``si``
+    covers the ``tile`` positions from ``si * superstep + ti * tile``, as
+    ``_parallel_chunk`` slices them: where ``tile`` does not divide the
+    superstep, the last sub-tile reaches into the next superstep.  Past the
+    visit order every position is padding (0), which also covers the
+    slices ``dynamic_slice`` clamps.
+    """
+    rows = jnp.pad(row_deg, (0, (n_chunks + 1) * superstep
+                             - row_deg.shape[0])).reshape(n_chunks + 1,
+                                                          superstep)
+    span = jnp.concatenate(
+        [rows[:-1], rows[1:, :n_tiles * tile - superstep]], axis=1)
+    return span.reshape(n_chunks, n_tiles, tile).max(axis=2)
+
+
+def _frontier_by_degree(order_r, n_need, degree):
+    """The round's frontier (the first ``n_need`` entries of the compacted
+    visit order) stable-sorted by ELL row length, widest first; the entries
+    after it keep their place.  Detection is free of order, so this changes
+    no result, and each detection chunk then holds rows of similar length.
+    """
+    pos = jnp.arange(order_r.shape[0], dtype=jnp.int32)
+    key = jnp.where(pos < n_need, -degree[jnp.maximum(order_r, 0)], 1)
+    return order_r[jnp.argsort(key, stable=True)]
+
+
 def _parallel_chunk(view, usage, order_pad, rand_u32, start, arrs, p_idx,
-                    cfg: ColorConfig, superstep: int):
+                    cfg: ColorConfig, superstep: int, widths: tuple,
+                    tile_wid):
     """Color one superstep as tile-parallel sub-tiles against the stale view.
 
     Each sub-tile of ``cfg.tile`` vertices colors at once: one ELL-row gather
@@ -229,10 +296,47 @@ def _parallel_chunk(view, usage, order_pad, rand_u32, start, arrs, p_idx,
     Conflicts within a tile are repaired by the round loop.  ``order_pad`` is
     the visit order padded by ``superstep`` entries of -1 so slices never
     clamp into unvisited territory.
+
+    Sub-tile ``ti`` gathers its rows at the smallest of ``widths`` that
+    holds ``tile_wid[ti]``, its widest row on any shard and lane; the rows
+    of a tile color together against one view, so the colors are those of
+    a full-width gather.
     """
     n_slots = view.shape[0]
     tile, n_tiles = _tiles(cfg, superstep)
     offset = cfg.stagger_offset(p_idx)
+    width_arr = jnp.asarray(widths, jnp.int32)
+
+    def color_at(w):
+        """Gather the tile's neighbour colors ``w`` slots wide, select its
+        colors and write them into the view, as ``recolor_at`` does: the
+        view and usage go through the branch, and ``cols`` are the tile's
+        whole ELL rows, cut here."""
+        def branch(view, usage, cols, v_safe, rand, active):
+            with jax.named_scope(tracing.NBR_GATHER):
+                nbr_colors = view[cols[:, :w]]          # (tile, w)
+                if cfg.distance == 2:
+                    nbr2_colors = view[arrs["nbr2"][v_safe]]
+            if cfg.distance == 2:
+                colors = ops.select_colors_d2(
+                    nbr_colors, nbr2_colors, active, rand,
+                    max_colors=cfg.max_colors, selection=cfg.selection,
+                    x=cfg.random_x, offset=offset, backend=cfg.backend)
+            else:
+                colors = ops.select_colors(
+                    nbr_colors, active, rand, max_colors=cfg.max_colors,
+                    selection=cfg.selection, x=cfg.random_x, offset=offset,
+                    backend=cfg.backend)
+            colors = jnp.minimum(colors, cfg.max_colors - 1).astype(jnp.int32)
+            idx = jnp.where(active, v_safe, n_slots - 1)   # park writes on the
+            val = jnp.where(active, colors, 0)             # sentinel (stays 0)
+            view = view.at[idx].set(val.astype(view.dtype))
+            usage = usage.at[jnp.where(active, colors, 0)].add(
+                active.astype(jnp.int32))
+            return view, usage
+        return branch
+
+    branches = [color_at(w) for w in widths]
 
     def tile_body(ti, carry):
         view, usage = carry
@@ -240,72 +344,79 @@ def _parallel_chunk(view, usage, order_pad, rand_u32, start, arrs, p_idx,
         v_safe = jnp.maximum(chunk, 0)
         active = (chunk >= 0) & (view[v_safe] == 0)
         with jax.named_scope(tracing.NBR_GATHER):
-            nbr_colors = view[arrs["nbr"][v_safe]]   # (tile, maxd)
-            if cfg.distance == 2:
-                nbr2_colors = view[arrs["nbr2"][v_safe]]
-        if cfg.distance == 2:
-            colors = ops.select_colors_d2(
-                nbr_colors, nbr2_colors, active,
-                rand_u32[v_safe], max_colors=cfg.max_colors,
-                selection=cfg.selection, x=cfg.random_x, offset=offset,
-                backend=cfg.backend)
-        else:
-            colors = ops.select_colors(
-                nbr_colors, active, rand_u32[v_safe],
-                max_colors=cfg.max_colors, selection=cfg.selection,
-                x=cfg.random_x, offset=offset, backend=cfg.backend)
-        colors = jnp.minimum(colors, cfg.max_colors - 1).astype(jnp.int32)
-        idx = jnp.where(active, v_safe, n_slots - 1)   # park writes on the
-        val = jnp.where(active, colors, 0)             # sentinel (stays 0)
-        view = view.at[idx].set(val.astype(view.dtype))
-        usage = usage.at[jnp.where(active, colors, 0)].add(
-            active.astype(jnp.int32))
-        return view, usage
+            cols = arrs["nbr"][v_safe]                  # (tile, maxd)
+        args = (view, usage, cols, v_safe, rand_u32[v_safe], active)
+        if len(widths) == 1:
+            return branches[0](*args)
+        # shard- and lane-uniform index: one branch taken under every vmap
+        b = jnp.sum(width_arr < tile_wid[ti], dtype=jnp.int32)  # first >= wid
+        return jax.lax.switch(b, branches, *args)
 
     return jax.lax.fori_loop(0, n_tiles, tile_body, (view, usage))
 
 
-def _detect_conflicts_frontier(view, arrs, order_pad, n_steps, n_need,
-                               superstep: int, backend="auto",
-                               distance: int = 1):
+def _detect_conflicts_frontier(view, arrs, rows_pad, n_steps, n_need,
+                               superstep: int, widths: tuple, chunk_wid,
+                               backend="auto", distance: int = 1):
     """Uncolor the lower-priority endpoint of every same-color frontier edge.
 
-    Chunked over the round's visit order: only the ``n_need`` vertices
-    recolored this round are rescanned (``n_steps`` is pmax-reduced by the
-    caller, so the trip count is shard-uniform and *shrinks* with the
-    conflict frontier).  Every chunk reads the same pre-detection ``view`` —
-    identical results to one full-width pass — and writes uncolorings into a
-    separate copy.  ``distance=2`` additionally scans the two-hop ELL rows
-    (both endpoints of a distance-2 conflict list each other in ``nbr2``, so
-    the repair argument is unchanged).  Returns (new_view, n_conflicts,
-    any_boundary_conflict).
+    Chunked over ``rows_pad``, whose first ``n_need`` entries are the
+    vertices recolored this round (the round's frontier, in any order),
+    padded by ``superstep`` entries of -1: only they are rescanned
+    (``n_steps`` is pmax-reduced by the caller, so the trip count is
+    shard- and lane-uniform and *shrinks* with the conflict frontier).  Every chunk
+    reads the same pre-detection ``view`` — identical results to one
+    full-width pass, whatever the order of the frontier — and writes
+    uncolorings into a separate copy.  Chunk ``si`` gathers its rows'
+    colors and priorities at the smallest of ``widths`` that holds
+    ``chunk_wid[si]``, its widest row on any shard and lane (a shard- and
+    lane-uniform index, so one ``switch`` branch runs).  ``distance=2``
+    additionally scans the two-hop ELL rows (both endpoints of a distance-2
+    conflict list each other in ``nbr2``, so the repair argument is
+    unchanged).  Returns (new_view, n_conflicts, any_boundary_conflict).
     """
     nbr, prio, is_internal = arrs["nbr"], arrs["prio"], arrs["is_internal"]
     n_slots = view.shape[0]
+    width_arr = jnp.asarray(widths, jnp.int32)
+
+    def detect_at(w):
+        """Check the chunk's rows against neighbours ``w`` slots wide; the
+        carry goes through the branch and ``cols`` are whole ELL rows, as
+        in ``_parallel_chunk``."""
+        def branch(carry, cols, r_safe, active):
+            new_view, n_conf, bnd = carry
+            with jax.named_scope(tracing.NBR_GATHER):
+                tiles = (view[cols[:, :w]], prio[cols[:, :w]])
+                if distance == 2:
+                    nbr2 = arrs["nbr2"]
+                    tiles += (view[nbr2[r_safe]], prio[nbr2[r_safe]])
+            if distance == 2:
+                conf = ops.detect_conflicts_d2(view[r_safe], prio[r_safe],
+                                               *tiles, active, backend=backend)
+            else:
+                conf = ops.detect_conflicts(view[r_safe], prio[r_safe],
+                                            *tiles, active, backend=backend)
+            idx = jnp.where(conf, r_safe, n_slots - 1)   # sentinel stays 0
+            new_view = new_view.at[idx].set(0)
+            n_conf = n_conf + jnp.sum(conf, dtype=jnp.int32)
+            bnd = bnd | jnp.any(conf & ~is_internal[r_safe])
+            return new_view, n_conf, bnd
+        return branch
+
+    branches = [detect_at(w) for w in widths]
 
     def body(si, carry):
-        new_view, n_conf, bnd = carry
-        rows = jax.lax.dynamic_slice(order_pad, (si * superstep,),
+        rows = jax.lax.dynamic_slice(rows_pad, (si * superstep,),
                                      (superstep,))
         pos = si * superstep + jnp.arange(superstep, dtype=jnp.int32)
         active = (rows >= 0) & (pos < n_need)
         r_safe = jnp.maximum(rows, 0)
         with jax.named_scope(tracing.NBR_GATHER):
-            tiles = (view[nbr[r_safe]], prio[nbr[r_safe]])
-            if distance == 2:
-                nbr2 = arrs["nbr2"]
-                tiles += (view[nbr2[r_safe]], prio[nbr2[r_safe]])
-        if distance == 2:
-            conf = ops.detect_conflicts_d2(view[r_safe], prio[r_safe],
-                                           *tiles, active, backend=backend)
-        else:
-            conf = ops.detect_conflicts(view[r_safe], prio[r_safe], *tiles,
-                                        active, backend=backend)
-        idx = jnp.where(conf, r_safe, n_slots - 1)   # sentinel stays 0
-        new_view = new_view.at[idx].set(0)
-        n_conf = n_conf + jnp.sum(conf, dtype=jnp.int32)
-        bnd = bnd | jnp.any(conf & ~is_internal[r_safe])
-        return new_view, n_conf, bnd
+            cols = nbr[r_safe]                          # (superstep, maxd)
+        if len(widths) == 1:
+            return branches[0](carry, cols, r_safe, active)
+        b = jnp.sum(width_arr < chunk_wid[si], dtype=jnp.int32)
+        return jax.lax.switch(b, branches, carry, cols, r_safe, active)
 
     return jax.lax.fori_loop(
         0, n_steps, body, (view, jnp.int32(0), jnp.bool_(False)))
@@ -377,8 +488,18 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
     tile, n_tiles = _tiles(cfg, S)
     rows2 = (tile * n_tiles if cfg.use_parallel_chunk else 0) + 2 * S
 
+    # one-hop gather widths; distance 2 gathers the two-hop rows too, so it
+    # keeps the one full width, as recolor does
+    maxd = arrs["nbr"].shape[1]
+    widths = gather_widths(maxd) if cfg.distance == 1 else (maxd,)
+    width_arr = jnp.asarray(widths, jnp.int32)
+    bucketed = len(widths) > 1
+    degree = arrs["degree"]                  # ELL row length (real entries)
+    n_tiles_run = n_tiles if cfg.use_parallel_chunk else 0
+
     def round_body(state):
-        view, usage, rnd, n_conf_in, n_ex, n_bytes, n_rnd, n_g2 = state
+        (view, usage, rnd, n_conf_in, n_ex, n_bytes, n_rnd, n_g,
+         n_g2) = state
         order_r, n_need = _compact_order(order, view)
         n_need_max = comm.pmax(n_need)
         n_steps = (n_need_max + S - 1) // S
@@ -388,25 +509,58 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
         n_steps_all = (comm.lane_uniform(n_need_max) + S - 1) // S
         rkey = jax.random.fold_in(jax.random.fold_in(key, rnd), p_idx)
         rand_u32 = jax.random.bits(rkey, (n_slots,), jnp.uint32)
-        order_pad = jnp.concatenate(
-            [order_r, jnp.full((S,), -1, order_r.dtype)])
+        pad = jnp.full((S,), -1, order_r.dtype)
+        order_pad = jnp.concatenate([order_r, pad])
 
         # Which superstep chunks color at least one boundary vertex, on any
-        # shard (one pmax per round).  Chunks of interior vertices cannot
-        # change any exchange payload, so the exchanges they would trigger
-        # are elided below — bitwise-safe, the ghosts could not move.
+        # shard.  Chunks of interior vertices cannot change any exchange
+        # payload, so the exchanges they would trigger are elided below —
+        # bitwise-safe, the ghosts could not move.
         pos = jnp.arange(n_chunks_max * S, dtype=jnp.int32)
         opad = order_pad[: n_chunks_max * S]
         bnd = ((opad >= 0) & (pos < n_need)
                & ~arrs["is_internal"][jnp.maximum(opad, 0)])
-        chunk_bnd = comm.pmax(jnp.any(bnd.reshape(n_chunks_max, S), axis=1))
+        # Each detection chunk's and each coloring tile's widest row: only
+        # the frontier (the first n_need entries) is checked or colored.
+        # Detection reads the frontier sorted by degree, so its chunks hold
+        # rows of similar length.
+        det_pad = order_pad
+        if bucketed:
+            live = jnp.arange(n_local_max, dtype=jnp.int32) < n_need
+            row_deg = lambda rows: jnp.where(
+                live, degree[jnp.maximum(rows, 0)], 0)
+            det_r = _frontier_by_degree(order_r, n_need, degree)
+            det_pad = jnp.concatenate([det_r, pad])
+            wid_local = [_superstep_widths(row_deg(det_r), S, S, 1,
+                                           n_chunks_max)]
+            if n_tiles_run:
+                wid_local += [_superstep_widths(row_deg(order_r), S, tile,
+                                                n_tiles, n_chunks_max)]
+        else:
+            wid_local = [jnp.full((n_chunks_max, 1 + n_tiles_run), maxd,
+                                  jnp.int32)]
+        # one pmax a round for the exchange gates and the widths
+        red = comm.pmax(jnp.concatenate(
+            [jnp.any(bnd.reshape(n_chunks_max, S), axis=1,
+                     keepdims=True).astype(jnp.int32)] + wid_local, axis=1))
+        chunk_bnd = red[:, 0] > 0
+        wid_own = red[:, 1:]          # this lane's widths (its solo run's)
+        wid = comm.lane_uniform(wid_own)
+        w_own = width_arr[jnp.sum(width_arr < wid_own[..., None], axis=-1)]
+        # slots this lane's own supersteps gather (one detection chunk of S
+        # rows, then tiles of `tile` rows), on every shard alike
+        own = jnp.arange(n_chunks_max) < n_steps
+        n_rows = jnp.asarray([S] + [tile] * n_tiles_run, jnp.int32)
+        g = jnp.sum(jnp.where(own[:, None], w_own * n_rows, 0),
+                    dtype=jnp.int32) * comm.size()
 
         def superstep(si, carry):
             view, usage, n_ex, n_bytes, pending = carry
             if cfg.use_parallel_chunk:
                 view, usage = _parallel_chunk(view, usage, order_pad,
                                               rand_u32, si * S,
-                                              arrs, p_idx, cfg, S)
+                                              arrs, p_idx, cfg, S, widths,
+                                              wid[si, 1:])
             else:
                 view, usage = _greedy_chunk(view, usage, order_r, rand_u32,
                                             si * S, S, arrs, p_idx, cfg)
@@ -424,9 +578,12 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
         view, usage, n_ex, n_bytes, _ = jax.lax.fori_loop(
             0, n_steps_all, superstep,
             (view, usage, n_ex, n_bytes, jnp.bool_(False)))
+        # the lane-uniform trip count keeps each chunk's width index
+        # unbatched under a lane vmap (chunks past this lane's frontier
+        # check no row)
         view, n_conf, bnd_conf = _detect_conflicts_frontier(
-            view, arrs, order_pad, n_steps, n_need, S, backend=cfg.backend,
-            distance=cfg.distance)
+            view, arrs, det_pad, n_steps_all, n_need, S, widths, wid[:, 0],
+            backend=cfg.backend, distance=cfg.distance)
         # publish uncolorings only if a boundary vertex lost somewhere
         do_final = comm.pmax(bnd_conf)
         new_view, b = jax.lax.cond(comm.lane_uniform(do_final), exchange,
@@ -443,17 +600,17 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
                 n_ex + do_final.astype(jnp.int32),
                 n_bytes + jnp.where(do_final, b, 0),
                 n_rnd + (n_conf_in > 0).astype(jnp.int32),
-                n_g2 + g2 * maxd2)
+                n_g + g, n_g2 + g2 * maxd2)
 
     def cond(state):
-        _, _, rnd, n_conf, _, _, _, _ = state
+        _, _, rnd, n_conf, _, _, _, _, _ = state
         return comm.lane_uniform(n_conf > 0) & (rnd < cfg.max_rounds)
 
     state0 = (view0, usage0, jnp.int32(0), jnp.int32(1), jnp.int32(0),
-              jnp.int32(0), jnp.int32(0), jnp.int32(0))
+              jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0))
     # round 0 must run: seed n_conf=1
-    view, usage, _, _, n_ex, n_bytes, n_rounds, n_g2 = jax.lax.while_loop(
-        cond, round_body, state0)
+    (view, usage, _, _, n_ex, n_bytes, n_rounds, n_g,
+     n_g2) = jax.lax.while_loop(cond, round_body, state0)
 
     local_max = jnp.max(view[:n_local_max])
     # distinct classes in use — the corrected quality metric (Staggered FF
@@ -470,6 +627,7 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
         n_rounds=n_rounds,
         n_exchanges=n_ex,
         wire_bytes=n_bytes,
+        gather_slots=n_g,
         gather2_slots=n_g2,
     )
     return view, stats
@@ -531,8 +689,9 @@ def color_graph_sim(pg: PartitionedGraph, order, cfg: ColorConfig,
     to global ``(n,)`` colors) and ``stats`` are python ints — ``n_colors``
     (max id), ``n_colors_distinct`` (the quality metric), ``n_rounds``,
     ``n_exchanges``, ``wire_bytes`` (measured, per-shard max),
-    ``gather2_slots`` (slots read through the two-hop ELL, all shards; 0
-    at distance 1).  ``color_graph_sharded`` is the bitwise-identical mesh variant.
+    ``gather_slots`` (ELL slots gathered by the tiles and the detection
+    chunks, all shards), ``gather2_slots`` (slots read through the two-hop
+    ELL, all shards; 0 at distance 1).  ``color_graph_sharded`` is the bitwise-identical mesh variant.
     """
     cfg = resolve_cfg(pg, cfg)
     arrs = {k: jnp.asarray(v) for k, v in

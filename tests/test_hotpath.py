@@ -206,6 +206,115 @@ def test_parallel_mode_valid_and_backends_agree(graph, pgraph, selname):
     assert st_x["n_colors"] == st_p["n_colors"]
 
 
+# Colorings captured from the full-width speculative phase (before width-
+# bucketed tiles and detection chunks): (graph, order, selection, superstep,
+# tile) -> (n_colors, n_rounds, colors hash), and the range of gather_slots
+# as a share of the full-width program's.  Tiles of 8 rows fall into
+# several widths on RMAT.  The grid's tiles are its supersteps, 64 rows, so
+# each holds a degree-26 row, as the benchmark's 128-row tiles do; tiles of
+# a few boundary rows (degrees 7-17), or of none in a small late frontier,
+# would gather narrower.
+SPEC_GOLD = [
+    pytest.param("rmat11", ordering.NATURAL, selection.FIRST_FIT, 64, 8,
+                 (16, 4, "63a38d0e79f70c6e"), (0, 0.6), id="skewed-ff"),
+    pytest.param("rmat11", ordering.NATURAL, selection.RANDOM_X, 64, 8,
+                 (33, 3, "d1768c8431c43327"), (0, 0.6), id="skewed-rx"),
+    pytest.param("rmat11", ordering.INTERNAL_FIRST, selection.FIRST_FIT, 64,
+                 8, (15, 4, "2110ea94e4a0158c"), (0, 0.6),
+                 id="skewed-ff-internal-first"),
+    # 24-row tiles: the third of each superstep reaches into the next one
+    pytest.param("rmat11", ordering.NATURAL, selection.RANDOM_X, 64, 24,
+                 (32, 3, "6d3052a2520106e5"), (0, 0.7), id="skewed-rx-spill"),
+    pytest.param("grid10", ordering.NATURAL, selection.FIRST_FIT, 64, 64,
+                 (16, 8, "bc3a557754649deb"), (0.98, 1), id="grid-ff"),
+    pytest.param("grid10", ordering.NATURAL, selection.RANDOM_X, 64, 64,
+                 (31, 4, "642382230cbfd7cf"), (0.98, 1), id="grid-rx"),
+]
+
+
+def _full_width_run(monkeypatch, pg, order, cfg):
+    """``color_graph_sim`` with every tile and chunk gathered at ``maxd``:
+    the program before width buckets."""
+    from repro.core import speculative
+    monkeypatch.setattr(speculative, "gather_widths", lambda maxd: (maxd,))
+    speculative._sim_fn.cache_clear()
+    try:
+        return color_graph_sim(pg, order, cfg)
+    finally:
+        monkeypatch.undo()
+        speculative._sim_fn.cache_clear()
+
+
+@pytest.mark.parametrize("name,order_kind,selname,superstep,tile,gold,share",
+                         SPEC_GOLD)
+def test_speculative_bucketed_is_full_width(monkeypatch, name, order_kind,
+                                            selname, superstep, tile, gold,
+                                            share):
+    """Width-bucketed tiles and degree-sorted detection reproduce the
+    full-width speculative coloring bitwise, on both backends, and gather
+    fewer slots only where degrees are skewed."""
+    g, pg, _ = _rc_input(name)
+    order = compute_order(pg, order_kind)
+    mk = lambda b: ColorConfig(max_colors=512, superstep=superstep,
+                               tile=tile, selection=selname, seed=0,
+                               backend=b)
+    (v, st), (v_p, st_p) = [color_graph_sim(pg, order, mk(b))
+                            for b in ("xla", "pallas")]
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(v_p))
+    assert st == st_p
+    colors = colors_from_views(pg, np.asarray(v))
+    assert_valid(g, colors, what=f"speculative-{name}-{selname}")
+    assert (st["n_colors"], st["n_rounds"], _hash(colors)) == gold
+    v_f, st_f = _full_width_run(monkeypatch, pg, order, mk("xla"))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(v_f))
+    lo, hi = share
+    assert lo * st_f["gather_slots"] <= st["gather_slots"] <= (
+        hi * st_f["gather_slots"]), (st["gather_slots"], st_f["gather_slots"])
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_detection_is_free_of_order(pgraph, backend):
+    """Conflict detection over the frontier in visit order, at full width,
+    and sorted by degree, at each chunk's width, gives the same uncolorings
+    on a view with conflicts: every chunk reads the pre-detection view."""
+    import jax.numpy as jnp
+
+    from repro.core.speculative import (_detect_conflicts_frontier,
+                                        _frontier_by_degree,
+                                        _superstep_widths, gather_widths)
+    pg, S = pgraph, 32
+    arrs = {k: jnp.asarray(v[0]) for k, v in pg.arrays(sparse=False).items()}
+    rng = np.random.default_rng(7)
+    n_slots, nl = arrs["prio"].shape[0], int(pg.n_local[0])
+    view = rng.integers(1, 6, n_slots).astype(np.int32)
+    view[-1] = 0                                     # the sentinel
+    n_need = (3 * nl) // 4
+    order = np.full(pg.n_local_max, -1, np.int32)
+    order[:nl] = rng.permutation(nl)
+    order = jnp.asarray(order)
+    view = jnp.asarray(view)
+    n_chunks = -(-pg.n_local_max // S)
+    n_steps = -(-n_need // S)
+    pad = jnp.full((S,), -1, jnp.int32)
+    live = jnp.arange(pg.n_local_max) < n_need
+
+    def detect(rows, widths):
+        row_deg = jnp.where(live, arrs["degree"][jnp.maximum(rows, 0)], 0)
+        wid = _superstep_widths(row_deg, S, S, 1, n_chunks)[:, 0]
+        return _detect_conflicts_frontier(
+            view, arrs, jnp.concatenate([rows, pad]), n_steps, n_need, S,
+            widths, wid, backend=backend)
+
+    widths = gather_widths(pg.maxd)
+    assert len(widths) >= 3
+    want = detect(order, (pg.maxd,))
+    assert int(want[1]) > 0 and bool(want[2])
+    for rows in (order, _frontier_by_degree(order, n_need, arrs["degree"])):
+        got = detect(rows, widths)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        assert int(got[1]) == int(want[1]) and bool(got[2]) == bool(want[2])
+
+
 # ------------------------------------------- recolor: chunked ELL vs seed --
 
 def _seed_coloring(pg):
@@ -350,29 +459,70 @@ def _engine_step_jaxpr(pg, cfg, B=2):
                                 jax.random.split(jax.random.key(0), B))
 
 
-@pytest.mark.parametrize("program", [_fused_batch_jaxpr, _engine_step_jaxpr],
-                         ids=["fused_batch", "engine_step"])
-def test_recolor_width_dispatch_is_one_cond(program):
-    """The chunk's width is one ``cond`` with a branch per width, on an
-    index that no vmap batches: no narrow gather runs outside it, as vmap's
-    run-every-branch lowering (gathers merged by ``select_n``) would."""
+def _many_batch_jaxpr(pg, cfg, B=2):
+    """The bucketed batch's program (``color_many``): B graph lanes."""
+    import jax.numpy as jnp
+
+    from repro.core.pipeline import _many_sim_program, _signature
+    arrs = pg.arrays(sparse=False)
+    lanes = lambda x: jax.ShapeDtypeStruct((B,) + np.shape(x),
+                                           jnp.asarray(x).dtype)
+    arrs_b = {k: lanes(v) for k, v in arrs.items()}
+    order = lanes(compute_order(pg, ordering.NATURAL))
+    keys = jax.random.split(jax.random.key(0), B)
+    fn = _many_sim_program(_signature("many_sim", pg.P, cfg, None, arrs_b,
+                                      batch=B), pg.P, cfg, None)
+    return jax.make_jaxpr(fn)(arrs_b, order, keys, keys)
+
+
+# Rows a gather tile holds in each bucketed phase of the dispatch tests: the
+# recolor chunk, the speculative tile and the detection chunk (the
+# superstep), all different so that each phase's dispatch is told apart.
+DISPATCH_ROWS = dict(recolor=16, tile=8, detect=32)
+
+
+def _dispatch(program, rows):
+    """Width dispatches (``cond``s) and stray narrow gathers of ``rows``-row
+    tiles in ``program``'s jaxpr, on a skewed graph with >= 3 widths."""
     from repro.core import PipelineConfig
     from repro.core.pipeline import program_cache_clear
-    from repro.core.recolor import gather_widths
+    from repro.core.speculative import gather_widths
     pg = partition_graph(rmat.rmat_good(8, 8, seed=1), 2)
     widths = gather_widths(pg.maxd)
     assert len(widths) >= 3
-    chunk = 16
     cfg = PipelineConfig(
-        color=ColorConfig(max_colors=64, superstep=16, scheme="allgather"),
-        recolor=RecolorConfig(max_colors=64, chunk=chunk,
+        color=ColorConfig(max_colors=64, superstep=DISPATCH_ROWS["detect"],
+                          tile=DISPATCH_ROWS["tile"], scheme="allgather"),
+        recolor=RecolorConfig(max_colors=64, chunk=DISPATCH_ROWS["recolor"],
                               scheme="allgather"), n_iters=1)
     try:
         jx = program(pg, cfg)
     finally:
         program_cache_clear()
     found, stray = [], []
-    _width_dispatch(jx.jaxpr, widths, chunk, found, stray)
+    _width_dispatch(jx.jaxpr, widths, rows, found, stray)
+    return found, stray
+
+
+@pytest.mark.parametrize("program", [_fused_batch_jaxpr, _engine_step_jaxpr],
+                         ids=["fused_batch", "engine_step"])
+def test_recolor_width_dispatch_is_one_cond(program):
+    """The chunk's width is one ``cond`` with a branch per width, on an
+    index that no vmap batches: no narrow gather runs outside it, as vmap's
+    run-every-branch lowering (gathers merged by ``select_n``) would."""
+    found, stray = _dispatch(program, DISPATCH_ROWS["recolor"])
+    assert len(found) == 1, len(found)
+    assert not stray
+
+
+@pytest.mark.parametrize("phase", ["tile", "detect"])
+@pytest.mark.parametrize("program", [_fused_batch_jaxpr, _many_batch_jaxpr],
+                         ids=["fused_batch", "many_batch"])
+def test_speculative_width_dispatch_is_one_cond(program, phase):
+    """The speculative phase's coloring tiles and detection chunks each
+    take their width through one ``cond`` with a branch per width, also
+    with graph lanes under vmap, and gather no narrow tile outside it."""
+    found, stray = _dispatch(program, DISPATCH_ROWS[phase])
     assert len(found) == 1, len(found)
     assert not stray
 

@@ -132,7 +132,8 @@ def test_pad_partition_preserves_coloring():
     np.testing.assert_array_equal(outs[0][0], outs[1][0])
     # gather_slots counts the slots of the padded tiles (more rows a
     # chunk, a wider top width), so it is compared apart from the rest
-    slots = [[h.pop("gather_slots") for h in r["history"]] for _, r in outs]
+    slots = [[h.pop("gather_slots") for h in r["history"] + [r["color"]]]
+             for _, r in outs]
     assert all(a <= b for a, b in zip(*slots))
     assert outs[0][1] == outs[1][1]              # histories + color stats
     assert pad_partition(pg) is pg               # no-op fast path

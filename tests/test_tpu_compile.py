@@ -80,8 +80,8 @@ SELECTIONS = [("first_fit", {}), ("random_x", dict(x=10)),
               ("staggered", dict(offset=64))]
 
 
-# the recolor chunk's narrowest widths (``recolor.gather_widths``) and the
-# full ELL width
+# the narrowest widths of a recolor chunk or a speculative tile
+# (``speculative.gather_widths``) and the full ELL width
 @pytest.mark.parametrize("width", [8, 16, MAXD])
 @pytest.mark.parametrize("tiles", [1, 2], ids=["d1", "d2"])
 @pytest.mark.parametrize("selection,kw", SELECTIONS,
@@ -107,6 +107,17 @@ def test_conflict_kernel_compiles(chip_compile, tiles):
         return entry(myc, myp, *nbrs, active, backend="pallas")
 
     chip_compile(fn, _spec((V,)), _spec((V,)), *[_spec((V, MAXD))] * 2 * tiles,
+                 _spec((V,), jnp.bool_))
+
+
+# the speculative phase's detection chunks gather at the same widths
+@pytest.mark.parametrize("width", [8, 16])
+def test_conflict_kernel_compiles_narrow(chip_compile, width):
+    def fn(myc, myp, *args):
+        *nbrs, active = args
+        return ops.detect_conflicts(myc, myp, *nbrs, active, backend="pallas")
+
+    chip_compile(fn, _spec((V,)), _spec((V,)), *[_spec((V, width))] * 2,
                  _spec((V,), jnp.bool_))
 
 
