@@ -5,18 +5,18 @@
 
 Batch phase: ``rmat_good(20, 8)`` (1,048,576 vertices) partitioned P=4 and
 simulated on one chip, through the paper's quality preset (Random-X Fit,
-X=10, then 5 fused ND recoloring iterations) and ``pipeline_sim``.  The
+X=10, then 5 fused ND recoloring iterations) and ``run_pipeline``.  The
 coloring must be valid, the compiled program must hold the Pallas kernels
 (``tpu_custom_call``), and the XLA twin (``backend="xla"``) must give a
 bitwise-identical view.
 
 Service phase: a ``ColoringService`` in continuous mode, on the wall clock,
 answers six requests (RMAT scales 14-16).  Every future must resolve valid
-and bitwise equal to its solo ``pipeline_sim`` run.
+and bitwise equal to its solo ``run_pipeline`` run.
 
-Sharded phase (``--chips 4``): ``pipeline_sharded`` on a four-device
-``workers`` mesh, under both exchange schemes, must equal ``pipeline_sim``
-bitwise, with each device holding its own shard.
+Sharded phase (``--chips 4``): ``run_pipeline`` on a four-device
+``workers`` mesh, under both exchange schemes, must equal its one-device
+run bitwise, with each device holding its own shard.
 
 Timings printed are bring-up readings, not benchmarks.  Any failure exits
 non-zero; so does a run that finds no TPU.  The last line of standard
@@ -39,10 +39,9 @@ import numpy as np  # noqa: E402
 
 from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.core import (check_coloring, colors_from_views,  # noqa: E402
-                        compute_order, partition_graph, presets, rmat)
-from repro.core.pipeline import (_pipeline_result,  # noqa: E402
-                                 pipeline_sharded, pipeline_sim,
-                                 pipeline_sim_program)
+                        compute_order, partition_graph, presets, rmat,
+                        run_pipeline)
+from repro.core.pipeline import pipeline_program  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch.mesh import make_worker_mesh  # noqa: E402
 from repro.launch.serve_coloring import (ColoringService,  # noqa: E402
@@ -83,7 +82,7 @@ def _quality_run(scale: int, P: int, n_iters: int, seed: int):
 
 
 def _same_run(a, b) -> bool:
-    """Bitwise equality of two ``pipeline_*`` results ``(view, result)``."""
+    """Bitwise equality of two ``run_pipeline`` results ``(view, result)``."""
     return (np.array_equal(np.asarray(a[0]), np.asarray(b[0]))
             and a[1] == b[1])
 
@@ -101,21 +100,21 @@ def batch_phase(*, scale: int = 20, P: int = 4, n_iters: int = 5,
     cfg = _with(cfg, backend=backend)
     prep_s = time.perf_counter() - t0
 
-    fn, args = pipeline_sim_program(pg, order, cfg)
+    fn, args = pipeline_program(pg, order, cfg)
     t0 = time.perf_counter()
     compiled = fn.lower(*args).compile()
     compile_s = time.perf_counter() - t0
     kernels = compiled.as_text().count("tpu_custom_call")
 
     t0 = time.perf_counter()
-    run = pipeline_sim(pg, order, cfg)       # dispatches the compiled program
+    run = run_pipeline(pg, order, cfg)       # dispatches the compiled program
     jax.block_until_ready(run[0])
     run_s = time.perf_counter() - t0
     check = check_coloring(g, colors_from_views(pg, np.asarray(run[0])))
     _require(check["valid"], f"batch coloring invalid: {check}")
 
     t0 = time.perf_counter()
-    twin = pipeline_sim(pg, order, _with(cfg, backend="xla"))
+    twin = run_pipeline(pg, order, _with(cfg, backend="xla"))
     twin_s = time.perf_counter() - t0
     _require(_same_run(run, twin),
              "batch: kernel path and XLA twin differ")
@@ -132,7 +131,7 @@ def service_phase(*, n_requests: int = 6, scale_lo: int = 14,
                   scale_hi: int = 16, P: int = 4, n_iters: int = 8,
                   seed: int = 0) -> dict:
     """Continuous-mode service on the wall clock; each request checked
-    valid and bitwise against its solo ``pipeline_sim`` run."""
+    valid and bitwise against its solo ``run_pipeline`` run."""
     graphs = _traffic(n_requests, scale_lo, scale_hi, seed)
     svc = ColoringService(P=P, validate=True,
                           cfg=default_config(n_iters=n_iters),
@@ -150,7 +149,7 @@ def service_phase(*, n_requests: int = 6, scale_lo: int = 14,
         m, rcfg = out["member"], out["cfg"]
         ck = jax.random.fold_in(jax.random.key(rcfg.color.seed), f.id)
         rk = jax.random.fold_in(jax.random.key(rcfg.seed), f.id)
-        view, solo = pipeline_sim(m, compute_order(m, svc.order_kind), rcfg,
+        view, solo = run_pipeline(m, compute_order(m, svc.order_kind), rcfg,
                                   color_key=ck, recolor_key=rk)
         colors = m.gather_global_colors(np.asarray(view)[:, :m.n_local_max])
         _require(np.array_equal(colors, out["colors"])
@@ -167,24 +166,16 @@ def service_phase(*, n_requests: int = 6, scale_lo: int = 14,
 
 def sharded_phase(*, scale: int = 20, P: int = 4, n_iters: int = 5,
                   seed: int = 0) -> dict:
-    """``pipeline_sharded`` over P devices == ``pipeline_sim`` on one.
-
-    Both one-device references are dispatched before the sharded runs
-    start, so their device time overlaps the sharded programs' compiles
-    (the phase's cost is paid on four chips)."""
+    """``run_pipeline`` over P devices == ``run_pipeline`` on one."""
     _require(len(jax.devices()) >= P, f"need {P} devices")
     g, pg, order, cfg = _quality_run(scale, P, n_iters, seed)
     mesh = make_worker_mesh(P)
     cfgs = {s: _with(cfg, scheme=s) for s in ("sparse", "allgather")}
     t0 = time.perf_counter()
-    pending = {}
-    for scheme, scfg in cfgs.items():
-        fn, args = pipeline_sim_program(pg, order, scfg)
-        pending[scheme] = fn(*args)          # dispatched, not awaited
     out = dict(n_vertices=g.n)
     for scheme, scfg in cfgs.items():
-        sh = pipeline_sharded(pg, order, scfg, mesh)
-        sim = _pipeline_result(*pending.pop(scheme))
+        sim = run_pipeline(pg, order, scfg)
+        sh = run_pipeline(pg, order, scfg, mesh)
         _require(_same_run(sim, sh), f"{scheme}: sharded differs from sim")
         shards = sh[0].addressable_shards
         _require(sorted(s.index[0].start for s in shards) == list(range(P))

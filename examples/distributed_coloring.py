@@ -36,12 +36,12 @@ for preset in (presets.speed(), presets.quality(x=10)):
 # real sharded execution if the process has multiple devices
 if len(jax.devices()) >= P:
     from jax.sharding import AxisType
-    from repro.core import ColorConfig, color_graph_sharded, compute_order, ordering
+    from repro.core import ColorConfig, color_graph, compute_order, ordering
     mesh = jax.make_mesh((P,), ("workers",), axis_types=(AxisType.Auto,))
     order = compute_order(pg, ordering.INTERNAL_FIRST)
-    view, stats = color_graph_sharded(pg, order,
-                                      ColorConfig(max_colors=1024,
-                                                  superstep=512), mesh)
+    view, stats = color_graph(pg, order,
+                              ColorConfig(max_colors=1024, superstep=512),
+                              mesh=mesh)
     print(f"\nshard_map over {P} real devices: {stats['n_colors']} colors")
 else:
     print(f"\n({len(jax.devices())} device(s) — rerun with "

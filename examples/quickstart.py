@@ -10,7 +10,7 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import numpy as np
 
 from repro.core import (check_coloring, colors_from_views, compute_order,
-                        partition_graph, pipeline_sim, presets, rmat)
+                        partition_graph, presets, rmat, run_pipeline)
 
 # 1. a graph (16k vertices, power-law degrees) partitioned over 8 workers
 g = rmat.rmat_good(14, 8, seed=1)
@@ -27,7 +27,7 @@ order = compute_order(pg, preset.ordering)
 # 3. one device-resident program: speculative coloring + up to 5 recoloring
 #    iterations (adaptive stop after 2 non-improving ones), per-iteration
 #    stats unpacked once at the end
-view, res = pipeline_sim(pg, order, cfg)
+view, res = run_pipeline(pg, order, cfg)
 print(f"initial: {res['color']['n_colors_distinct']} colors in "
       f"{res['color']['n_rounds']} rounds "
       f"({res['color']['n_exchanges']} boundary exchanges)")

@@ -5,10 +5,10 @@ shipped entry points at P=2 and asserts on the traced program itself:
 
 1. **Shard-uniform collective sequence** — the ordered list of collective
    primitives (psum/pmax/ppermute/all_gather/...) in the per-shard
-   program must be exactly the list in the vmapped (``run_sim``) and
-   graph-batched (``color_many``) programs, for every exchange scheme,
-   less the graph-batched program's reductions over its named lane axis
-   (the lane-uniform control values).  A shard- or lane-dependent
+   program must be exactly the list in the vmapped (``run_spmd`` on one
+   device) and graph-batched (``color_many``) programs, for every
+   exchange scheme, less the graph-batched program's reductions over its
+   named lane axis (the lane-uniform control values).  A shard- or lane-dependent
    collective would show up as a sequence mismatch — the static moral
    equivalent of a deadlock.
 2. **Scheme resolution** — ``scheme="auto"`` must trace to bitwise the
@@ -18,7 +18,7 @@ shipped entry points at P=2 and asserts on the traced program itself:
    ``while``/``cond``/``scan`` sub-jaxpr) contain zero callback
    primitives; the device loop never bounces through the host.
 4. **One compile per PlanSignature** — dispatching a ≥3-signature graph
-   family through ``pipeline_sim`` twice traces exactly once per
+   family through ``run_pipeline`` twice traces exactly once per
    distinct signature (the program-cache contract of DESIGN.md §2).
 
 ``run_trace_audit`` returns a :class:`TraceAudit`; the
@@ -141,15 +141,16 @@ def _entry_jaxprs(pg, cfg, P, jax):
     """(name -> abstract jaxpr) for one resolved config.
 
     ``pipe`` / ``loop`` are the per-shard SPMD programs behind
-    ``pipeline_sim`` / ``recolor_loop_sim``; ``pipe_vmap`` is the
-    ``run_sim`` lane-stacked program, ``lanes_vmap`` the same with the
-    graph-lane axis named (traced as one lane), and ``many`` the
-    graph-batched ``color_many`` program, which vmaps ``lanes_vmap`` over
-    that axis — the sequence equalities between them are check (1).
+    ``run_pipeline`` / ``recolor_iterations``; ``pipe_vmap`` is the
+    one-device ``run_spmd`` program, ``lanes_vmap`` one lane of the
+    graph-batched program (traced with the graph-lane axis named), and
+    ``many`` the graph-batched ``color_many`` program, which vmaps
+    ``lanes_vmap`` over that axis — the sequence equalities between them
+    are check (1).
     """
     import numpy as np
 
-    from ..core.comm import AXIS, LANE_AXIS, run_sim
+    from ..core.comm import AXIS, LANE_AXIS, run_spmd
     from ..core.pipeline import (_plan_static, color_then_recolor,
                                  recolor_loop_spmd)
 
@@ -163,20 +164,24 @@ def _entry_jaxprs(pg, cfg, P, jax):
     key = jax.random.key(0)
     axis_env = [(AXIS, P)]
 
-    pipe = lambda a, o, ck, rk: color_then_recolor(
-        a, o, ck, rk, cfg=cfg, P_size=P, plan_static=ps)
+    def pipe(a, o, ck, rk, axis=AXIS, lane_axes=()):
+        return color_then_recolor(a, o, ck, rk, cfg=cfg, P_size=P,
+                                  plan_static=ps, axis=axis,
+                                  lane_axes=lane_axes)
+
     loop = lambda a, v, rk: recolor_loop_spmd(
         a, v, rk, cfg=cfg, P_size=P, plan_static=ps)
 
     stack = lambda s: jax.ShapeDtypeStruct((P,) + tuple(s.shape), s.dtype)
     full_arrs = {k: stack(v) for k, v in shard_arrs.items()}
-    pipe_vmap = lambda a, o, ck, rk: run_sim(pipe, P, (a, o), (ck, rk))
-    pipe_lanes = lambda a, o, ck, rk: color_then_recolor(
-        a, o, ck, rk, cfg=cfg, P_size=P, plan_static=ps,
-        lane_axes=(LANE_AXIS,))
-    lanes_vmap = lambda a, o, ck, rk: run_sim(pipe_lanes, P, (a, o),
+    pipe_vmap = lambda a, o, ck, rk: run_spmd(pipe, None, P, (a, o),
                                               (ck, rk))
-    many = jax.vmap(lanes_vmap, in_axes=(0, 0, 0, 0), axis_name=LANE_AXIS)
+    one_lane = lambda a, o, ck, rk, axis, lane_axes: pipe(
+        a, o, ck, rk, axis, (LANE_AXIS,))
+    lanes_vmap = lambda a, o, ck, rk: run_spmd(one_lane, None, P, (a, o),
+                                               (ck, rk))
+    many = lambda a, o, ck, rk: run_spmd(pipe, None, P, (a, o), (ck, rk),
+                                         lanes=True)
     batch = lambda s: jax.ShapeDtypeStruct((2,) + tuple(s.shape), s.dtype)
     keys2 = jax.numpy.stack([key, jax.random.key(1)])
 
@@ -222,7 +227,7 @@ def _audit_collectives(audit: TraceAudit, pg, base_cfg, P, jax) -> None:
                       if not cbs else f"{name}/{scheme}: {list(cbs)}")
             audit.record("no-host-callbacks", not cbs, detail)
 
-    # Under run_sim's lane-vmap, shuffles (ppermute/all_gather/axis_index)
+    # Under the one-device executor's vmap, shuffles (ppermute/all_gather/axis_index)
     # lower into lane gathers; cross-shard *reductions* keep their
     # primitive.  So the shard-uniformity pin is: the ordered reduction
     # subsequence survives batching bit-for-bit; naming the graph-lane
@@ -279,8 +284,8 @@ def _audit_compile_cache(audit: TraceAudit, graphs, cfg, P, jax) -> None:
     """One XLA trace per distinct PlanSignature across a graph family."""
     from ..core.graph import partition_graph
     from ..core.ordering import NATURAL, compute_order
-    from ..core.pipeline import (pipeline_sim, plan_signature,
-                                 program_cache_clear, program_cache_stats)
+    from ..core.pipeline import plan_signature, run_pipeline
+    from ..core.programs import program_cache_clear, program_cache_stats
 
     program_cache_clear()
     sigs = set()
@@ -291,7 +296,7 @@ def _audit_compile_cache(audit: TraceAudit, graphs, cfg, P, jax) -> None:
         order = compute_order(pg, NATURAL)
         for seed in (0, 1):
             key = jax.random.key(seed)
-            pipeline_sim(pg, order, cfg, recolor_key=key)
+            run_pipeline(pg, order, cfg, recolor_key=key)
             dispatches += 1
     stats = program_cache_stats()
     audit.record(

@@ -1,12 +1,12 @@
-"""Communication abstraction: one SPMD code path, two executors, two schemes.
+"""Communication abstraction: one SPMD code path, one executor, two schemes.
 
 Algorithms in this package are written as *per-shard* SPMD functions that
-communicate exclusively through ``AxisComm`` (named-axis collectives). They
-can then run
+communicate exclusively through ``AxisComm`` (named-axis collectives).  The
+one executor, ``run_spmd``, takes the mesh, and runs them
 
-- **simulated** on a single device via ``jax.vmap(..., axis_name=AXIS)`` —
-  used for the paper's quality/scaling studies (P up to 512 simulated
-  processors on one CPU), and
+- **simulated** on a single device (``mesh=None``) via
+  ``jax.vmap(..., axis_name=AXIS)`` — used for the paper's quality/scaling
+  studies (P up to 512 simulated processors on one CPU), or
 - **sharded** on a real device mesh via ``shard_map`` — the production
   path; the multi-pod dry-run lowers exactly this.
 
@@ -176,7 +176,7 @@ def batch_axis_size(mesh) -> int:
 def mesh_axes(mesh) -> tuple:
     """Hashable ``((axis name, axis size), ...)`` — the program-cache key
     component that pins which mesh geometry a sharded program was traced
-    for (``pipeline.PlanSignature.axes``)."""
+    for (``programs.PlanSignature.axes``)."""
     return tuple((n, int(s)) for n, s in zip(mesh.axis_names,
                                              mesh.devices.shape))
 
@@ -205,32 +205,47 @@ def allgather_bytes_per_exchange(P_size: int, max_boundary: int,
     return (P_size - 1) * max_boundary * itemsize
 
 
-def resolve_scheme(scheme: str, pg) -> str:
+def resolve_scheme(scheme: str, part) -> str:
     """The trace-time sparse-vs-allgather decision (DESIGN.md §2).
 
     ``scheme`` other than ``"auto"`` is a user override and returns as-is.
     ``"auto"`` picks whichever exchange *physically ships* fewer bytes for
-    this partition: the sparse plan's padded (pow2-rung) buffer widths —
-    what the compiled ``ppermute`` rounds actually put on the wire —
-    against the ring all-gather's ``(P-1)·max_b``.  Both schemes produce
-    bitwise-identical colorings, so this is a pure cost decision; the
-    result lands in the program's ``PlanSignature``/jit key, never in user
-    config.  Ties go to sparse (fewer bytes *accounted* too, and zero
+    ``part`` — a ``PartitionedGraph``, or a ``GraphBucket`` whose members
+    share one compiled program: the sparse plan's padded (pow2-rung)
+    buffer widths (``part.plan_static``) — what the compiled ``ppermute``
+    rounds actually put on the wire — against the ring all-gather's
+    ``(P-1)·max_b`` (``part.P``, ``part.max_boundary``).  Both schemes
+    produce bitwise-identical colorings, so this is a pure cost decision;
+    the result lands in the program's ``PlanSignature``/jit key, never in
+    user config.  Ties go to sparse (fewer bytes *accounted* too, and zero
     rounds on cross-edge-free partitions).
     """
     if scheme != AUTO:
         return scheme
-    sparse_b = pg.comm_plan.bytes_per_exchange(padded=True)
+    sparse_b = sum(part.plan_static[1]) * 4
     return SPARSE if sparse_b <= allgather_bytes_per_exchange(
-        pg.P, pg.max_boundary) else ALLGATHER
+        part.P, part.max_boundary) else ALLGATHER
+
+
+def resolve_cfg(part, cfg):
+    """Concretize ``scheme="auto"`` on one stage config against ``part``.
+
+    Works on any frozen config dataclass with a ``scheme`` field
+    (ColorConfig / RecolorConfig); an explicit "sparse"/"allgather" passes
+    through untouched, so the flag stays a user override.
+    """
+    if cfg.scheme == AUTO:
+        cfg = dataclasses.replace(cfg, scheme=resolve_scheme(AUTO, part))
+    return cfg
 
 
 def stats_to_host(stats) -> dict:
     """Device stats dict -> python ints.
 
-    Works for 0-d scalars, per-shard ``(P,)`` stacks from ``run_sim`` and
-    sharded outputs alike: every stat is either shard-uniform (schedules are
-    pmax-reduced) or a quantity whose shard-max is the meaningful summary.
+    Works for 0-d scalars, per-shard ``(P,)`` stacks from ``run_spmd`` on
+    the simulator and sharded outputs alike: every stat is either
+    shard-uniform (schedules are pmax-reduced) or a quantity whose
+    shard-max is the meaningful summary.
 
     This is the pipeline's *single* blessed device->host exit (repro-lint's
     ``host-sync`` rule, DESIGN.md §9): the shard-maxes are launched async on
@@ -241,80 +256,75 @@ def stats_to_host(stats) -> dict:
     return {k: int(v) for k, v in host.items()}
 
 
-def run_sim(fn, P_size: int, sharded_args: tuple, broadcast_args: tuple = (),
-            axis: str = AXIS):
-    """Execute SPMD `fn` on ONE device by vmapping over the leading P axis.
+def _spmd_axes(mesh, lanes: bool) -> tuple:
+    """``(axis, lane_axes)`` a per-shard program runs under on ``mesh``.
 
-    ``sharded_args`` carry a leading axis of size ``P_size``; ``broadcast_args``
-    are replicated. `fn(*sharded, *broadcast)` must only communicate via
-    ``AxisComm`` (over ``axis``).
+    ``axis`` is the shard axis every data collective runs over:
+    ``shard_axis_of(mesh)``, or ``"workers"`` for the one-device
+    simulator (``mesh=None``).  ``lane_axes`` is empty for a solo program;
+    a graph-lane program adds the lane vmap's axis, after the mesh's batch
+    axis where it has one (``AxisComm.lane_uniform``).
     """
-    in_axes = tuple(0 for _ in sharded_args) + tuple(None for _ in broadcast_args)
-    return jax.vmap(fn, in_axes=in_axes, axis_name=axis,
-                    axis_size=P_size)(*sharded_args, *broadcast_args)
+    if mesh is None:
+        axis, baxis = AXIS, None
+    else:
+        axis, baxis = shard_axis_of(mesh), batch_axis_of(mesh)
+    if not lanes:
+        return axis, ()
+    return axis, ((baxis,) if baxis is not None else ()) + (LANE_AXIS,)
 
 
-def run_sharded(fn, mesh, sharded_args: tuple, broadcast_args: tuple = (),
-                axis: str | None = None):
-    """Execute SPMD `fn` over a real mesh shard axis via shard_map.
+def run_spmd(fn, mesh, P_size: int, sharded_args: tuple,
+             other_args: tuple = (), *, lanes: bool = False):
+    """Execute the per-shard SPMD ``fn`` over ``P_size`` shards.
 
-    ``axis`` defaults to ``shard_axis_of(mesh)`` — the coloring core never
-    assumes the axis is literally named ``workers``.
+    ``fn(*shard_args, *other_args, axis=, lane_axes=)`` communicates only
+    through ``AxisComm`` over the axes ``_spmd_axes(mesh, lanes)`` names.
+
+    - ``mesh=None`` — simulated on ONE device: a vmap over the leading
+      ``P`` axis of ``sharded_args``, named ``"workers"``;
+    - a mesh — ``shard_map`` over ``shard_axis_of(mesh)``, one shard per
+      device.
+
+    A solo program (``lanes=False``) takes ``sharded_args`` as ``(P, ...)``
+    and replicates ``other_args``; on a 2D ``batch × shard`` mesh the
+    graph is replicated over the batch axis.  A graph-lane program
+    (``lanes=True``) runs ``fn`` once per graph under a vmap named
+    ``LANE_AXIS``, and ``other_args`` carry ``(B, ...)`` per-lane values
+    (RNG keys).  Its ``sharded_args`` are ``(B, P, ...)`` on the simulator
+    and ``(P, B, ...)`` on a mesh; where the mesh has a ``batch`` axis,
+    dim 1 and the lane args are sharded over it (each device holds
+    ``B / batch_size`` lanes of one shard, so ``B`` must divide by the
+    batch-axis size), and on a 1-axis mesh the lanes ride inside each
+    shard, as a 2D mesh with ``batch=1`` lowers per shard.
     """
-    axis = shard_axis_of(mesh) if axis is None else axis
+    axis, lane_axes = _spmd_axes(mesh, lanes)
+    fn = partial(fn, axis=axis, lane_axes=lane_axes)
+    ns = len(sharded_args)
+    if mesh is None:
+        in_axes = (0,) * ns + (None,) * len(other_args)
+        shards = jax.vmap(fn, in_axes=in_axes, axis_name=axis,
+                          axis_size=P_size)
+        if lanes:
+            shards = jax.vmap(shards, axis_name=LANE_AXIS)
+        return shards(*sharded_args, *other_args)
+    if lanes:
+        fn = jax.vmap(fn, axis_name=LANE_AXIS)
+    baxis = batch_axis_of(mesh) if lanes else None
+    spec, other = (P(axis), P()) if baxis is None else (P(axis, baxis),
+                                                         P(baxis))
 
     def wrapped(*args):
-        ns = len(sharded_args)
         sh = [jax.tree.map(lambda x: x[0], a) for a in args[:ns]]
         out = fn(*sh, *args[ns:])
         return jax.tree.map(lambda x: x[None], out)
 
-    in_specs = tuple(P(axis) for _ in sharded_args) + tuple(
-        P() for _ in broadcast_args)
     # check_vma=False: loop carries start replicated and become
     # worker-varying after the first exchange
-    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(axis), check_vma=False)(
-                                *sharded_args, *broadcast_args)
-
-
-def run_sharded_many(fn, mesh, sharded_args: tuple, lane_args: tuple = (),
-                     axis: str | None = None):
-    """Execute a lane-vmapped SPMD ``fn`` on a 2D ``batch × shard`` mesh.
-
-    ``fn`` is the per-shard program already vmapped over a leading graph-lane
-    axis (``jax.vmap(color_then_recolor)``-style).  ``sharded_args`` carry
-    ``(P, B, ...)``: dim 0 shards over the shard axis, dim 1 over the batch
-    axis (so each device holds ``B / batch_size`` lanes of one shard) —
-    the vmap graph axis and the shard_map graph axis are distinct mesh
-    dimensions instead of vmap-inside-shard_map.  ``lane_args`` carry
-    ``(B, ...)`` per-lane values (RNG keys): sharded over the batch axis
-    only, replicated across shards.
-
-    On a mesh without a ``batch`` axis this defers to ``run_sharded`` with
-    the lanes as broadcast args — bitwise (and program-structure-wise) the
-    1-axis ``color_many_sharded`` path, which is also what a 2D mesh with
-    ``batch=1`` lowers to per shard.  ``B`` must divide by the batch-axis
-    size (the pipeline driver pads lanes to a multiple).
-    """
-    axis = shard_axis_of(mesh) if axis is None else axis
-    baxis = batch_axis_of(mesh)
-    if baxis is None:
-        return run_sharded(fn, mesh, sharded_args, lane_args, axis=axis)
-    # 2D mesh (a batch=1 axis included — every device then holds all B
-    # lanes, which is exactly the 1-axis per-shard program):
-
-    def wrapped(*args):
-        ns = len(sharded_args)
-        sh = [jax.tree.map(lambda x: x[0], a) for a in args[:ns]]
-        out = fn(*sh, *args[ns:])
-        return jax.tree.map(lambda x: x[None], out)
-
-    in_specs = tuple(P(axis, baxis) for _ in sharded_args) + tuple(
-        P(baxis) for _ in lane_args)
-    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(axis, baxis), check_vma=False)(
-                                *sharded_args, *lane_args)
+    return jax.shard_map(wrapped, mesh=mesh,
+                         in_specs=(spec,) * ns + (other,) * len(other_args),
+                         out_specs=spec, check_vma=False)(
+                             *sharded_args, *other_args)
 
 
 def exchange_boundary(view: jnp.ndarray, boundary: jnp.ndarray,

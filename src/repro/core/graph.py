@@ -341,7 +341,7 @@ class CommPlan:
         plan bytes (the paper's model; what ``stats["wire_bytes"]``
         measures).  ``padded=True`` counts the pow2-rung buffer widths the
         compiled program physically ships — the quantity the trace-time
-        sparse-vs-allgather decision compares (``pipeline.resolve_scheme``).
+        sparse-vs-allgather decision compares (``comm.resolve_scheme``).
         """
         ws = self.widths if padded else self.exact_widths
         if round_mask is None:
@@ -405,6 +405,12 @@ class PartitionedGraph:
     def comm_plan(self) -> CommPlan:
         """Sparse-exchange schedule; built once, cached on the instance."""
         return build_comm_plan(self)
+
+    @property
+    def plan_static(self) -> tuple:
+        """Hashable ``(shifts, padded widths)`` of ``comm_plan`` — the jit
+        cache key, as ``GraphBucket.plan_static`` is a bucket's."""
+        return self.comm_plan.static
 
     def arrays(self, *, sparse: bool = True) -> dict[str, np.ndarray]:
         """Device-ready dict (everything that the JAX kernels consume).
@@ -835,6 +841,11 @@ class GraphBucket:
     def plan_static(self) -> tuple:
         """Hashable shared ``(shifts, widths)`` — the batch's jit cache key."""
         return self._union_plan[0]
+
+    @property
+    def max_boundary(self) -> int:
+        """The members' shared (padded) boundary width."""
+        return self.members[0].max_boundary
 
     def member_arrays(self, j: int, *, sparse: bool = True) -> dict:
         """Device dict of member ``j`` under the *shared* comm schedule."""

@@ -3,7 +3,7 @@
 The paper's central result is a *loop*: a cheap speculative initial coloring
 followed by multiple recoloring iterations dominates the time-quality Pareto
 front.  The host-looped form (``recolor_iterations`` dispatching one
-``recolor_sim`` per iteration) pays a host-device round-trip per iteration —
+``recolor_step`` per iteration) pays a host-device round-trip per iteration —
 the color view and every stat sync through the host, and each permutation
 kind traces its own program.  ``color_then_recolor`` keeps the whole
 experiment resident on device, the "communicate only what changed"
@@ -30,16 +30,20 @@ Rokos et al. 2015) applied to the iteration loop itself:
   unpacked **once** at the end — the only host sync of the whole run.
 
 ``recolor_iterations`` is a thin wrapper over the recolor-only loop
-(``recolor_loop_sim``); the host loop survives behind ``fused=False`` as the
+(``recolor_loop_spmd``); the host loop survives behind ``fused=False`` as the
 bitwise reference (tests/test_pipeline.py pins fused == host at P ∈
 {2, 4, 16}, both exchange schemes, distance 1 and 2).
 
-**Batched multi-graph pipeline** (``color_many`` / ``color_many_sharded``,
-DESIGN.md §8): production coloring traffic arrives as *many*
-small-to-medium graphs (per-batch conflict graphs, per-tile sparsity
-patterns), not one giant one.  ``bucket_graphs`` pads the partitions into
-shape buckets; within a bucket the fused program is lifted over a leading
-graph axis with ``vmap`` — per-graph RNG keys, per-graph ``(K, n_stats)``
+Every driver here takes ``mesh``: ``None`` simulates the P shards on one
+device, a mesh runs them on its shard axis (``comm.run_spmd``), and both
+take their compiled programs from the one cache of ``programs.py``.
+
+**Batched multi-graph pipeline** (``color_many``, DESIGN.md §8):
+production coloring traffic arrives as *many* small-to-medium graphs
+(per-batch conflict graphs, per-tile sparsity patterns), not one giant
+one.  ``bucket_graphs`` pads the partitions into shape buckets; within a
+bucket the fused program is lifted over a leading graph axis with
+``vmap`` — per-graph RNG keys, per-graph ``(K, n_stats)``
 histories, and a per-graph adaptive stop.  The graph axis is a named vmap
 axis (``comm.LANE_AXIS``) in the program's ``lane_axes``, so its control
 values are lane-uniform as across a 2D mesh (DESIGN.md §10): the loops run
@@ -51,7 +55,6 @@ both exchange schemes, distance 1 and 2).
 from __future__ import annotations
 
 import dataclasses
-from collections import OrderedDict
 from functools import partial
 
 import jax
@@ -61,16 +64,17 @@ import numpy as np
 from repro import tracing
 
 from . import ordering
-from .comm import (ALLGATHER, AUTO, AXIS, LANE_AXIS, SPARSE, AxisComm,
-                   allgather_bytes_per_exchange, batch_axis_of,
-                   batch_axis_size, mesh_axes, run_sharded, run_sharded_many,
-                   run_sim, shard_axis_of, stats_to_host)
+from .comm import (AUTO, AXIS, SPARSE, AxisComm, batch_axis_size,
+                   resolve_cfg)
 from .graph import PartitionedGraph, _ceil_pow2, bucket_graphs
 from .ordering import compute_order
+from .programs import (PlanSignature, cached_jit, device_arrays, dispatch,
+                       signature, spmd_program)
 from .recolor import (ALL_PERMS, ND, PERM_IDS, RecolorConfig, class_sizes,
                       permutation_rank, permutation_rank_traced,
-                      recolor_pass_spmd, schedule_for_iteration)
-from .speculative import ColorConfig, _apply_partial, color_spmd, resolve_cfg
+                      recolor_pass_spmd, recolor_step,
+                      schedule_for_iteration)
+from .speculative import ColorConfig, _apply_partial, color_spmd
 
 # Column layout of the device-resident per-iteration history.  ``ran`` marks
 # rows the adaptive stop never reached (they stay zero).
@@ -86,9 +90,9 @@ class PipelineConfig:
     ``n_iters`` (K) caps the recoloring iterations; ``patience`` (in
     iterations, 0 = off) is the adaptive stop on the global distinct-color
     count; the color/recolor stages keep their own configs (``color=None``
-    = recolor-only).  Drivers: ``pipeline_sim`` / ``pipeline_sharded`` for
-    one graph, ``color_many`` / ``color_many_sharded`` for a bucketed
-    batch — all four bitwise-identical per graph for the same keys.
+    = recolor-only).  Drivers: ``run_pipeline`` for one graph,
+    ``color_many`` for a bucketed batch — on one device or a mesh, all
+    bitwise-identical per graph for the same keys.
     """
 
     color: ColorConfig | None = None  # None = recolor-only (seed view given)
@@ -363,196 +367,57 @@ def _history_to_host(hist) -> list[dict]:
     return out
 
 
-def _plan_static(pg: PartitionedGraph, cfg: PipelineConfig):
-    return pg.comm_plan.static if cfg.needs_sparse_plan else None
-
-
-def _pipeline_arrays(pg: PartitionedGraph, cfg: PipelineConfig) -> dict:
-    """Device-resident input dict, cached on the partition instance.
-
-    JAX arrays are immutable, so the same device buffers serve every
-    dispatch of this partition — a memoized serving entry pays the
-    host->device transfer once, not per warm request.
-    """
-    cache = pg.__dict__.setdefault("_device_arrays", {})
-    sparse = cfg.needs_sparse_plan
-    if sparse not in cache:
-        with jax.profiler.TraceAnnotation(tracing.UPLOAD):
-            cache[sparse] = {k: jnp.asarray(v)
-                             for k, v in pg.arrays(sparse=sparse).items()}
-    return cache[sparse]
-
-
-# ------------------------------------------------- compiled-program cache --
-
-@dataclasses.dataclass(frozen=True)
-class PlanSignature:
-    """Hashable identity of one compiled pipeline program (DESIGN.md §2).
-
-    Two dispatches with equal signatures share one lowered program.  The
-    named fields are the readable core (``launch/dryrun.py`` prints them):
-    ``rungs`` is the comm plan's static ``(shifts, pow2 widths)`` — the
-    part width quantization exists to stabilize — and ``scheme`` is the
-    *resolved* exchange scheme (never "auto").  ``dims`` pins every input
-    array's ``(name, shape, dtype)`` so signature equality is exactly as
-    strict as the jit trace, ``axes`` pins the mesh layout as ``((axis
-    name, axis size), ...)`` — two meshes with different axis names or
-    shapes lower different collectives, so they must not share a program —
-    and ``cfg`` carries the full static config; ``extra`` holds non-array
-    trace context (the mesh object, for sharded programs).
-    """
-
-    kind: str          # program family: pipe_sim | loop_sim | pipe_sharded
-                       # | many_sim | many_sharded
-    P: int
-    n_local_max: int
-    maxd: int
-    max_colors: int
-    distance: int
-    scheme: str        # resolved: "sparse" | "allgather"
-    rungs: tuple       # plan static (shifts, pow2 widths); () for allgather
-    batch: int         # vmapped graph lanes (0 = solo program)
-    cfg: object        # resolved PipelineConfig (trace-static)
-    dims: tuple        # ((name, shape, dtype), ...) of every input array
-    axes: tuple = ()   # mesh layout ((axis name, axis size), ...)
-    extra: object = None
-
-    def describe(self) -> str:
-        """The human-readable core (what ``dryrun --coloring`` reports)."""
-        axes = "×".join(f"{n}={s}" for n, s in self.axes) or "-"
-        return (f"kind={self.kind} P={self.P} "
-                f"n_local_max={self.n_local_max} maxd={self.maxd} "
-                f"max_colors={self.max_colors} distance={self.distance} "
-                f"scheme={self.scheme} batch={self.batch} axes={axes} "
-                f"rungs={self.rungs[1] if self.rungs else ()}")
-
-
-class _ProgramCache:
-    """Process-wide LRU of jitted pipeline programs keyed on PlanSignature.
-
-    ``hits``/``misses`` count signature lookups; ``traces`` counts actual
-    XLA traces (a Python side effect inside each jitted wrapper, executed
-    once per trace) — the regression tests pin ``traces`` so a silently
-    widened cache key can't reintroduce retrace-per-graph dispatch.
-    """
-
-    def __init__(self, maxsize: int = 128):
-        self._fns: OrderedDict = OrderedDict()
-        self.maxsize = maxsize
-        self.hits = self.misses = self.traces = 0
-
-    def get(self, sig: PlanSignature, build):
-        fn = self._fns.get(sig)
-        if fn is not None:
-            self._fns.move_to_end(sig)
-            self.hits += 1
-            return fn
-        self.misses += 1
-        fn = build()
-        self._fns[sig] = fn
-        while len(self._fns) > self.maxsize:
-            self._fns.popitem(last=False)
-        return fn
-
-    def clear(self):
-        self._fns.clear()
-        self.hits = self.misses = self.traces = 0
-
-
-_PROGRAMS = _ProgramCache()
-
-
-def program_cache_stats() -> dict:
-    """Snapshot of the process-wide program cache counters."""
-    return dict(hits=_PROGRAMS.hits, misses=_PROGRAMS.misses,
-                traces=_PROGRAMS.traces, size=len(_PROGRAMS._fns))
-
-
-def program_cache_clear() -> None:
-    """Drop every cached program and zero the counters (tests/benchmarks)."""
-    _PROGRAMS.clear()
-
-
-def _dispatch(sig: PlanSignature, build, *args):
-    """Look the program up (building it on a miss) and dispatch it; its
-    first call compiles, inside the span."""
-    with jax.profiler.TraceAnnotation(tracing.PROGRAM):
-        return _PROGRAMS.get(sig, build)(*args)
-
-
-def _count_traces(fn):
-    """Increment the trace counter when (and only when) XLA traces ``fn``."""
-    def wrapped(*args):
-        _PROGRAMS.traces += 1
-        return fn(*args)
-    return wrapped
-
-
-def _dims_of(arrs) -> tuple:
-    return tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                        for k, v in arrs.items()))
-
-
-def _mesh_axes_or_sim(mesh, P: int) -> tuple:
-    """Signature ``axes``: the mesh layout, or the sim executor's implied
-    single vmap axis (``run_sim`` binds ``AXIS`` at size P)."""
-    return ((AXIS, P),) if mesh is None else mesh_axes(mesh)
+def _plan_static(part, cfg: PipelineConfig):
+    """The plan statics a program of ``cfg`` over ``part`` (a partition or
+    a bucket) is traced for: ``None`` where no stage runs sparse."""
+    return part.plan_static if cfg.needs_sparse_plan else None
 
 
 def _signature(kind: str, P: int, cfg: PipelineConfig, plan_static, arrs,
-               batch: int = 0, extra=None) -> PlanSignature:
-    mc = (cfg.color.max_colors if cfg.color is not None
-          else cfg.recolor.max_colors)
-    return PlanSignature(
-        kind=kind, P=P, n_local_max=int(arrs["indptr"].shape[-1]) - 1,
-        maxd=int(arrs["nbr"].shape[-1]), max_colors=mc,
-        distance=cfg.recolor.distance, scheme=cfg.recolor.scheme,
-        rungs=plan_static if plan_static is not None else (),
-        batch=batch, cfg=cfg, dims=_dims_of(arrs),
-        axes=_mesh_axes_or_sim(extra, P), extra=extra)
+               batch: int = 0, mesh=None) -> PlanSignature:
+    """A fused program's signature: it names the seed stage's color bound
+    and scheme (the recolor stage's, recolor-only) and the distance."""
+    stage = cfg.recolor if cfg.color is None else cfg.color
+    return signature(kind, P, cfg, plan_static, arrs, stage=stage,
+                     batch=batch, mesh=mesh)
 
 
-def resolve_pipeline_cfg(pg: PartitionedGraph,
-                         cfg: PipelineConfig) -> PipelineConfig:
-    """Concretize any ``scheme="auto"`` stage against ``pg``'s comm plan.
+def resolve_pipeline_cfg(part, cfg: PipelineConfig) -> PipelineConfig:
+    """Concretize any ``scheme="auto"`` stage against ``part``'s comm plan.
 
-    The decision (``comm.resolve_scheme``) compares the *padded* sparse
-    plan bytes — what the compiled program physically ships — against the
-    broadcast's; an explicit scheme passes through untouched.
+    ``part`` is a ``PartitionedGraph``, or a ``GraphBucket`` whose members
+    share one compiled program (the decision is then made once, from the
+    union plan).  The decision (``comm.resolve_scheme``) compares the
+    *padded* sparse plan bytes — what the compiled program physically
+    ships — against the broadcast's; an explicit scheme passes through
+    untouched.
     """
     if not cfg.has_auto:
         return cfg
     return dataclasses.replace(
-        cfg, color=None if cfg.color is None else resolve_cfg(pg, cfg.color),
-        recolor=resolve_cfg(pg, cfg.recolor))
+        cfg, color=None if cfg.color is None else resolve_cfg(part, cfg.color),
+        recolor=resolve_cfg(part, cfg.recolor))
 
 
 def plan_signature(pg: PartitionedGraph, cfg: PipelineConfig, *,
-                   kind: str | None = None, batch: int = 0,
+                   kind: str = "pipe", batch: int = 0,
                    mesh=None) -> PlanSignature:
-    """The signature a ``pipeline_sim``-family dispatch of ``pg`` would use.
+    """The signature a ``run_pipeline`` dispatch of ``pg`` on ``mesh``
+    would use.
 
     Public inspection hook (``launch/dryrun.py``, the serving cost model,
     tests): resolves "auto", builds the device dict host-side and derives
-    the exact cache key without compiling anything.  ``mesh`` selects the
-    ``pipeline_sharded`` program (``kind`` defaults accordingly).
+    the exact cache key without compiling anything.
     """
-    if kind is None:
-        kind = "pipe_sim" if mesh is None else "pipe_sharded"
     cfg = resolve_pipeline_cfg(pg, cfg)
     arrs = pg.arrays(sparse=cfg.needs_sparse_plan)
     return _signature(kind, pg.P, cfg, _plan_static(pg, cfg), arrs,
-                      batch=batch, extra=mesh)
-
-
-def program_cache_contains(sig: PlanSignature) -> bool:
-    """Cache-probe for the serving cost model — no counter side effects."""
-    return sig in _PROGRAMS._fns
+                      batch=batch, mesh=mesh)
 
 
 def bucket_signature(bucket, cfg: PipelineConfig, *, pad_batch: bool = True,
                      mesh=None) -> PlanSignature:
-    """The signature a ``color_many``(`_sharded``) dispatch of ``bucket``
+    """The signature a ``color_many`` dispatch of ``bucket`` on ``mesh``
     would use.
 
     The serving driver's cost model probes the program cache with this
@@ -560,7 +425,7 @@ def bucket_signature(bucket, cfg: PipelineConfig, *, pad_batch: bool = True,
     batch padding and the sharded layout's axis swap are applied to shapes
     only.
     """
-    bcfg = _resolve_bucket_cfg(bucket, cfg)
+    bcfg = resolve_pipeline_cfg(bucket, cfg)
     ma = bucket.member_arrays(0, sparse=bcfg.needs_sparse_plan)
     lane_multiple = batch_axis_size(mesh) if mesh is not None else 1
     B = _lane_target(bucket.B, pad_batch, lane_multiple)
@@ -569,63 +434,57 @@ def bucket_signature(bucket, cfg: PipelineConfig, *, pad_batch: bool = True,
         s = (B,) + tuple(v.shape)
         return (s[1], s[0]) + s[2:] if mesh is not None else s
 
-    dims = tuple(sorted((k, dim(v), str(np.asarray(v).dtype))
-                        for k, v in ma.items()))
-    ps = bucket.plan_static if bcfg.needs_sparse_plan else None
-    mc = (bcfg.color.max_colors if bcfg.color is not None
-          else bcfg.recolor.max_colors)
-    return PlanSignature(
-        kind="many_sim" if mesh is None else "many_sharded", P=bucket.P,
-        n_local_max=bucket.members[0].n_local_max,
-        maxd=bucket.members[0].maxd, max_colors=mc,
-        distance=bcfg.recolor.distance, scheme=bcfg.recolor.scheme,
-        rungs=ps if ps is not None else (), batch=B, cfg=bcfg, dims=dims,
-        axes=_mesh_axes_or_sim(mesh, bucket.P), extra=mesh)
+    shapes = {k: jax.ShapeDtypeStruct(dim(v), np.asarray(v).dtype)
+              for k, v in ma.items()}
+    return _signature("many", bucket.P, bcfg, _plan_static(bucket, bcfg),
+                      shapes, batch=B, mesh=mesh)
 
 
-def _bucket_scheme(bucket) -> str:
-    """Trace-time sparse-vs-allgather pick for one bucket (union plan)."""
-    sparse_b = sum(bucket.plan_static[1]) * 4
-    ag_b = allgather_bytes_per_exchange(bucket.P,
-                                        bucket.members[0].max_boundary)
-    return SPARSE if sparse_b <= ag_b else ALLGATHER
+def _recolor_loop(pg: PartitionedGraph, view, cfg: PipelineConfig):
+    """Fused recolor-only loop on one device: ``recolor_iterations``' core.
 
-
-def _resolve_bucket_cfg(bucket, cfg: PipelineConfig) -> PipelineConfig:
-    """Per-bucket "auto" resolution: members share one compiled program, so
-    the decision is made once from the union plan's padded bytes."""
-    if not cfg.has_auto:
-        return cfg
-    scheme = _bucket_scheme(bucket)
-    fix = lambda c: (None if c is None else
-                     dataclasses.replace(c, scheme=scheme)
-                     if c.scheme == AUTO else c)
-    return dataclasses.replace(cfg, color=fix(cfg.color),
-                               recolor=fix(cfg.recolor))
-
-
-def recolor_loop_sim(pg: PartitionedGraph, view, cfg: PipelineConfig,
-                     key=None):
-    """Fused recolor-only loop (sim executor): ``recolor_iterations``' core.
-
-    Returns ``(view, history list-of-dicts, n_iters_run)``.
+    Returns ``(view, history list-of-dicts)``.
     """
     cfg = resolve_pipeline_cfg(pg, cfg)
-    arrs = _pipeline_arrays(pg, cfg)
-    if key is None:
-        key = jax.random.key(cfg.seed)
+    arrs = device_arrays(pg, cfg.needs_sparse_plan)
     ps = _plan_static(pg, cfg)
-    sig = _signature("loop_sim", pg.P, cfg, ps, arrs)
-
-    def build(P=pg.P):
-        fn = partial(recolor_loop_spmd, cfg=cfg, P_size=P, plan_static=ps)
-        return jax.jit(_count_traces(
-            lambda arrs, view, key: run_sim(fn, P, (arrs, view), (key,))))
-
-    view, hist, n_run = _dispatch(sig, build, arrs, jnp.asarray(view), key)
+    sig = _signature("loop", pg.P, cfg, ps, arrs)
+    fn = partial(recolor_loop_spmd, cfg=cfg, P_size=pg.P, plan_static=ps)
+    view, hist, _ = dispatch(sig, fn, pg.P, None, (arrs, jnp.asarray(view)),
+                             (jax.random.key(cfg.seed),))
     with jax.profiler.TraceAnnotation(tracing.WAIT):
-        hist, n_run = jax.device_get((hist, n_run))  # one host transfer
-    return view, _history_to_host(hist), int(np.max(n_run))
+        hist = jax.device_get(hist)                  # one host transfer
+    return view, _history_to_host(hist)
+
+
+def recolor_iterations(pg: PartitionedGraph, view, n_iters: int,
+                       cfg: RecolorConfig, *, base_perm: str = ND,
+                       rand_every: int = 0, rand_pow2: bool = False,
+                       seed: int = 0, collect=None, fused: bool = True):
+    """Run `n_iters` RC iterations with an ND-RAND%x style schedule (sim).
+
+    By default the loop runs *device-resident* through the fused recolor
+    loop (``recolor_loop_spmd``): one jitted program, no per-iteration
+    host round-trip, bitwise-identical views and history to the host loop.
+    ``fused=False`` forces the host loop (one ``recolor_step`` dispatch per
+    iteration) — kept as the reference the fused path is tested against;
+    ``collect=`` implies it, since per-iteration views must reach the host.
+    """
+    if fused and collect is None and n_iters > 0:
+        pcfg = PipelineConfig(
+            color=None, recolor=cfg, n_iters=n_iters, base_perm=base_perm,
+            rand_every=rand_every, rand_pow2=rand_pow2, seed=seed)
+        return _recolor_loop(pg, view, pcfg)
+    history = []
+    for it in range(1, n_iters + 1):
+        kind = schedule_for_iteration(it, base_perm, rand_every, rand_pow2)
+        key = jax.random.fold_in(jax.random.key(seed), it)
+        view, stats = recolor_step(pg, view, kind, cfg, key)
+        stats["iteration"], stats["perm"] = it, kind
+        history.append(stats)
+        if collect is not None:
+            collect(view, stats)
+    return view, history
 
 
 def _keys(cfg: PipelineConfig, color_key, recolor_key):
@@ -647,125 +506,60 @@ def _pipeline_result(view, cstats, hist, n_run):
                       n_iters_run=int(np.max(n_run)))
 
 
-def pipeline_sim(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
-                 marked=None, color_key=None, recolor_key=None):
-    """Run the fused pipeline *simulated* on one device (P vmap lanes).
+def _pipeline_parts(pg, order, cfg, mesh, marked, color_key, recolor_key):
+    """``(signature, per-shard fn, sharded args, other args)`` of the fused
+    program ``run_pipeline`` dispatches."""
+    assert cfg.color is not None, "run_pipeline needs cfg.color"
+    cfg = resolve_pipeline_cfg(pg, cfg)
+    arrs = device_arrays(pg, cfg.needs_sparse_plan)
+    order = jnp.asarray(_apply_partial(order, cfg.color, marked))
+    ps = _plan_static(pg, cfg)
+    sig = _signature("pipe", pg.P, cfg, ps, arrs, mesh=mesh)
+    fn = partial(color_then_recolor, cfg=cfg, P_size=pg.P, plan_static=ps)
+    return sig, fn, (arrs, order), _keys(cfg, color_key, recolor_key)
 
-    ``order``/``marked`` as ``color_graph_sim``; ``color_key`` /
+
+def run_pipeline(pg: PartitionedGraph, order, cfg: PipelineConfig,
+                 mesh=None, *, marked=None, color_key=None,
+                 recolor_key=None):
+    """Run the fused pipeline: speculative coloring + K recoloring
+    iterations in one device-resident program.
+
+    ``mesh=None`` simulates the P shards on one device (P vmap lanes); a
+    mesh runs them on its shard axis (``shard_axis_of(mesh)``) via
+    shard_map — on a 2D ``batch × shard`` mesh the solo graph is
+    replicated over the batch axis — bitwise the same results.
+    ``order``/``marked`` as ``speculative.color_graph``; ``color_key`` /
     ``recolor_key`` default to ``key(cfg.color.seed)`` / ``key(cfg.seed)``.
     Returns ``(view, result)``: ``view`` is the final ``(P, n_slots)``
     device view and ``result`` holds the initial-coloring stats
-    (``"color"``, keys as ``color_graph_sim``), the per-iteration
+    (``"color"``, keys as ``color_graph``), the per-iteration
     ``"history"`` (one dict per executed iteration, keys as
-    ``recolor_sim`` plus ``perm``/``iteration``) and ``"n_iters_run"``
-    (adaptive stop included).  ``pipeline_sharded`` is the
-    bitwise-identical ``workers``-mesh variant.
+    ``recolor_step`` plus ``perm``/``iteration``) and ``"n_iters_run"``
+    (adaptive stop included).
     """
-    sig, build, args = _pipeline_sim_parts(pg, order, cfg, marked,
-                                           color_key, recolor_key)
-    return _pipeline_result(*_dispatch(sig, build, *args))
+    sig, fn, sharded, keys = _pipeline_parts(pg, order, cfg, mesh, marked,
+                                             color_key, recolor_key)
+    return _pipeline_result(*dispatch(sig, fn, pg.P, mesh, sharded, keys))
 
 
-def pipeline_sim_program(pg: PartitionedGraph, order, cfg: PipelineConfig, *,
-                         marked=None, color_key=None, recolor_key=None):
-    """The cached jitted program ``pipeline_sim`` dispatches, with its
+# The benchmark's executors (``bench/harness/drivers/batch_closed.py``,
+# ``bench/tests/test_reference.py``) import these two names; ``mesh`` is
+# the fourth positional parameter, so both of their call shapes work.
+pipeline_sim = pipeline_sharded = run_pipeline
+
+
+def pipeline_program(pg: PartitionedGraph, order, cfg: PipelineConfig,
+                     mesh=None, *, marked=None, color_key=None,
+                     recolor_key=None):
+    """The cached jitted program ``run_pipeline`` dispatches, with its
     arguments: ``(fn, args)``.  ``fn.lower(*args).compile()`` compiles
-    exactly what ``pipeline_sim`` runs (compile timing, HLO inspection,
-    compiling for a described device from the arguments' shapes)."""
-    sig, build, args = _pipeline_sim_parts(pg, order, cfg, marked,
-                                           color_key, recolor_key)
-    return _PROGRAMS.get(sig, build), args
-
-
-def _pipeline_sim_parts(pg, order, cfg, marked, color_key, recolor_key):
-    """``(signature, build, args)`` of ``pipeline_sim``'s program."""
-    assert cfg.color is not None, "pipeline_sim needs cfg.color"
-    cfg = resolve_pipeline_cfg(pg, cfg)
-    arrs = _pipeline_arrays(pg, cfg)
-    order = _apply_partial(order, cfg.color, marked)
-    ck, rk = _keys(cfg, color_key, recolor_key)
-    ps = _plan_static(pg, cfg)
-    sig = _signature("pipe_sim", pg.P, cfg, ps, arrs)
-
-    def build(P=pg.P):
-        fn = partial(color_then_recolor, cfg=cfg, P_size=P, plan_static=ps)
-        return jax.jit(_count_traces(
-            lambda arrs, order, ck, rk: run_sim(fn, P, (arrs, order),
-                                                (ck, rk))))
-
-    return sig, build, (arrs, jnp.asarray(order), ck, rk)
-
-
-def pipeline_sharded(pg: PartitionedGraph, order, cfg: PipelineConfig, mesh,
-                     *, marked=None, color_key=None, recolor_key=None):
-    """Run the fused pipeline on a real mesh shard axis
-    (``shard_axis_of(mesh)``) via shard_map; on a 2D ``batch × shard``
-    mesh the solo graph is replicated over the batch axis."""
-    assert cfg.color is not None, "pipeline_sharded needs cfg.color"
-    cfg = resolve_pipeline_cfg(pg, cfg)
-    arrs = _pipeline_arrays(pg, cfg)
-    order = _apply_partial(order, cfg.color, marked)
-    ck, rk = _keys(cfg, color_key, recolor_key)
-    ps = _plan_static(pg, cfg)
-    sig = _signature("pipe_sharded", pg.P, cfg, ps, arrs, extra=mesh)
-
-    def build(P=pg.P):
-        axis = shard_axis_of(mesh)
-        fn = partial(color_then_recolor, cfg=cfg, P_size=P, plan_static=ps,
-                     axis=axis)
-        return jax.jit(_count_traces(
-            lambda a, o, k1, k2: run_sharded(fn, mesh, (a, o), (k1, k2),
-                                             axis=axis)))
-
-    out = _dispatch(sig, build, arrs, jnp.asarray(order), ck, rk)
-    return _pipeline_result(*out)
-
-
-# ------------------------------------------- batched multi-graph pipeline --
-#
-# The graph lanes one device holds are a vmap named ``LANE_AXIS``, and the
-# lane programs carry it in ``lane_axes`` (with a 2D mesh's batch axis):
-# their control values (trip counts, exchange gates, the recolor chunk
-# width) are then lane-uniform, so each ``cond``/``switch`` stays one branch
-# taken instead of vmap's run-every-branch + select.
-
-def _mesh_lane_axes(mesh) -> tuple:
-    """``lane_axes`` of a lane program inside ``run_sharded_many``."""
-    baxis = batch_axis_of(mesh)
-    return ((baxis,) if baxis is not None else ()) + (LANE_AXIS,)
-
-
-def _many_sim_program(sig, P, cfg, plan_static):
-    """One jitted program per signature: vmap over graphs of vmap over
-    shards — reused across batches (and graphs) through ``_PROGRAMS``."""
-    def build():
-        fn = partial(color_then_recolor, cfg=cfg, P_size=P,
-                     plan_static=plan_static, lane_axes=(LANE_AXIS,))
-        inner = lambda arrs, order, ck, rk: run_sim(fn, P, (arrs, order),
-                                                    (ck, rk))
-        return jax.jit(_count_traces(jax.vmap(inner, axis_name=LANE_AXIS)))
-    return _PROGRAMS.get(sig, build)
-
-
-def _many_sharded_program(sig, P, cfg, plan_static, mesh):
-    """Cached mesh dispatch — without it every flush would rebuild the
-    vmap/jit wrappers and recompile, defeating the pow2 shape bucketing
-    the serving path relies on.
-
-    On a 2D ``batch × shard`` mesh the graph lanes are *sharded* over the
-    batch axis (``run_sharded_many``): each device vmaps only its B/Bm
-    lanes, and the per-graph RNG keys ride as batch-sharded lane args.  On
-    a 1D mesh this degenerates to the classic vmap-inside-shard_map."""
-    def build():
-        axis = shard_axis_of(mesh)
-        fn = jax.vmap(partial(color_then_recolor, cfg=cfg, P_size=P,
-                              plan_static=plan_static, axis=axis,
-                              lane_axes=_mesh_lane_axes(mesh)),
-                      axis_name=LANE_AXIS)
-        return jax.jit(_count_traces(
-            lambda a, o, k1, k2: run_sharded_many(fn, mesh, (a, o),
-                                                  (k1, k2), axis=axis)))
-    return _PROGRAMS.get(sig, build)
+    exactly what ``run_pipeline`` runs (compile timing, HLO inspection,
+    compiling for a described device from the arguments' shapes), and
+    ``fn(*args)`` dispatches it without waiting for the result."""
+    sig, fn, sharded, keys = _pipeline_parts(pg, order, cfg, mesh, marked,
+                                             color_key, recolor_key)
+    return spmd_program(sig, fn, pg.P, len(sharded), mesh), sharded + keys
 
 
 # ----------------------------------------------- continuous-engine programs --
@@ -781,22 +575,10 @@ def engine_init_program(P: int, cfg: PipelineConfig, plan_static, arrs,
     into its lane buffers, so admission never recompiles (DESIGN.md §11).
     """
     assert not cfg.has_auto
-    sig = _signature("engine_init", P, cfg, plan_static, arrs, extra=mesh)
-
-    def build():
-        if mesh is None:
-            fn = partial(pipeline_carry_spmd, cfg=cfg, P_size=P,
-                         plan_static=plan_static)
-            return jax.jit(_count_traces(
-                lambda a, o, ck: run_sim(fn, P, (a, o), (ck,))))
-        axis = shard_axis_of(mesh)
-        fn = partial(pipeline_carry_spmd, cfg=cfg, P_size=P,
-                     plan_static=plan_static, axis=axis)
-        return jax.jit(_count_traces(
-            lambda a, o, ck: run_sharded(fn, mesh, (a, o), (ck,),
-                                         axis=axis)))
-
-    return _PROGRAMS.get(sig, build)
+    sig = _signature("engine_init", P, cfg, plan_static, arrs, mesh=mesh)
+    fn = partial(pipeline_carry_spmd, cfg=cfg, P_size=P,
+                 plan_static=plan_static)
+    return spmd_program(sig, fn, P, 2, mesh)
 
 
 def engine_step_program(P: int, cfg: PipelineConfig, plan_static, arrs,
@@ -808,34 +590,17 @@ def engine_step_program(P: int, cfg: PipelineConfig, plan_static, arrs,
     over the B lane axis), with the carry input buffers **donated**: the
     engine owns exactly one generation of lane state at a time.  Sim
     layout stacks lanes on axis 0 (``(B, P, ...)``, ``done (B, P)``); on a
-    mesh the lanes ride ``run_sharded_many``'s ``(P, B, ...)`` layout
+    mesh the lanes ride ``comm.run_spmd``'s ``(P, B, ...)`` layout
     (``done (P, B)``) and are sharded over the batch mesh axis.  Lanes
     whose stop has tripped (or that are empty) are frozen by the body's
     select-mask, so a partially idle engine steps bitwise-inertly.
     """
     assert not cfg.has_auto
     sig = _signature(f"engine_step{chunk}", P, cfg, plan_static, arrs,
-                     batch=B, extra=mesh)
-
-    def build():
-        if mesh is None:
-            fn = partial(pipeline_step_spmd, cfg=cfg, chunk=chunk, P_size=P,
-                         plan_static=plan_static, lane_axes=(LANE_AXIS,))
-            inner = lambda a, c, k: run_sim(fn, P, (a, c), (k,))
-            return jax.jit(_count_traces(jax.vmap(inner,
-                                                  axis_name=LANE_AXIS)),
-                           donate_argnums=(1,))
-        axis = shard_axis_of(mesh)
-        fn = jax.vmap(partial(pipeline_step_spmd, cfg=cfg, chunk=chunk,
-                              P_size=P, plan_static=plan_static, axis=axis,
-                              lane_axes=_mesh_lane_axes(mesh)),
-                      axis_name=LANE_AXIS)
-        return jax.jit(_count_traces(
-            lambda a, c, k: run_sharded_many(fn, mesh, (a, c), (k,),
-                                             axis=axis)),
-            donate_argnums=(1,))
-
-    return _PROGRAMS.get(sig, build)
+                     batch=B, mesh=mesh)
+    fn = partial(pipeline_step_spmd, cfg=cfg, chunk=chunk, P_size=P,
+                 plan_static=plan_static)
+    return spmd_program(sig, fn, P, 2, mesh, lanes=True, donate_argnums=(1,))
 
 
 def engine_put_program(P: int, cfg: PipelineConfig, plan_static, arrs,
@@ -851,18 +616,24 @@ def engine_put_program(P: int, cfg: PipelineConfig, plan_static, arrs,
     """
     assert not cfg.has_auto
     sig = _signature("engine_put", P, cfg, plan_static, arrs, batch=B,
-                     extra=mesh)
+                     mesh=mesh)
     lane_axis = 0 if mesh is None else 1
 
-    def build():
-        def put(bufs, vals, b):
-            return jax.tree.map(
-                lambda buf, v: jax.lax.dynamic_update_index_in_dim(
-                    buf, v, b, axis=lane_axis), bufs, vals)
-        return jax.jit(_count_traces(put), donate_argnums=(0,))
+    def put(bufs, vals, b):
+        return jax.tree.map(
+            lambda buf, v: jax.lax.dynamic_update_index_in_dim(
+                buf, v, b, axis=lane_axis), bufs, vals)
 
-    return _PROGRAMS.get(sig, build)
+    return cached_jit(sig, put, donate_argnums=(0,))
 
+
+# ------------------------------------------- batched multi-graph pipeline --
+#
+# The graph lanes one device holds are a vmap named ``LANE_AXIS``, and the
+# lane programs carry it in ``lane_axes`` (with a 2D mesh's batch axis):
+# their control values (trip counts, exchange gates, the recolor chunk
+# width) are then lane-uniform, so each ``cond``/``switch`` stays one branch
+# taken instead of vmap's run-every-branch + select.
 
 def _keys_many(cfg: PipelineConfig, n, color_keys, recolor_keys):
     """Per-graph key lists: defaults fold the graph's input position into
@@ -943,7 +714,7 @@ def _pad_batch_lanes(st, order_b, cks_b, rks_b, B, target):
 
 def _bucket_inputs(bucket, cfg, orders, marked, cks, rks, pad_batch,
                    lane_multiple: int = 1):
-    """Per-bucket dispatch inputs, shared by the sim and sharded drivers."""
+    """Per-bucket dispatch inputs of ``color_many``."""
     st = bucket.stacked_arrays(sparse=cfg.needs_sparse_plan)
     order_b = _bucket_order(bucket, cfg, orders, marked)
     cks_b = [cks[i] for i in bucket.indices]
@@ -972,79 +743,62 @@ def _unpack_bucket(out, bucket, bi, pgs, results):
     return results
 
 
-def color_many(pgs, cfg: PipelineConfig, *, orders=None, marked=None,
-               color_keys=None, recolor_keys=None, buckets=None,
+def color_many(pgs, cfg: PipelineConfig, mesh=None, *, orders=None,
+               marked=None, color_keys=None, recolor_keys=None, buckets=None,
                pad_batch: bool = False):
     """Color a batch of partitioned graphs through one fused program each
-    bucket (sim executor) — the batched service's dispatch core.
+    bucket — the batched service's dispatch core.
 
     ``pgs`` — same-``P`` ``PartitionedGraph`` list (``halo`` per
-    ``cfg``'s distance).  ``orders`` — an ``ordering`` kind string (default
-    ``internal_first``) or per-graph ``(P, n_local_max)`` arrays.
-    ``marked`` — per-graph partial-coloring masks (``cfg.color.partial``).
-    ``color_keys``/``recolor_keys`` — per-graph JAX keys; the default folds
-    each graph's input position into the config seeds.  ``buckets`` — a
-    precomputed ``bucket_graphs(pgs)`` result (a server that already
-    bucketed its queue passes it to skip the host-side re-pad).
-    ``pad_batch=True`` rounds every bucket's batch axis up to a power of
-    two with dropped dummy lanes, so batch-program shapes stay stable as
-    queue depth fluctuates (jit-cache friendly serving).
+    ``cfg``'s distance).  ``mesh=None`` runs on one device: a vmap over
+    graphs of a vmap over shards.  On a 1D mesh the graph batch axis rides
+    *inside* each shard (vmap under shard_map); on a 2D ``batch × shard``
+    mesh (``make_coloring_mesh(P, batch=Bm)``) the lanes are additionally
+    sharded over the batch axis — each device vmaps B/Bm lanes, and lane
+    counts are padded to a multiple of Bm.  ``orders`` — an ``ordering``
+    kind string (default ``internal_first``) or per-graph ``(P,
+    n_local_max)`` arrays.  ``marked`` — per-graph partial-coloring masks
+    (``cfg.color.partial``).  ``color_keys``/``recolor_keys`` — per-graph
+    JAX keys; the default folds each graph's input position into the
+    config seeds.  ``buckets`` — a precomputed ``bucket_graphs(pgs)``
+    result (a server that already bucketed its queue passes it to skip the
+    host-side re-pad).  ``pad_batch=True`` rounds every bucket's batch axis
+    up to a power of two with dropped dummy lanes, so batch-program shapes
+    stay stable as queue depth fluctuates (jit-cache friendly serving).
 
     Returns one dict per input graph (input order): ``view`` ``(P,
     n_slots)`` padded device view, ``colors`` ``(n_global,)`` 1-based,
     ``color`` initial-coloring stats, ``history``/``n_iters_run`` as
-    ``pipeline_sim``, and the ``bucket`` index.  Each graph's view and
-    history are bitwise a solo ``pipeline_sim`` run on its padded member
-    (``bucket.members[j]``) with the same keys.
+    ``run_pipeline``, and the ``bucket`` index.  Each graph's view and
+    history are bitwise a solo ``run_pipeline`` run on its padded member
+    (``bucket.members[j]``) with the same keys, on any mesh.
     """
     assert cfg.color is not None, "color_many needs cfg.color"
     pgs = list(pgs)
     if buckets is None:
         buckets = bucket_graphs(pgs)
     cks, rks = _keys_many(cfg, len(pgs), color_keys, recolor_keys)
+    lane_multiple = batch_axis_size(mesh) if mesh is not None else 1
     results = [None] * len(pgs)
     for bi, bucket in enumerate(buckets):
-        bcfg = _resolve_bucket_cfg(bucket, cfg)
+        bcfg = resolve_pipeline_cfg(bucket, cfg)
         st, order_b, cks_b, rks_b, ps = _bucket_inputs(
-            bucket, bcfg, orders, marked, cks, rks, pad_batch)
-        sig = _signature("many_sim", bucket.P, bcfg, ps, st,
-                         batch=len(cks_b))
-        out = _many_sim_program(sig, bucket.P, bcfg, ps)(
-            {k: jnp.asarray(v) for k, v in st.items()},
-            jnp.asarray(order_b), jnp.stack(cks_b), jnp.stack(rks_b))
-        _unpack_bucket(out, bucket, bi, pgs, results)
-    return results
-
-
-def color_many_sharded(pgs, cfg: PipelineConfig, mesh, *, orders=None,
-                       marked=None, color_keys=None, recolor_keys=None,
-                       buckets=None, pad_batch: bool = False):
-    """``color_many`` on a real mesh: collectives run over the mesh's
-    shard axis (``shard_axis_of``).  On a 1D mesh the graph batch axis
-    rides *inside* each shard (vmap under shard_map); on a 2D ``batch ×
-    shard`` mesh (``make_coloring_mesh(P, batch=Bm)``) the lanes are
-    additionally sharded over the batch axis — each device vmaps B/Bm
-    lanes, and lane counts are padded to a multiple of Bm.  Either way
-    every per-graph result is bitwise the sim executor's."""
-    assert cfg.color is not None, "color_many_sharded needs cfg.color"
-    pgs = list(pgs)
-    if buckets is None:
-        buckets = bucket_graphs(pgs)
-    cks, rks = _keys_many(cfg, len(pgs), color_keys, recolor_keys)
-    results = [None] * len(pgs)
-    for bi, bucket in enumerate(buckets):
-        bcfg = _resolve_bucket_cfg(bucket, cfg)
-        st, order_b, cks_b, rks_b, ps = _bucket_inputs(
-            bucket, bcfg, orders, marked, cks, rks, pad_batch,
-            lane_multiple=batch_axis_size(mesh))
-        # leading axis P for shard_map; per-shard arrays carry (B, ...)
-        arrs = {k: jnp.moveaxis(jnp.asarray(v), 0, 1) for k, v in st.items()}
-        order_b = jnp.moveaxis(jnp.asarray(order_b), 0, 1)
-        sig = _signature("many_sharded", bucket.P, bcfg, ps, arrs,
-                         batch=len(cks_b), extra=mesh)
-        out = _many_sharded_program(sig, bucket.P, bcfg, ps, mesh)(
-            arrs, order_b, jnp.stack(cks_b), jnp.stack(rks_b))
-        # outputs carry (P, B, ...): put the graph axis back in front
-        out = jax.tree.map(lambda x: np.moveaxis(np.asarray(x), 0, 1), out)
+            bucket, bcfg, orders, marked, cks, rks, pad_batch, lane_multiple)
+        arrs = {k: jnp.asarray(v) for k, v in st.items()}
+        order_b = jnp.asarray(order_b)
+        if mesh is not None:
+            # leading axis P for shard_map; per-shard arrays carry (B, ...)
+            arrs = {k: jnp.moveaxis(v, 0, 1) for k, v in arrs.items()}
+            order_b = jnp.moveaxis(order_b, 0, 1)
+        sig = _signature("many", bucket.P, bcfg, ps, arrs, batch=len(cks_b),
+                         mesh=mesh)
+        fn = partial(color_then_recolor, cfg=bcfg, P_size=bucket.P,
+                     plan_static=ps)
+        out = dispatch(sig, fn, bucket.P, mesh, (arrs, order_b),
+                       (jnp.stack(cks_b), jnp.stack(rks_b)), lanes=True)
+        if mesh is not None:
+            # outputs carry (P, B, ...): put the graph axis back in front
+            out = jax.tree.map(lambda x: np.moveaxis(np.asarray(x), 0, 1),
+                               out)
         _unpack_bucket(out, bucket, bi, pgs, results)
     return results

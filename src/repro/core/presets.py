@@ -43,7 +43,7 @@ def quality(x: int = 10, max_colors: int = 1024, superstep: int = 512,
 
 def pipeline_config(preset: Preset, *, n_iters: int | None = None,
                     patience: int = 0, seed: int = 0):
-    """A preset as one fused-pipeline config (``pipeline_sim``-ready).
+    """A preset as one fused-pipeline config (``run_pipeline``-ready).
 
     ``n_iters`` overrides the preset's recoloring budget (``patience`` adds
     the adaptive stop on top); the RNG streams match ``run_preset``'s, so
@@ -68,15 +68,15 @@ def run_preset(pg, preset: Preset, seed: int = 0):
     stats, then one ``stage="recolor"`` entry per executed iteration.
     """
     from . import ordering as ord_mod
-    from .pipeline import pipeline_sim
-    from .speculative import color_graph_sim
+    from .pipeline import run_pipeline
+    from .speculative import color_graph
 
     order = ord_mod.compute_order(pg, preset.ordering)
     if not preset.recolor_iters:
         cfg = dataclasses.replace(preset.color_cfg, seed=seed)
-        view, stats = color_graph_sim(pg, order, cfg)
+        view, stats = color_graph(pg, order, cfg)
         return view, [dict(stage="initial", **stats)]
-    view, res = pipeline_sim(pg, order, pipeline_config(preset, seed=seed))
+    view, res = run_pipeline(pg, order, pipeline_config(preset, seed=seed))
     log = [dict(stage="initial", **res["color"])]
     log += [dict(stage="recolor", **h) for h in res["history"]]
     return view, log

@@ -33,7 +33,7 @@ the two-hop ELL (rows × ``maxd2`` over chunks and shards; 0 at distance 1).
 
 Color-class permutations (§3): RV (reverse), NI (non-increasing class size),
 ND (non-decreasing — the paper's best), RAND (Knuth shuffle), and the hybrid
-schedules ND-RAND%x / ND-RAND%2^i handled by `recolor_iterations`.
+schedules ND-RAND%x / ND-RAND%2^i (``schedule_for_iteration``).
 
 Piggybacking (§3.1) becomes *exchange-step coalescing* on TPU: a ghost color
 assigned at step s is only needed by a local reader at step t>s, so the
@@ -52,9 +52,9 @@ color class and reruns the speculative framework (conflicts possible).
 
 Multi-iteration runs live in ``pipeline.py`` (DESIGN.md §7): the fused
 ``color_then_recolor`` keeps seed coloring + K iterations device-resident in
-one ``lax.while_loop``; ``recolor_iterations`` below is a thin wrapper over
-its recolor-only loop, with the host loop kept behind ``fused=False`` as the
-bitwise reference.
+one ``lax.while_loop``; ``pipeline.recolor_iterations`` is a thin wrapper
+over its recolor-only loop, with the host loop of ``recolor_step`` calls
+kept behind ``fused=False`` as the bitwise reference.
 
 Distance-2 mode (``RecolorConfig(distance=2)``, DESIGN.md §5): a class of a
 valid D2 coloring is a distance-2 independent set, so the step stays
@@ -68,7 +68,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from functools import lru_cache, partial
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -76,13 +76,12 @@ import jax.numpy as jnp
 from repro import tracing
 from repro.kernels import ops
 
-from .comm import (AUTO, AXIS, DEFAULT_SCHEME, SCHEME_CHOICES, SCHEMES,
-                   SPARSE, AxisComm, CommConfig, exchange_boundary,
-                   make_exchange, run_sharded, run_sim, shard_axis_of,
+from .comm import (AUTO, AXIS, DEFAULT_SCHEME, SCHEME_CHOICES, SPARSE,
+                   AxisComm, CommConfig, make_exchange, resolve_cfg,
                    shard_uniform, stats_to_host)
 from .graph import PartitionedGraph
-from .speculative import (ColorConfig, _compact_order, _plan_static,
-                          color_spmd, gather_widths, resolve_cfg,
+from .programs import device_arrays, dispatch, signature
+from .speculative import (ColorConfig, color_spmd, gather_widths,
                           validate_color_bounds)
 
 RV = "rv"
@@ -94,9 +93,9 @@ ALL_PERMS = (RV, NI, ND, RAND)
 # (``pipeline.py`` resolves the per-iteration kind with ``lax.switch``).
 PERM_IDS = {kind: i for i, kind in enumerate(ALL_PERMS)}
 
-# Driver-level call counter: manual back-to-back ``recolor_sim`` calls that
-# fall back to the config seed must not replay the identical RAND permutation
-# (ISSUE 4).  Callers that need reproducible keys pass ``key=`` explicitly.
+# Driver-level call counter: manual back-to-back ``recolor_step`` calls that
+# fall back to the config seed must not replay the identical RAND
+# permutation.  Callers that need reproducible keys pass ``key=`` explicitly.
 _DEFAULT_KEY_CALLS = itertools.count()
 
 
@@ -110,11 +109,11 @@ class RecolorConfig:
 
     Units: ``max_colors`` bounds the *seed* coloring's ids (32-aligned);
     ``chunk`` is vertices selected per ELL tile (clamped to the shard's
-    row count at trace time).  Drivers: ``recolor_sim`` /
-    ``recolor_sharded`` run one iteration (sim vs ``workers`` mesh,
-    bitwise identical); ``arc_sim`` is the asynchronous variant;
-    ``recolor_iterations`` / ``pipeline.PipelineConfig`` run schedules of
-    iterations device-resident.
+    row count at trace time).  Drivers: ``recolor_step`` runs one
+    iteration (one device or a mesh, bitwise identical); ``arc_sim`` is
+    the asynchronous variant; ``pipeline.recolor_iterations`` /
+    ``pipeline.PipelineConfig`` run schedules of iterations
+    device-resident.
     """
 
     max_colors: int = 1024         # bound on colors of the SEED coloring
@@ -352,7 +351,7 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
     chunk = min(cfg.chunk, n_local_max)
     if cfg.scheme == AUTO:
         raise ValueError("scheme='auto' must be resolved by a driver "
-                         "(resolve_cfg / resolve_scheme) before the SPMD fn")
+                         "(comm.resolve_cfg) before the SPMD fn")
     sparse = cfg.scheme == SPARSE
     if sparse and (P_size is None or plan_static is None):
         raise ValueError("sparse scheme needs P_size and plan_static "
@@ -511,7 +510,7 @@ def recolor_pass_spmd(arrs, view, rank, n_classes, cfg: RecolorConfig,
 @tracing.scope(tracing.RECOLOR)
 def recolor_spmd(arrs, view, key, perm_kind: str, cfg: RecolorConfig,
                  P_size: int | None = None, plan_static=None,
-                 axis: str = AXIS):
+                 axis: str = AXIS, lane_axes: tuple = ()):
     """One synchronous recoloring iteration (per-shard SPMD).
 
     `view` is a valid coloring (n_slots,) with fresh ghosts. Returns the new
@@ -521,7 +520,7 @@ def recolor_spmd(arrs, view, key, perm_kind: str, cfg: RecolorConfig,
     instead of recomputing it (bitwise the same array) — here the stand-alone
     call computes both ends itself.
     """
-    comm = AxisComm(axis)
+    comm = AxisComm(axis, lane_axes)
     n_local_max = arrs["indptr"].shape[0] - 1
     sizes, n_oor = class_sizes(view, arrs["n_local"], n_local_max,
                                cfg.max_colors, comm)
@@ -529,7 +528,7 @@ def recolor_spmd(arrs, view, key, perm_kind: str, cfg: RecolorConfig,
     rank = permutation_rank(sizes, perm_kind, key)
     new_view, stats = recolor_pass_spmd(arrs, view, rank, n_classes, cfg,
                                         P_size=P_size, plan_static=plan_static,
-                                        axis=axis)
+                                        axis=axis, lane_axes=lane_axes)
     sizes_after, _ = class_sizes(new_view, arrs["n_local"], n_local_max,
                                  cfg.max_colors, comm)
     # distinct classes actually in use — the paper's quality metric (the max
@@ -553,9 +552,9 @@ def arc_order_spmd(view, n_local, n_local_max, rank):
 
 def arc_spmd(arrs, view, key, perm_kind: str, rc_cfg: RecolorConfig,
              sp_cfg: ColorConfig, P_size: int | None = None,
-             plan_static=None, axis: str = AXIS):
+             plan_static=None, axis: str = AXIS, lane_axes: tuple = ()):
     """One asynchronous recoloring iteration: local class order + speculative."""
-    comm = AxisComm(axis)
+    comm = AxisComm(axis, lane_axes)
     n_local_max = arrs["indptr"].shape[0] - 1
     mc = rc_cfg.max_colors
     sizes, n_oor = class_sizes(view, arrs["n_local"], n_local_max, mc, comm)
@@ -566,24 +565,20 @@ def arc_spmd(arrs, view, key, perm_kind: str, rc_cfg: RecolorConfig,
     rank = permutation_rank(sizes, perm_kind, k_rank)
     order = arc_order_spmd(view, arrs["n_local"], n_local_max, rank)
     new_view, stats = color_spmd(arrs, order, k_repair, sp_cfg, P_size=P_size,
-                                 plan_static=plan_static, axis=axis)
+                                 plan_static=plan_static, axis=axis,
+                                 lane_axes=lane_axes)
     stats["n_out_of_range"] = n_oor
     return new_view, stats
 
 
 # ----------------------------------------------------------------- drivers --
 
-@lru_cache(maxsize=64)
-def _rc_sim_fn(P, perm_kind, cfg, plan_static):
-    fn = partial(recolor_spmd, perm_kind=perm_kind, cfg=cfg, P_size=P,
-                 plan_static=plan_static)
-    return jax.jit(lambda arrs, view, key: run_sim(fn, P, (arrs, view), (key,)))
+def recolor_step(pg: PartitionedGraph, view, perm_kind: str,
+                 cfg: RecolorConfig, key=None, *, mesh=None):
+    """One synchronous RC iteration of ``pg``.
 
-
-def recolor_sim(pg: PartitionedGraph, view, perm_kind: str,
-                cfg: RecolorConfig, key=None):
-    """One synchronous RC iteration, simulated on one device.
-
+    ``mesh=None`` simulates the P shards on one device; a mesh runs them on
+    its shard axis (``shard_axis_of(mesh)``), bitwise the same results.
     ``view`` — ``(P, n_slots)`` valid coloring with fresh ghosts (a driver
     output); ``perm_kind`` — one of ``RV``/``NI``/``ND``/``RAND``; ``key``
     defaults to a per-call-counter fold of ``cfg.seed`` (pass an explicit
@@ -592,24 +587,20 @@ def recolor_sim(pg: PartitionedGraph, view, perm_kind: str,
     ``n_colors_before``, ``n_exchanges`` (executed), ``gather_slots`` (ELL
     slots gathered, all shards), ``gather2_slots`` (two-hop ELL slots
     gathered, all shards; 0 at distance 1), ``n_steps`` (= class count),
-    ``wire_bytes``, ``n_out_of_range``.  ``recolor_sharded`` is
-    the bitwise-identical ``workers``-mesh variant.
+    ``wire_bytes``, ``n_out_of_range``.
     """
     cfg = resolve_cfg(pg, cfg)
-    arrs = {k: jnp.asarray(v) for k, v in
-            pg.arrays(sparse=cfg.scheme == SPARSE).items()}
+    sparse = cfg.scheme == SPARSE
+    arrs = device_arrays(pg, sparse)
+    ps = pg.plan_static if sparse else None
     if key is None:
         key = _default_key(cfg.seed)
-    new_view, stats = _rc_sim_fn(pg.P, perm_kind, cfg, _plan_static(pg, cfg))(
-        arrs, jnp.asarray(view), key)
+    sig = signature(f"recolor_{perm_kind}", pg.P, cfg, ps, arrs, mesh=mesh)
+    fn = partial(recolor_spmd, perm_kind=perm_kind, cfg=cfg, P_size=pg.P,
+                 plan_static=ps)
+    new_view, stats = dispatch(sig, fn, pg.P, mesh,
+                               (arrs, jnp.asarray(view)), (key,))
     return new_view, stats_to_host(stats)
-
-
-@lru_cache(maxsize=64)
-def _arc_sim_fn(P, perm_kind, rc_cfg, sp_cfg, plan_static):
-    fn = partial(arc_spmd, perm_kind=perm_kind, rc_cfg=rc_cfg, sp_cfg=sp_cfg,
-                 P_size=P, plan_static=plan_static)
-    return jax.jit(lambda arrs, view, key: run_sim(fn, P, (arrs, view), (key,)))
 
 
 def arc_sim(pg: PartitionedGraph, view, perm_kind: str, rc_cfg: RecolorConfig,
@@ -617,35 +608,21 @@ def arc_sim(pg: PartitionedGraph, view, perm_kind: str, rc_cfg: RecolorConfig,
     """One asynchronous (aRC) iteration, simulated: order by local class
     rank (``rc_cfg``, ``perm_kind``) and rerun the speculative framework
     (``sp_cfg``) — conflicts possible, hence the repair rounds.  Shapes and
-    stats as ``color_graph_sim``; the key splits into independent rank and
-    repair streams.
+    stats as ``speculative.color_graph``; the key splits into independent
+    rank and repair streams.
     """
     rc_cfg, sp_cfg = resolve_cfg(pg, rc_cfg), resolve_cfg(pg, sp_cfg)
-    arrs = {k: jnp.asarray(v) for k, v in
-            pg.arrays(sparse=sp_cfg.scheme == SPARSE).items()}
+    sparse = sp_cfg.scheme == SPARSE
+    arrs = device_arrays(pg, sparse)
+    ps = pg.plan_static if sparse else None
     if key is None:
         key = _default_key(rc_cfg.seed)
-    new_view, stats = _arc_sim_fn(pg.P, perm_kind, rc_cfg, sp_cfg,
-                                  _plan_static(pg, sp_cfg))(
-        arrs, jnp.asarray(view), key)
-    return new_view, stats_to_host(stats)
-
-
-def recolor_sharded(pg: PartitionedGraph, view, perm_kind: str,
-                    cfg: RecolorConfig, mesh, key=None):
-    """``recolor_sim`` on a real mesh shard axis (``shard_axis_of(mesh)``;
-    same contract, bitwise-identical results)."""
-    cfg = resolve_cfg(pg, cfg)
-    arrs = {k: jnp.asarray(v) for k, v in
-            pg.arrays(sparse=cfg.scheme == SPARSE).items()}
-    if key is None:
-        key = _default_key(cfg.seed)
-    axis = shard_axis_of(mesh)
-    fn = partial(recolor_spmd, perm_kind=perm_kind, cfg=cfg, P_size=pg.P,
-                 plan_static=_plan_static(pg, cfg), axis=axis)
-    new_view, stats = jax.jit(
-        lambda a, v, k: run_sharded(fn, mesh, (a, v), (k,), axis=axis))(
-            arrs, jnp.asarray(view), key)
+    sig = signature(f"arc_{perm_kind}", pg.P, (rc_cfg, sp_cfg), ps, arrs,
+                    stage=sp_cfg)
+    fn = partial(arc_spmd, perm_kind=perm_kind, rc_cfg=rc_cfg, sp_cfg=sp_cfg,
+                 P_size=pg.P, plan_static=ps)
+    new_view, stats = dispatch(sig, fn, pg.P, None,
+                               (arrs, jnp.asarray(view)), (key,))
     return new_view, stats_to_host(stats)
 
 
@@ -657,35 +634,3 @@ def schedule_for_iteration(it: int, base: str = ND, rand_every: int = 0,
     if rand_every and it % rand_every == 0:
         return RAND
     return base
-
-
-def recolor_iterations(pg: PartitionedGraph, view, n_iters: int,
-                       cfg: RecolorConfig, *, base_perm: str = ND,
-                       rand_every: int = 0, rand_pow2: bool = False,
-                       seed: int = 0, collect=None, fused: bool = True):
-    """Run `n_iters` RC iterations with an ND-RAND%x style schedule (sim).
-
-    By default the loop runs *device-resident* through the fused pipeline
-    (``pipeline.recolor_loop_sim``): one jitted program, no per-iteration
-    host round-trip, bitwise-identical views and history to the host loop.
-    ``fused=False`` forces the host loop (one ``recolor_sim`` dispatch per
-    iteration) — kept as the reference the fused path is tested against;
-    ``collect=`` implies it, since per-iteration views must reach the host.
-    """
-    if fused and collect is None and n_iters > 0:
-        from .pipeline import PipelineConfig, recolor_loop_sim
-        pcfg = PipelineConfig(
-            color=None, recolor=cfg, n_iters=n_iters, base_perm=base_perm,
-            rand_every=rand_every, rand_pow2=rand_pow2, seed=seed)
-        view, history, _ = recolor_loop_sim(pg, view, pcfg)
-        return view, history
-    history = []
-    for it in range(1, n_iters + 1):
-        kind = schedule_for_iteration(it, base_perm, rand_every, rand_pow2)
-        key = jax.random.fold_in(jax.random.key(seed), it)
-        view, stats = recolor_sim(pg, view, kind, cfg, key)
-        stats["iteration"], stats["perm"] = it, kind
-        history.append(stats)
-        if collect is not None:
-            collect(view, stats)
-    return view, history

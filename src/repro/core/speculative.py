@@ -81,7 +81,7 @@ unmarked vertices stay at color 0, invisible to every bitset.
 from __future__ import annotations
 
 import dataclasses
-from functools import lru_cache, partial
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -91,10 +91,11 @@ from repro import tracing
 from repro.kernels import ops
 
 from . import selection as sel
-from .comm import (AUTO, AXIS, DEFAULT_SCHEME, SCHEME_CHOICES, SCHEMES,
-                   SPARSE, AxisComm, CommConfig, make_exchange, resolve_scheme,
-                   run_sharded, run_sim, shard_axis_of, stats_to_host)
+from .comm import (AUTO, AXIS, DEFAULT_SCHEME, SCHEME_CHOICES, SPARSE,
+                   AxisComm, CommConfig, make_exchange, resolve_cfg,
+                   stats_to_host)
 from .graph import PartitionedGraph
+from .programs import device_arrays, dispatch, signature
 
 
 def validate_color_bounds(max_colors: int, wire16: bool, backend: str):
@@ -117,10 +118,9 @@ class ColorConfig:
     clamped to the shard's row count at trace time); ``max_colors`` is the
     color-id bound (32-aligned — the bitset word width); ``exchange_every``
     counts supersteps between boundary exchanges; ``max_rounds`` bounds the
-    speculate/repair rounds.  Drivers: ``color_graph_sim`` (one device, P
-    vmap lanes) and ``color_graph_sharded`` (real ``workers`` mesh axis)
-    run the identical program; ``color_spmd`` is the raw per-shard SPMD
-    function both wrap.
+    speculate/repair rounds.  Driver: ``color_graph`` (one device with P
+    vmap lanes, or a mesh's shard axis — the identical program);
+    ``color_spmd`` is the raw per-shard SPMD function it wraps.
     """
 
     max_colors: int = 1024
@@ -457,7 +457,7 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
     p_idx = comm.index()
     if cfg.scheme == AUTO:
         raise ValueError("scheme='auto' must be resolved by a driver "
-                         "(resolve_cfg / resolve_scheme) before the SPMD fn")
+                         "(comm.resolve_cfg) before the SPMD fn")
     if cfg.scheme == SPARSE and (P_size is None or plan_static is None):
         raise ValueError("sparse scheme needs P_size and plan_static "
                          "(see PartitionedGraph.comm_plan)")
@@ -633,30 +633,6 @@ def color_spmd(arrs, order, key, cfg: ColorConfig, P_size: int | None = None,
     return view, stats
 
 
-@lru_cache(maxsize=64)
-def _sim_fn(P, cfg, plan_static):
-    fn = partial(color_spmd, cfg=cfg, P_size=P, plan_static=plan_static)
-    return jax.jit(lambda arrs, order, key: run_sim(fn, P, (arrs, order), (key,)))
-
-
-def _plan_static(pg: PartitionedGraph, cfg) -> tuple | None:
-    return pg.comm_plan.static if cfg.scheme == SPARSE else None
-
-
-def resolve_cfg(pg: PartitionedGraph, cfg):
-    """Concretize ``scheme="auto"`` against this partition's comm plan.
-
-    Works on any frozen config dataclass with a ``scheme`` field
-    (ColorConfig / RecolorConfig / PipelineConfig).  The decision is made
-    from modeled bytes at trace time (``comm.resolve_scheme``); an explicit
-    "sparse"/"allgather" passes through untouched, so the flag stays a
-    user override.
-    """
-    if cfg.scheme == AUTO:
-        cfg = dataclasses.replace(cfg, scheme=resolve_scheme(AUTO, pg))
-    return cfg
-
-
 def _apply_partial(order, cfg: ColorConfig, marked):
     """Mask the visit order down to the marked subset (``cfg.partial``).
 
@@ -676,12 +652,14 @@ def _apply_partial(order, cfg: ColorConfig, marked):
     return np.where((order >= 0) & keep, order, -1)
 
 
-def color_graph_sim(pg: PartitionedGraph, order, cfg: ColorConfig,
-                    key=None, *, marked=None):
-    """Run distributed coloring *simulated* on one device (P vmap lanes).
+def color_graph(pg: PartitionedGraph, order, cfg: ColorConfig, key=None, *,
+                mesh=None, marked=None):
+    """Run distributed speculative coloring of ``pg``.
 
-    ``order`` — ``(P, n_local_max)`` int32 visit order of local slots, -1 =
-    skip (``compute_order``); ``key`` — JAX key (default
+    ``mesh=None`` simulates the P shards on one device (P vmap lanes); a
+    mesh runs them on its shard axis (``shard_axis_of(mesh)``), bitwise the
+    same results.  ``order`` — ``(P, n_local_max)`` int32 visit order of
+    local slots, -1 = skip (``compute_order``); ``key`` — JAX key (default
     ``key(cfg.seed)``); ``marked`` — ``(P, n_local_max)`` bool host mask,
     only with ``cfg.partial``.  Returns ``(view, stats)``: ``view`` is the
     ``(P, n_slots)`` int32 device view (colors are 1-based; ghosts +
@@ -691,35 +669,16 @@ def color_graph_sim(pg: PartitionedGraph, order, cfg: ColorConfig,
     ``n_exchanges``, ``wire_bytes`` (measured, per-shard max),
     ``gather_slots`` (ELL slots gathered by the tiles and the detection
     chunks, all shards), ``gather2_slots`` (slots read through the two-hop
-    ELL, all shards; 0 at distance 1).  ``color_graph_sharded`` is the bitwise-identical mesh variant.
+    ELL, all shards; 0 at distance 1).
     """
     cfg = resolve_cfg(pg, cfg)
-    arrs = {k: jnp.asarray(v) for k, v in
-            pg.arrays(sparse=cfg.scheme == SPARSE).items()}
+    sparse = cfg.scheme == SPARSE
+    arrs = device_arrays(pg, sparse)
+    ps = pg.plan_static if sparse else None
     if key is None:
         key = jax.random.key(cfg.seed)
-    order = _apply_partial(order, cfg, marked)
-    view, stats = _sim_fn(pg.P, cfg, _plan_static(pg, cfg))(
-        arrs, jnp.asarray(order), key)
-    return view, stats_to_host(stats)
-
-
-def color_graph_sharded(pg: PartitionedGraph, order, cfg: ColorConfig, mesh,
-                        key=None, *, marked=None):
-    """Run distributed coloring on a real mesh shard axis
-    (``shard_axis_of(mesh)``, ``"workers"`` on the standard meshes) via
-    shard_map; same contract and bitwise the same results as
-    ``color_graph_sim``."""
-    cfg = resolve_cfg(pg, cfg)
-    arrs = {k: jnp.asarray(v) for k, v in
-            pg.arrays(sparse=cfg.scheme == SPARSE).items()}
-    if key is None:
-        key = jax.random.key(cfg.seed)
-    order = _apply_partial(order, cfg, marked)
-    axis = shard_axis_of(mesh)
-    fn = partial(color_spmd, cfg=cfg, P_size=pg.P,
-                 plan_static=_plan_static(pg, cfg), axis=axis)
-    view, stats = jax.jit(
-        lambda a, o, k: run_sharded(fn, mesh, (a, o), (k,), axis=axis))(
-            arrs, jnp.asarray(order), key)
+    order = jnp.asarray(_apply_partial(order, cfg, marked))
+    sig = signature("color", pg.P, cfg, ps, arrs, mesh=mesh)
+    fn = partial(color_spmd, cfg=cfg, P_size=pg.P, plan_static=ps)
+    view, stats = dispatch(sig, fn, pg.P, mesh, (arrs, order), (key,))
     return view, stats_to_host(stats)

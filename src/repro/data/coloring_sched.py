@@ -14,8 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core import (ColorConfig, color_graph_sim, colors_from_views,
-                        compute_order, ordering, partition_graph, presets)
+from repro.core import colors_from_views, partition_graph, presets
 from repro.core.graph import Graph
 
 

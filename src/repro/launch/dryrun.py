@@ -20,6 +20,8 @@ mesh flattened to a 1-axis worker mesh — the production coloring config.
 Usage:
   python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k
   python -m repro.launch.dryrun --all [--multi-pod] [--coloring]
+  python -m repro.launch.dryrun --coloring   # the coloring lowerings only;
+                                             # exits non-zero if one fails
 """
 import argparse
 import json
@@ -122,7 +124,7 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
 
     from repro.core import (ColorConfig, RecolorConfig, color_spmd,
                             partition_graph, rmat)
-    from repro.core.comm import run_sharded
+    from repro.core.comm import run_spmd
     from repro.core.recolor import recolor_spmd
     from functools import partial
 
@@ -146,7 +148,7 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
         cfg = ColorConfig(max_colors=256, superstep=64, scheme="allgather")
         fn = partial(color_spmd, cfg=cfg, P_size=P)
         lowered = jax.jit(
-            lambda a, o, k: run_sharded(fn, mesh, (a, o), (k,))).lower(
+            lambda a, o, k: run_spmd(fn, mesh, P, (a, o), (k,))).lower(
                 arrs, order, key)
         compiled = lowered.compile()
         hlo = compiled.as_text()
@@ -157,7 +159,7 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
                       P_size=P)
         view = jnp.zeros((P, pg.n_slots), jnp.int32)
         lowered_rc = jax.jit(
-            lambda a, v, k: run_sharded(rfn, mesh, (a, v), (k,))).lower(
+            lambda a, v, k: run_spmd(rfn, mesh, P, (a, v), (k,))).lower(
                 arrs, view, key)
         compiled_rc = lowered_rc.compile()
         analysis_rc = analyze_hlo(compiled_rc.as_text())
@@ -166,7 +168,7 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
                         cfg=RecolorConfig(max_colors=256, wire16=True,
                                           scheme="allgather"), P_size=P)
         compiled_rc16 = jax.jit(
-            lambda a, v, k: run_sharded(rfn16, mesh, (a, v), (k,))).lower(
+            lambda a, v, k: run_spmd(rfn16, mesh, P, (a, v), (k,))).lower(
                 arrs, view, key).compile()
         analysis_rc16 = analyze_hlo(compiled_rc16.as_text())
         # sparse neighbour-to-neighbour scheme (DESIGN.md §2): modeled bytes
@@ -202,7 +204,7 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
                           cfg=RecolorConfig(max_colors=256, scheme="sparse"),
                           P_size=P, plan_static=plan.static)
             compiled_sp = jax.jit(
-                lambda a, v, k: run_sharded(rfs, mesh, (a, v), (k,))).lower(
+                lambda a, v, k: run_spmd(rfs, mesh, P, (a, v), (k,))).lower(
                     arrs, view, key).compile()
             sparse_rec["recolor_coll_bytes"] = analyze_hlo(
                 compiled_sp.as_text())["coll_bytes"]
@@ -221,8 +223,8 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
         pfn = partial(color_then_recolor, cfg=pcfg, P_size=P)
         t_pipe = time.time()
         compiled_pipe = jax.jit(
-            lambda a, o, ck, rk: run_sharded(pfn, mesh, (a, o),
-                                             (ck, rk))).lower(
+            lambda a, o, ck, rk: run_spmd(pfn, mesh, P, (a, o),
+                                          (ck, rk))).lower(
                 arrs, order, key, key).compile()
         analysis_pipe = analyze_hlo(compiled_pipe.as_text())
         pipeline_rec = dict(
@@ -237,25 +239,18 @@ def dryrun_coloring(*, multi_pod: bool, out_dir: Path,
         # P=256 (uses all 512 host devices); the multi-pod cell keeps
         # batch=1 (512 shards already occupy every device) but still runs
         # the 2D program structure.
-        from repro.core.comm import (LANE_AXIS, batch_axis_of, mesh_axes,
-                                     run_sharded_many, shard_axis_of)
+        from repro.core.comm import mesh_axes
         Bm = 1 if multi_pod else 2
         mesh2d = make_coloring_mesh(P, batch=Bm)
-        axis2 = shard_axis_of(mesh2d)
         B = 2                                     # lanes (a multiple of Bm)
         arrs_b = {k: jnp.repeat(v[:, None], B, axis=1)
                   for k, v in arrs.items()}
         order_b = jnp.repeat(order[:, None], B, axis=1)
         keys_b = jax.random.split(key, B)
-        pfn2 = jax.vmap(partial(color_then_recolor, cfg=pcfg, P_size=P,
-                                axis=axis2,
-                                lane_axes=(batch_axis_of(mesh2d),
-                                           LANE_AXIS)),
-                        axis_name=LANE_AXIS)
         t_2d = time.time()
         compiled_2d = jax.jit(
-            lambda a, o, k1, k2: run_sharded_many(
-                pfn2, mesh2d, (a, o), (k1, k2), axis=axis2)).lower(
+            lambda a, o, k1, k2: run_spmd(pfn, mesh2d, P, (a, o), (k1, k2),
+                                          lanes=True)).lower(
                     arrs_b, order_b, keys_b, keys_b).compile()
         analysis_2d = analyze_hlo(compiled_2d.as_text())
         mesh2d_rec = dict(
@@ -308,10 +303,14 @@ def main():
     meshes = [False, True] if args.both_meshes else [args.multi_pod]
 
     if args.coloring:
+        failed = 0
         for mp in meshes:
             rec = dryrun_coloring(multi_pod=mp, out_dir=out_dir,
                                   force=args.force)
             print(json.dumps(rec)[:240])
+            failed += rec["status"] != "ok"
+        if failed:
+            raise SystemExit(f"{failed} coloring lowering(s) failed")
         if not (args.all or args.arch):
             return
 

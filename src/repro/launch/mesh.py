@@ -90,7 +90,7 @@ def make_coloring_mesh(n_workers: int | None = None, batch: int = 1):
     """2D ``(batch, workers)`` coloring mesh; needs batch × workers devices.
 
     ``batch`` shards the batched pipeline's graph-lane axis
-    (``color_many_sharded``); solo dispatches replicate over it.
+    (``core.color_many``); solo dispatches replicate over it.
     """
     n = n_workers or len(jax.devices()) // batch
     return MeshSpec.coloring(n, batch).build()
@@ -105,7 +105,7 @@ def engine_lanes(mesh, lanes: int) -> int:
     """Lane count a continuous-batching engine on ``mesh`` must allocate.
 
     The engine's lane axis is sharded over the mesh's ``batch`` axis
-    (``run_sharded_many``), so the configured ``ServeConfig.lanes`` is
+    (``core.comm.run_spmd``), so the configured ``ServeConfig.lanes`` is
     rounded up to a multiple of the batch axis size; ``mesh=None`` (sim
     executor) and 1D meshes keep it as-is.
     """

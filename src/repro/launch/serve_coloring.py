@@ -19,7 +19,7 @@ Two scheduling modes (``ServeConfig.mode``, DESIGN.md §11):
   ``submit`` returns a request id whose ``JobFuture`` resolves
   asynchronously; **admission control** under a latency SLO decides
   solo-dispatch (program-cache hit) vs lane admission vs shed/defer per
-  request.  Every lane is bitwise-equal to a solo ``pipeline_sim`` run of
+  request.  Every lane is bitwise-equal to a solo ``run_pipeline`` run of
   the same engine-padded member under arbitrary admission interleavings
   (the chunked step applies the while loop's self-freezing body, see
   ``core.pipeline_step_spmd``).
@@ -27,8 +27,7 @@ Two scheduling modes (``ServeConfig.mode``, DESIGN.md §11):
   hit → immediate solo dispatch, miss → grouped batch compile
   (``core.color_many``).  A straggler graph holds its whole bucket
   hostage until the batch program returns — the p99 cliff the continuous
-  mode exists to remove (``benchmarks/bench_serve.py`` measures both
-  against open-loop Poisson arrivals).
+  mode exists to remove.
 
 Request RNG keys fold the *request id* into the config seeds, so a
 request's coloring does not depend on which route, lane or batch position
@@ -54,13 +53,12 @@ import numpy as np
 
 from repro.core import (ColorConfig, Graph, PipelineConfig, RecolorConfig,
                         bucket_graphs, bucket_signature, check_coloring,
-                        color_many, color_many_sharded, compute_order,
-                        engine_init_program, engine_put_program,
-                        engine_step_program, ordering,
-                        pad_partition, partition_graph, pipeline_sharded,
-                        pipeline_sim, plan_fits, plan_signature,
-                        program_cache_contains, program_cache_stats,
-                        remap_plan_arrays, resolve_pipeline_cfg, rmat)
+                        color_many, compute_order, engine_init_program,
+                        engine_put_program, engine_step_program, ordering,
+                        pad_partition, partition_graph, plan_fits,
+                        plan_signature, program_cache_contains,
+                        program_cache_stats, remap_plan_arrays,
+                        resolve_pipeline_cfg, rmat, run_pipeline)
 from repro.compile_cache import use_compile_cache
 from repro.core.pipeline import _history_to_host
 from repro.core.speculative import _apply_partial
@@ -233,7 +231,7 @@ class _Entry:
     pg: object          # PartitionedGraph (original dims)
     bucket: object      # its one-graph GraphBucket (pow2-padded)
     signature: object   # the bucket's PlanSignature (batch-lane grouping)
-    solo_sig: object    # the padded member's pipeline_sim/_sharded signature
+    solo_sig: object    # the padded member's run_pipeline signature
     order: object       # visit order for the padded member (np array)
     exact_sig: object   # the original dims' pipeline signature (hot path)
     exact_order: object  # visit order for the original partition
@@ -270,7 +268,7 @@ class _Engine:
     generation of lane state.
 
     Sim layout stacks lanes on axis 0 (``(B, P, ...)``); on a mesh the
-    lanes ride axis 1 (``(P, B, ...)``, ``run_sharded_many``) and shard
+    lanes ride axis 1 (``(P, B, ...)``, ``comm.run_spmd``) and shard
     over the batch mesh axis.
     """
 
@@ -837,11 +835,9 @@ class ColoringService:
         cks, rks = self._keys([job])
         marked = (self._marked_blocks(tgt, job.marked)
                   if self.cfg.color.partial else None)
-        run = (pipeline_sim if self.mesh is None else
-               lambda *a, **kw: pipeline_sharded(a[0], a[1], a[2], self.mesh,
-                                                 **kw))
-        view, res = run(tgt, order, self.cfg, marked=marked,
-                        color_key=cks[0], recolor_key=rks[0])
+        view, res = run_pipeline(tgt, order, self.cfg, self.mesh,
+                                 marked=marked, color_key=cks[0],
+                                 recolor_key=rks[0])
         view = np.asarray(view)
         return dict(
             colors=e.pg.gather_global_colors(view[:, :e.pg.n_local_max]),
@@ -866,14 +862,11 @@ class ColoringService:
             marked = [self._marked_blocks(pg, j.marked)
                       for pg, j in zip(pgs, jobs)]
         cks, rks = self._keys(jobs)
-        run = (color_many if self.mesh is None
-               else lambda *a, **kw: color_many_sharded(
-                   a[0], a[1], self.mesh, **kw))
         # pad_batch: pow2 batch lanes keep program shapes stable as the
         # queue depth fluctuates, so steady-state flushes stay compiled
-        return run(pgs, self.cfg, orders=self.order_kind, marked=marked,
-                   color_keys=cks, recolor_keys=rks, buckets=buckets,
-                   pad_batch=True)
+        return color_many(pgs, self.cfg, self.mesh, orders=self.order_kind,
+                          marked=marked, color_keys=cks, recolor_keys=rks,
+                          buckets=buckets, pad_batch=True)
 
     def _finish(self, job, r, latency, route, results):
         out = dict(colors=r["colors"],

@@ -36,7 +36,7 @@ taken.  A span's parent is the span open around it on the same thread.
   first use of a partition's sparse plan);
 - ``repro.order`` — ``ordering.compute_order``;
 - ``repro.upload`` — a partition's arrays built and sent to the device
-  (``pipeline._pipeline_arrays``, once per partition);
+  (``programs.device_arrays``, once per partition);
 - ``repro.program`` — the compiled-program lookup and the dispatch of the
   program; a compile lands here;
 - ``repro.wait`` — the host blocked on the device for the run's stats;

@@ -14,11 +14,6 @@ Usage (tests/test_serve_continuous.py, the CI ``serve-stress`` job):
     script = random_script(rng, graphs, n=20, mean_gap=1.5)
     res = run_script(svc, script)
     # res.results / res.shed / res.failed / res.futures
-
-``benchmarks/bench_serve.py``'s open-loop sweep runs the same event loop
-on a hybrid clock (its poll cost is the *measured* wall seconds of each
-scheduler step, making latency percentiles load-dependent while arrivals
-stay scripted).
 """
 from __future__ import annotations
 
@@ -61,11 +56,11 @@ def run_script(svc, arrivals, *, poll_cost: float = 1.0,
 
     Event loop: submit every arrival whose time has come, run one
     ``svc.poll()``, advance the clock by ``poll_cost`` (the scripted cost
-    of a scheduler step — virtual seconds per poll, or measured wall
-    seconds in the benchmark), repeat; when the service is idle, jump the
-    clock straight to the next arrival.  With the default ``poll_cost=1``
-    arrival times are effectively in poll ticks, so scripts express exact
-    interleavings ("request 3 lands two chunks into request 1's run").
+    of a scheduler step, in virtual seconds per poll), repeat; when the
+    service is idle, jump the clock straight to the next arrival.  With
+    the default ``poll_cost=1`` arrival times are effectively in poll
+    ticks, so scripts express exact interleavings ("request 3 lands two
+    chunks into request 1's run").
     """
     clock = svc._clock
     assert isinstance(clock, FakeClock), "inject a FakeClock into the service"

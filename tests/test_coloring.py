@@ -4,9 +4,9 @@ import numpy as np
 import pytest
 
 from repro.core import (ColorConfig, RecolorConfig, arc_sim, assert_valid,
-                        check_coloring, color_graph_sim, colors_from_views,
+                        check_coloring, color_graph, colors_from_views,
                         compute_order, ordering, partition_graph,
-                        recolor_iterations, recolor_sim, rmat, selection)
+                        recolor_iterations, recolor_step, rmat, selection)
 
 GRAPHS = {
     "grid9": lambda: rmat.grid2d(32, 32, 9),
@@ -20,7 +20,7 @@ def color(g, P, *, order_kind=ordering.NATURAL, sel=selection.FIRST_FIT,
     order = compute_order(pg, order_kind)
     cfg = ColorConfig(max_colors=max_colors, superstep=superstep,
                       selection=sel, random_x=x, seed=seed)
-    view, stats = color_graph_sim(pg, order, cfg)
+    view, stats = color_graph(pg, order, cfg)
     return pg, np.asarray(view), stats
 
 
@@ -82,7 +82,7 @@ class TestRecolor:
 
     @pytest.mark.parametrize("perm", ["rv", "ni", "nd", "rand"])
     def test_permutations_valid_and_no_worse(self, perm):
-        new_view, st = recolor_sim(self.pg, self.view, perm, self.rcfg)
+        new_view, st = recolor_step(self.pg, self.view, perm, self.rcfg)
         colors = colors_from_views(self.pg, np.asarray(new_view))
         assert_valid(self.g, colors, what=f"RC-{perm}")
         # Culberson: recoloring never increases the number of colors
@@ -102,19 +102,19 @@ class TestRecolor:
         v1 = np.zeros((1, pg1.n_slots), np.int32)
         v1[0, :pg1.n_local_max] = c_global
         key = jax.random.key(11)
-        v1n, st1 = recolor_sim(pg1, v1, "nd", self.rcfg, key=key)
-        vPn, stP = recolor_sim(self.pg, self.view, "nd", self.rcfg, key=key)
+        v1n, st1 = recolor_step(pg1, v1, "nd", self.rcfg, key=key)
+        vPn, stP = recolor_step(self.pg, self.view, "nd", self.rcfg, key=key)
         assert (colors_from_views(pg1, np.asarray(v1n))
                 == colors_from_views(self.pg, np.asarray(vPn))).all()
 
     def test_piggyback_equals_per_step_exchange(self):
         """Coalesced exchanges produce the identical coloring (§3.1)."""
         key = jax.random.key(3)
-        v_pig, st_pig = recolor_sim(self.pg, self.view, "nd",
-                                    RecolorConfig(max_colors=512,
+        v_pig, st_pig = recolor_step(self.pg, self.view, "nd",
+                                     RecolorConfig(max_colors=512,
                                                   piggyback=True), key=key)
-        v_all, st_all = recolor_sim(self.pg, self.view, "nd",
-                                    RecolorConfig(max_colors=512,
+        v_all, st_all = recolor_step(self.pg, self.view, "nd",
+                                     RecolorConfig(max_colors=512,
                                                   piggyback=False), key=key)
         assert (np.asarray(v_pig) == np.asarray(v_all)).all()
         assert st_pig["n_exchanges"] <= st_all["n_exchanges"]
@@ -132,5 +132,5 @@ def test_exchange_staleness_still_valid():
     pg = partition_graph(g, 8)
     order = compute_order(pg, ordering.NATURAL)
     cfg = ColorConfig(max_colors=512, superstep=64, exchange_every=4)
-    view, stats = color_graph_sim(pg, order, cfg)
+    view, stats = color_graph(pg, order, cfg)
     assert_valid(g, colors_from_views(pg, np.asarray(view)))

@@ -9,10 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (ColorConfig, RecolorConfig, assert_valid,
-                        color_graph_sim, colors_from_views, compute_order,
-                        ordering, partition_graph, recolor_sim, rmat,
-                        stats_to_host)
+from repro.core import (ColorConfig, PipelineConfig, RecolorConfig,
+                        assert_valid, bucket_graphs, color_graph,
+                        colors_from_views, compute_order, ordering,
+                        partition_graph, recolor_step, resolve_pipeline_cfg,
+                        resolve_scheme, rmat, stats_to_host)
 from repro.core.graph import Graph
 
 
@@ -50,7 +51,7 @@ def test_sparse_equals_allgather_speculative(P, seed):
     g = rmat.rmat_good(9, 8, seed=seed)
     pg = partition_graph(g, P)
     order = compute_order(pg, ordering.NATURAL)
-    views, stats = _run_both(pg, order, lambda s: color_graph_sim(
+    views, stats = _run_both(pg, order, lambda s: color_graph(
         pg, order, ColorConfig(max_colors=512, superstep=64, seed=0,
                                scheme=s)))
     _assert_views_equal(pg, views["allgather"], views["sparse"])
@@ -68,10 +69,10 @@ def test_sparse_equals_allgather_recolor(P):
     g = rmat.rmat_good(9, 8, seed=5)
     pg = partition_graph(g, P)
     order = compute_order(pg, ordering.NATURAL)
-    seed_view, _ = color_graph_sim(
+    seed_view, _ = color_graph(
         pg, order, ColorConfig(max_colors=512, superstep=64, seed=0))
     key = jax.random.key(7)
-    views, stats = _run_both(pg, order, lambda s: recolor_sim(
+    views, stats = _run_both(pg, order, lambda s: recolor_step(
         pg, seed_view, "nd", RecolorConfig(max_colors=512, scheme=s),
         key=key))
     _assert_views_equal(pg, views["allgather"], views["sparse"])
@@ -88,12 +89,12 @@ def test_sparse_piggyback_equals_per_step():
     g = rmat.rmat_good(9, 8, seed=5)
     pg = partition_graph(g, 4)
     order = compute_order(pg, ordering.NATURAL)
-    seed_view, _ = color_graph_sim(
+    seed_view, _ = color_graph(
         pg, order, ColorConfig(max_colors=512, superstep=64, seed=0))
     key = jax.random.key(3)
-    v_pig, st_pig = recolor_sim(pg, seed_view, "nd", RecolorConfig(
+    v_pig, st_pig = recolor_step(pg, seed_view, "nd", RecolorConfig(
         max_colors=512, piggyback=True, scheme="sparse"), key=key)
-    v_all, st_all = recolor_sim(pg, seed_view, "nd", RecolorConfig(
+    v_all, st_all = recolor_step(pg, seed_view, "nd", RecolorConfig(
         max_colors=512, piggyback=False, scheme="sparse"), key=key)
     _assert_views_equal(pg, v_pig, v_all)
     assert st_pig["wire_bytes"] < st_all["wire_bytes"]
@@ -125,14 +126,14 @@ def test_zero_cross_edges_zero_sparse_bytes():
     plan = pg.comm_plan
     assert plan.shifts == () and plan.bytes_per_exchange() == 0
     order = compute_order(pg, ordering.NATURAL)
-    view, st = color_graph_sim(pg, order, ColorConfig(
+    view, st = color_graph(pg, order, ColorConfig(
         max_colors=64, superstep=8, scheme="sparse"))
     assert_valid(g, colors_from_views(pg, np.asarray(view)))
     assert st["wire_bytes"] == 0
     # ... and no exchange events at all: nothing was ever pending
     assert st["n_exchanges"] == 0
     # the broadcast scheme ships (P-1)*max_b bytes per event regardless
-    _, st_ag = color_graph_sim(pg, order, ColorConfig(
+    _, st_ag = color_graph(pg, order, ColorConfig(
         max_colors=64, superstep=8, scheme="allgather"))
     assert st_ag["wire_bytes"] == 0  # elided: no boundary vertex ever colored
 
@@ -141,10 +142,10 @@ def test_zero_cross_edges_zero_recolor_bytes():
     g = _disjoint_cliques(4, 8)
     pg = partition_graph(g, 4)
     order = compute_order(pg, ordering.NATURAL)
-    view, _ = color_graph_sim(pg, order, ColorConfig(max_colors=64,
+    view, _ = color_graph(pg, order, ColorConfig(max_colors=64,
                                                      superstep=8))
-    v2, st = recolor_sim(pg, view, "nd",
-                         RecolorConfig(max_colors=64, scheme="sparse"))
+    v2, st = recolor_step(pg, view, "nd",
+                          RecolorConfig(max_colors=64, scheme="sparse"))
     assert_valid(g, colors_from_views(pg, np.asarray(v2)))
     assert st["wire_bytes"] == 0
 
@@ -206,6 +207,36 @@ def test_wire16_halves_sparse_bytes():
     g = rmat.rmat_good(9, 8, seed=3)
     pg = partition_graph(g, 4)
     order = compute_order(pg, ordering.NATURAL)
-    mk = lambda w: stats_to_host(color_graph_sim(pg, order, ColorConfig(
+    mk = lambda w: stats_to_host(color_graph(pg, order, ColorConfig(
         max_colors=512, superstep=64, scheme="sparse", wire16=w))[1])
     assert mk(True)["wire_bytes"] * 2 == mk(False)["wire_bytes"]
+
+
+# ------------------------------------------------- the scheme decision -----
+
+_DECISION_GRAPHS = {"rmat_good": lambda: rmat.rmat_good(9, 8, seed=3),
+                    "grid3d": lambda: rmat.grid3d(12, 12, 12)}
+
+
+@pytest.mark.parametrize("graph,P,solo,bucket", [
+    ("rmat_good", 4, "allgather", "sparse"),
+    ("rmat_good", 16, "sparse", "sparse"),
+    ("grid3d", 4, "sparse", "sparse"),
+    ("grid3d", 16, "sparse", "sparse"),
+])
+def test_auto_scheme_of_partition_and_bucket(graph, P, solo, bucket):
+    """``scheme="auto"`` resolves to the same scheme for a partition (the
+    solo program) and for its one-graph bucket (``color_many``'s program)
+    as the two rules gave before they became one.  The bucket pads
+    ``max_boundary`` to a power of two, so RMAT at P=4 ties the
+    all-gather's bytes there and goes sparse."""
+    pg = partition_graph(_DECISION_GRAPHS[graph](), P, seed=0)
+    bk = bucket_graphs([pg])[0]
+    assert resolve_scheme("auto", pg) == solo
+    assert resolve_scheme("auto", bk) == bucket
+    cfg = PipelineConfig(
+        color=ColorConfig(max_colors=64, scheme="auto"),
+        recolor=RecolorConfig(max_colors=64, scheme="auto"))
+    for part, want in ((pg, solo), (bk, bucket)):
+        got = resolve_pipeline_cfg(part, cfg)
+        assert (got.color.scheme, got.recolor.scheme) == (want, want)

@@ -22,9 +22,9 @@ import numpy as np
 import pytest
 
 from repro.core import (ColorConfig, PipelineConfig, RecolorConfig,
-                        check_coloring, color_graph_sim, colors_from_views,
+                        check_coloring, color_graph, colors_from_views,
                         compute_order, ordering, partition_graph,
-                        pipeline_sim, recolor_sim, rmat)
+                        run_pipeline, recolor_step, rmat)
 
 GRAPHS = {
     "grid2d": lambda: rmat.grid2d(12, 12, 9),
@@ -58,7 +58,7 @@ def _color_d2(gname, P, scheme="sparse", backend="xla"):
     pg = _pgraph(gname, P)
     order = compute_order(pg, ordering.NATURAL)
     cfg = ColorConfig(scheme=scheme, backend=backend, **CFG)
-    view, stats = color_graph_sim(pg, order, cfg)
+    view, stats = color_graph(pg, order, cfg)
     return np.asarray(view), stats
 
 
@@ -133,7 +133,7 @@ def test_d2_sequential_mode_valid():
     pg = _pgraph("rmat_good", P)
     order = compute_order(pg, ordering.NATURAL)
     cfg = ColorConfig(parallel_chunk=False, **CFG)
-    view, _ = color_graph_sim(pg, order, cfg)
+    view, _ = color_graph(pg, order, cfg)
     st = check_coloring(g, colors_from_views(pg, np.asarray(view)),
                         distance=2)
     assert st["valid"], st
@@ -154,7 +154,7 @@ def test_gather2_slots_count_two_hop_rows(distance):
         recolor=RecolorConfig(max_colors=512, chunk=512, distance=distance,
                               scheme="sparse"),
         n_iters=2)
-    view, res = pipeline_sim(pg, compute_order(pg, ordering.NATURAL), cfg)
+    view, res = run_pipeline(pg, compute_order(pg, ordering.NATURAL), cfg)
     assert check_coloring(g, colors_from_views(pg, np.asarray(view)),
                           distance=distance)["valid"]
     rows = pg.n_local_max
@@ -175,8 +175,8 @@ def test_d1_on_halo2_partition_matches_halo1():
     order1 = compute_order(pg1, ordering.NATURAL)
     order2 = compute_order(pg2, ordering.NATURAL)
     cfg = ColorConfig(max_colors=512, superstep=64, seed=0)
-    v1, _ = color_graph_sim(pg1, order1, cfg)
-    v2, _ = color_graph_sim(pg2, order2, cfg)
+    v1, _ = color_graph(pg1, order1, cfg)
+    v2, _ = color_graph(pg2, order2, cfg)
     np.testing.assert_array_equal(colors_from_views(pg1, np.asarray(v1)),
                                   colors_from_views(pg2, np.asarray(v2)))
 
@@ -192,7 +192,7 @@ class TestD2Recolor:
     def test_permutations_valid_and_no_worse(self, seeded, perm):
         g, pg, view, stats = seeded
         cfg = RecolorConfig(max_colors=512, distance=2)
-        v2, st = recolor_sim(pg, view, perm, cfg, key=jax.random.key(11))
+        v2, st = recolor_step(pg, view, perm, cfg, key=jax.random.key(11))
         colors = colors_from_views(pg, np.asarray(v2))
         chk = check_coloring(g, colors, distance=2)
         assert chk["valid"], chk
@@ -202,9 +202,9 @@ class TestD2Recolor:
         """The D2 dep sources (CSR + two-hop ELL) defer no needed round."""
         g, pg, view, _ = seeded
         key = jax.random.key(3)
-        v_pig, st_pig = recolor_sim(pg, view, "nd", RecolorConfig(
+        v_pig, st_pig = recolor_step(pg, view, "nd", RecolorConfig(
             max_colors=512, distance=2, piggyback=True), key=key)
-        v_all, st_all = recolor_sim(pg, view, "nd", RecolorConfig(
+        v_all, st_all = recolor_step(pg, view, "nd", RecolorConfig(
             max_colors=512, distance=2, piggyback=False), key=key)
         _assert_views_equal(pg, np.asarray(v_pig), np.asarray(v_all))
         assert st_pig["n_exchanges"] <= st_all["n_exchanges"]
@@ -212,9 +212,9 @@ class TestD2Recolor:
     def test_scheme_equivalence(self, seeded):
         g, pg, view, _ = seeded
         key = jax.random.key(5)
-        va, _ = recolor_sim(pg, view, "nd", RecolorConfig(
+        va, _ = recolor_step(pg, view, "nd", RecolorConfig(
             max_colors=512, distance=2, scheme="allgather"), key=key)
-        vs, _ = recolor_sim(pg, view, "nd", RecolorConfig(
+        vs, _ = recolor_step(pg, view, "nd", RecolorConfig(
             max_colors=512, distance=2, scheme="sparse"), key=key)
         _assert_views_equal(pg, np.asarray(va), np.asarray(vs))
 
@@ -237,7 +237,7 @@ class TestPartialD2:
         marked_g, marked = self._marked(g, pg)
         order = compute_order(pg, ordering.NATURAL)
         cfg = ColorConfig(partial=True, **CFG)
-        view, stats = color_graph_sim(pg, order, cfg, marked=marked)
+        view, stats = color_graph(pg, order, cfg, marked=marked)
         colors = colors_from_views(pg, np.asarray(view))
         assert (colors[~marked_g] == 0).all()        # untouched subset
         chk = check_coloring(g, colors, distance=2, marked=marked_g)
@@ -250,7 +250,7 @@ class TestPartialD2:
         pg = _pgraph("grid2d", 2)
         order = compute_order(pg, ordering.NATURAL)
         with pytest.raises(AssertionError):
-            color_graph_sim(pg, order, ColorConfig(partial=True, **CFG))
+            color_graph(pg, order, ColorConfig(partial=True, **CFG))
 
     def test_partial_then_recolor(self):
         """RC on a partial coloring recolors only the marked classes.
@@ -262,11 +262,11 @@ class TestPartialD2:
         pg = _pgraph("grid2d", P)
         marked_g, marked = self._marked(g, pg)
         order = compute_order(pg, ordering.NATURAL)
-        view, _ = color_graph_sim(pg, order,
-                                  ColorConfig(partial=True, **CFG),
-                                  marked=marked)
+        view, _ = color_graph(pg, order,
+                              ColorConfig(partial=True, **CFG),
+                              marked=marked)
         cfg = RecolorConfig(max_colors=512, distance=2)
-        v2, _ = recolor_sim(pg, view, "nd", cfg, key=jax.random.key(2))
+        v2, _ = recolor_step(pg, view, "nd", cfg, key=jax.random.key(2))
         colors = colors_from_views(pg, np.asarray(v2))
         assert (colors[~marked_g] == 0).all()
         chk = check_coloring(g, colors, distance=2, marked=marked_g)
@@ -278,7 +278,7 @@ def test_distance2_requires_halo2():
     pg = partition_graph(g, 2, halo=1)
     order = compute_order(pg, ordering.NATURAL)
     with pytest.raises(ValueError, match="halo=2"):
-        color_graph_sim(pg, order, ColorConfig(**CFG))
+        color_graph(pg, order, ColorConfig(**CFG))
 
 
 def test_validator_negative_sentinel_no_crash():

@@ -21,9 +21,9 @@ import pytest
 
 from repro.analysis.trace_audit import _param_jaxprs
 from repro.core import (ColorConfig, RecolorConfig, assert_valid,
-                        color_graph_sim, colors_from_views, compute_order,
+                        color_graph, colors_from_views, compute_order,
                         ordering, pad_partition, partition_graph,
-                        recolor_sim, rmat, select_colors, selection)
+                        recolor_step, rmat, select_colors, selection)
 from repro.kernels import ops, ref
 
 
@@ -178,7 +178,7 @@ SEED_GOLD = {
 def test_sequential_mode_is_seed_behavior(graph, pgraph, selname):
     """parallel_chunk=False reproduces the pre-rework coloring bitwise."""
     order = compute_order(pgraph, ordering.NATURAL)
-    runs = [color_graph_sim(pgraph, order, ColorConfig(
+    runs = [color_graph(pgraph, order, ColorConfig(
         max_colors=512, superstep=64, selection=selname, random_x=10, seed=0,
         parallel_chunk=False, backend=b)) for b in ("xla", "pallas")]
     (view, st), (view_p, st_p) = runs
@@ -198,10 +198,10 @@ def test_parallel_mode_valid_and_backends_agree(graph, pgraph, selname):
     order = compute_order(pgraph, ordering.NATURAL)
     mk = lambda b: ColorConfig(max_colors=512, superstep=64,
                                selection=selname, seed=0, backend=b)
-    view_x, st_x = color_graph_sim(pgraph, order, mk("xla"))
+    view_x, st_x = color_graph(pgraph, order, mk("xla"))
     assert_valid(graph, colors_from_views(pgraph, np.asarray(view_x)),
                  what=f"parallel-{selname}")
-    view_p, st_p = color_graph_sim(pgraph, order, mk("pallas"))
+    view_p, st_p = color_graph(pgraph, order, mk("pallas"))
     np.testing.assert_array_equal(np.asarray(view_x), np.asarray(view_p))
     assert st_x["n_colors"] == st_p["n_colors"]
 
@@ -233,16 +233,16 @@ SPEC_GOLD = [
 
 
 def _full_width_run(monkeypatch, pg, order, cfg):
-    """``color_graph_sim`` with every tile and chunk gathered at ``maxd``:
+    """``color_graph`` with every tile and chunk gathered at ``maxd``:
     the program before width buckets."""
-    from repro.core import speculative
+    from repro.core import program_cache_clear, speculative
     monkeypatch.setattr(speculative, "gather_widths", lambda maxd: (maxd,))
-    speculative._sim_fn.cache_clear()
+    program_cache_clear()
     try:
-        return color_graph_sim(pg, order, cfg)
+        return color_graph(pg, order, cfg)
     finally:
         monkeypatch.undo()
-        speculative._sim_fn.cache_clear()
+        program_cache_clear()
 
 
 @pytest.mark.parametrize("name,order_kind,selname,superstep,tile,gold,share",
@@ -258,7 +258,7 @@ def test_speculative_bucketed_is_full_width(monkeypatch, name, order_kind,
     mk = lambda b: ColorConfig(max_colors=512, superstep=superstep,
                                tile=tile, selection=selname, seed=0,
                                backend=b)
-    (v, st), (v_p, st_p) = [color_graph_sim(pg, order, mk(b))
+    (v, st), (v_p, st_p) = [color_graph(pg, order, mk(b))
                             for b in ("xla", "pallas")]
     np.testing.assert_array_equal(np.asarray(v), np.asarray(v_p))
     assert st == st_p
@@ -319,7 +319,7 @@ def test_detection_is_free_of_order(pgraph, backend):
 
 def _seed_coloring(pg):
     order = compute_order(pg, ordering.NATURAL)
-    view, _ = color_graph_sim(
+    view, _ = color_graph(
         pg, order, ColorConfig(max_colors=512, superstep=64, seed=0,
                                parallel_chunk=False))
     return view
@@ -386,9 +386,9 @@ def test_recolor_chunked_is_seed_behavior(name, perm, chunk, gold, share):
     """Chunked ELL/bitset recolor == the seed dense-occupancy recolor, and
     width-bucketed chunks gather fewer slots only where degrees are skewed."""
     g, pg, view = _rc_input(name)
-    runs = [recolor_sim(pg, view, perm,
-                        RecolorConfig(max_colors=512, chunk=chunk, backend=b),
-                        key=jax.random.key(11)) for b in ("xla", "pallas")]
+    runs = [recolor_step(pg, view, perm,
+                         RecolorConfig(max_colors=512, chunk=chunk, backend=b),
+                         key=jax.random.key(11)) for b in ("xla", "pallas")]
     (v2, st), (v_p, st_p) = runs
     np.testing.assert_array_equal(np.asarray(v2), np.asarray(v_p))
     assert st == st_p
@@ -438,9 +438,8 @@ def _width_dispatch(jaxpr, widths, chunk, found, stray, inside=False):
 
 
 def _fused_batch_jaxpr(pg, cfg):
-    from repro.core.pipeline import pipeline_sim_program
-    fn, args = pipeline_sim_program(pg, compute_order(pg, ordering.NATURAL),
-                                    cfg)
+    from repro.core.pipeline import pipeline_program
+    fn, args = pipeline_program(pg, compute_order(pg, ordering.NATURAL), cfg)
     return jax.make_jaxpr(fn)(*args)
 
 
@@ -463,15 +462,18 @@ def _many_batch_jaxpr(pg, cfg, B=2):
     """The bucketed batch's program (``color_many``): B graph lanes."""
     import jax.numpy as jnp
 
-    from repro.core.pipeline import _many_sim_program, _signature
+    from repro.core.pipeline import _signature, color_then_recolor
+    from repro.core.programs import spmd_program
     arrs = pg.arrays(sparse=False)
     lanes = lambda x: jax.ShapeDtypeStruct((B,) + np.shape(x),
                                            jnp.asarray(x).dtype)
     arrs_b = {k: lanes(v) for k, v in arrs.items()}
     order = lanes(compute_order(pg, ordering.NATURAL))
     keys = jax.random.split(jax.random.key(0), B)
-    fn = _many_sim_program(_signature("many_sim", pg.P, cfg, None, arrs_b,
-                                      batch=B), pg.P, cfg, None)
+    fn = spmd_program(_signature("many", pg.P, cfg, None, arrs_b, batch=B),
+                      functools.partial(color_then_recolor, cfg=cfg,
+                                        P_size=pg.P, plan_static=None),
+                      pg.P, 2, lanes=True)
     return jax.make_jaxpr(fn)(arrs_b, order, keys, keys)
 
 
@@ -484,8 +486,7 @@ DISPATCH_ROWS = dict(recolor=16, tile=8, detect=32)
 def _dispatch(program, rows):
     """Width dispatches (``cond``s) and stray narrow gathers of ``rows``-row
     tiles in ``program``'s jaxpr, on a skewed graph with >= 3 widths."""
-    from repro.core import PipelineConfig
-    from repro.core.pipeline import program_cache_clear
+    from repro.core import PipelineConfig, program_cache_clear
     from repro.core.speculative import gather_widths
     pg = partition_graph(rmat.rmat_good(8, 8, seed=1), 2)
     widths = gather_widths(pg.maxd)
@@ -529,21 +530,21 @@ def test_speculative_width_dispatch_is_one_cond(program, phase):
 
 def test_recolor_backends_agree(pgraph, seed_view):
     key = jax.random.key(11)
-    v_x, _ = recolor_sim(pgraph, seed_view, "nd",
-                         RecolorConfig(max_colors=512, backend="xla"), key=key)
-    v_p, _ = recolor_sim(pgraph, seed_view, "nd",
-                         RecolorConfig(max_colors=512, backend="pallas"),
-                         key=key)
+    v_x, _ = recolor_step(pgraph, seed_view, "nd",
+                          RecolorConfig(max_colors=512, backend="xla"), key=key)
+    v_p, _ = recolor_step(pgraph, seed_view, "nd",
+                          RecolorConfig(max_colors=512, backend="pallas"),
+                          key=key)
     np.testing.assert_array_equal(np.asarray(v_x), np.asarray(v_p))
 
 
 def test_recolor_odd_chunk_size(graph, pgraph, seed_view):
     """Chunk size must not change the result (class = independent set)."""
     key = jax.random.key(11)
-    v_a, _ = recolor_sim(pgraph, seed_view, "nd",
-                         RecolorConfig(max_colors=512, chunk=256), key=key)
-    v_b, _ = recolor_sim(pgraph, seed_view, "nd",
-                         RecolorConfig(max_colors=512, chunk=19), key=key)
+    v_a, _ = recolor_step(pgraph, seed_view, "nd",
+                          RecolorConfig(max_colors=512, chunk=256), key=key)
+    v_b, _ = recolor_step(pgraph, seed_view, "nd",
+                          RecolorConfig(max_colors=512, chunk=19), key=key)
     np.testing.assert_array_equal(np.asarray(v_a), np.asarray(v_b))
 
 

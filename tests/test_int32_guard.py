@@ -101,3 +101,24 @@ class TestPartitionRunsThePolicy:
         for a, _ in calls:
             assert a[0] == g.n
         assert any(a[1] * a[2] > 1 for a, _ in calls if len(a) >= 3)
+
+
+@pytest.mark.parametrize("scale,P,id_dtype,ell_dtype,total,fits", [
+    (31, 1024, "int64", "int32", 1704984600, True),
+    (33, 4096, "int64", "int32", 1704984600, True),
+    (36, 32768, "int64", "int32", 1704984600, True),
+    (36, 2048, "int64", "int64", 27279753240, False),
+])
+def test_memory_projection_of_the_int64_regime(scale, P, id_dtype,
+                                               ell_dtype, total, fits):
+    """``roofline.coloring_memory_projection`` sizes the int64 regime on
+    paper (DESIGN.md §10): past 2**31 vertices the ids widen and the slot
+    arrays stay int32 until ``n_local_max * maxd`` crosses the bound, and
+    a scale-36 RMAT graph fits a 16 GB v5e shard at P=32768, not at
+    P=2048 (maxd 64)."""
+    from repro.roofline import coloring_memory_projection
+    proj = coloring_memory_projection(2**scale, P, maxd=64)
+    assert (proj["id_dtype"], proj["ell_dtype"]) == (id_dtype, ell_dtype)
+    assert proj["promoted"]
+    assert proj["total_per_shard"] == total
+    assert proj["fits_hbm"] is fits

@@ -5,8 +5,8 @@ contract (stub meshes — no devices needed), the ``PlanSignature.axes``
 cache-key component, and the lane-target padding arithmetic.
 
 Subprocess (8 host devices, ``@slow``): the ISSUE's bitwise pins —
-``color_many_sharded`` on a 2D mesh with batch=1 equals the 1-axis result
-equals ``pipeline_sim``/``color_many``, both exchange schemes, distance 1
+``color_many`` on a 2D mesh with batch=1 equals the 1-axis result
+equals ``run_pipeline``/``color_many``, both exchange schemes, distance 1
 and 2, plus a genuinely-sharded batch case on a ``(2, 2)`` mesh.
 """
 import dataclasses
@@ -122,14 +122,13 @@ class TestLaneTarget:
 
 @pytest.mark.slow
 def test_mesh2d_batch1_bitwise_equals_1axis_and_sim():
-    """The ISSUE's safety pin: 2D mesh (batch=1) == 1-axis == pipeline_sim,
-    both schemes, distance 1 and 2."""
+    """The safety pin: 2D mesh (batch=1) == 1-axis == one device, both
+    schemes, distance 1 and 2."""
     print(run_sub("""
         import numpy as np
         from repro.core import (rmat, partition_graph, compute_order,
                                 ColorConfig, RecolorConfig, PipelineConfig,
-                                color_many, color_many_sharded, pipeline_sim,
-                                pipeline_sharded)
+                                color_many, run_pipeline)
         from repro.launch.mesh import make_coloring_mesh, make_worker_mesh
         P = 4
         mesh1 = make_worker_mesh(P)
@@ -147,8 +146,8 @@ def test_mesh2d_batch1_bitwise_equals_1axis_and_sim():
                                       distance=distance),
                 n_iters=2, patience=1)
             sim = color_many(pgs, cfg, pad_batch=True)
-            one = color_many_sharded(pgs, cfg, mesh1, pad_batch=True)
-            two = color_many_sharded(pgs, cfg, mesh2, pad_batch=True)
+            one = color_many(pgs, cfg, mesh1, pad_batch=True)
+            two = color_many(pgs, cfg, mesh2, pad_batch=True)
             for a, b, c in zip(sim, one, two):
                 assert np.array_equal(a["view"], b["view"])
                 assert np.array_equal(a["view"], c["view"])
@@ -157,8 +156,8 @@ def test_mesh2d_batch1_bitwise_equals_1axis_and_sim():
                 assert a["color"] == b["color"] == c["color"]
             # solo fused pipeline on the 2D mesh == sim
             order = compute_order(pgs[0], "internal_first")
-            v_sim, r_sim = pipeline_sim(pgs[0], order, cfg)
-            v_2d, r_2d = pipeline_sharded(pgs[0], order, cfg, mesh2)
+            v_sim, r_sim = run_pipeline(pgs[0], order, cfg)
+            v_2d, r_2d = run_pipeline(pgs[0], order, cfg, mesh2)
             assert np.array_equal(np.asarray(v_sim), np.asarray(v_2d))
             assert r_sim == r_2d
             print("pin OK:", scheme, "D", distance)
@@ -173,8 +172,7 @@ def test_mesh2d_sharded_batch_on_2x2_mesh():
     print(run_sub("""
         import numpy as np
         from repro.core import (rmat, partition_graph, ColorConfig,
-                                RecolorConfig, PipelineConfig, color_many,
-                                color_many_sharded)
+                                RecolorConfig, PipelineConfig, color_many)
         from repro.launch.mesh import make_coloring_mesh
         P = 2
         mesh = make_coloring_mesh(P, batch=2)
@@ -186,7 +184,7 @@ def test_mesh2d_sharded_batch_on_2x2_mesh():
             recolor=RecolorConfig(max_colors=64, scheme="sparse"),
             n_iters=2, patience=1)
         sim = color_many(pgs, cfg, pad_batch=True)
-        sh = color_many_sharded(pgs, cfg, mesh, pad_batch=True)
+        sh = color_many(pgs, cfg, mesh, pad_batch=True)
         for a, b in zip(sim, sh):
             assert np.array_equal(a["view"], b["view"])
             assert np.array_equal(a["colors"], b["colors"])

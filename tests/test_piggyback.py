@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import (ColorConfig, color_graph_sim, colors_from_views,
+from repro.core import (ColorConfig, color_graph, colors_from_views,
                         compute_order, message_stats, ordering,
                         partition_graph, rmat)
 from repro.core.comm import AxisComm
@@ -16,7 +16,7 @@ def _setup(P=8):
     order = compute_order(pg, ordering.NATURAL)
     # paper-faithful sequential supersteps: the message-count study mirrors
     # the paper's Fig. 4 setup, whose seed coloring is the sequential one
-    view, _ = color_graph_sim(pg, order, ColorConfig(max_colors=64,
+    view, _ = color_graph(pg, order, ColorConfig(max_colors=64,
                                                      superstep=64,
                                                      parallel_chunk=False))
     colors = colors_from_views(pg, np.asarray(view))
@@ -52,7 +52,7 @@ def test_more_processors_more_savings():
     for P in (2, 8):
         pg = partition_graph(g, P)
         order = compute_order(pg, ordering.NATURAL)
-        view, _ = color_graph_sim(pg, order, ColorConfig(max_colors=64,
+        view, _ = color_graph(pg, order, ColorConfig(max_colors=64,
                                                          superstep=64,
                                                          parallel_chunk=False))
         colors = colors_from_views(pg, np.asarray(view))

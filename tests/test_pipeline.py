@@ -3,7 +3,7 @@
 The acceptance matrix of ``pipeline.color_then_recolor``: the fused program
 (initial speculative coloring + K recoloring iterations in one
 ``lax.while_loop``) must be *bitwise identical* — views and every
-per-iteration stat — to the host-looped ``color_graph_sim`` +
+per-iteration stat — to the host-looped ``color_graph`` +
 ``recolor_iterations(fused=False)`` reference sequence, across P, exchange
 schemes, and distance 1|2; the adaptive stop must fire on a plateaued
 schedule.  The satellite regressions pin the corrected distinct-color
@@ -15,11 +15,11 @@ import numpy as np
 import pytest
 
 from repro.core import (ColorConfig, Graph, PipelineConfig, RecolorConfig,
-                        arc_sim, check_coloring, color_graph_sim,
+                        arc_sim, check_coloring, color_graph,
                         colors_from_views, compute_order, ordering,
-                        partition_graph, pipeline_sim, recolor_iterations,
-                        recolor_sim, rmat)
-from repro.core.comm import AxisComm, run_sim
+                        partition_graph, run_pipeline, recolor_iterations,
+                        recolor_step, rmat)
+from repro.core.comm import AxisComm, run_spmd
 from repro.core.recolor import class_sizes
 
 MC = 512
@@ -31,7 +31,7 @@ def _graph():
 
 
 def _host_reference(pg, order, ccfg, rcfg, n_iters, **sched):
-    view, cstats = color_graph_sim(pg, order, ccfg)
+    view, cstats = color_graph(pg, order, ccfg)
     view, hist = recolor_iterations(pg, np.asarray(view), n_iters, rcfg,
                                     fused=False, **sched)
     return np.asarray(view), cstats, hist
@@ -41,7 +41,7 @@ def _assert_pipeline_equals_host(pg, order, ccfg, rcfg, n_iters, **sched):
     v_host, _, hist_host = _host_reference(pg, order, ccfg, rcfg, n_iters,
                                            **sched)
     pcfg = PipelineConfig(color=ccfg, recolor=rcfg, n_iters=n_iters, **sched)
-    v_fused, res = pipeline_sim(pg, order, pcfg)
+    v_fused, res = run_pipeline(pg, order, pcfg)
     np.testing.assert_array_equal(np.asarray(v_fused), v_host)
     assert res["n_iters_run"] == n_iters
     assert res["history"] == hist_host        # every stat, every iteration
@@ -84,7 +84,7 @@ def test_recolor_iterations_fused_wrapper_bitwise():
     """The default (fused) recolor_iterations == its own host loop."""
     pg = partition_graph(_graph(), 4)
     order = compute_order(pg, ordering.NATURAL)
-    view, _ = color_graph_sim(pg, order, ColorConfig(**CCFG))
+    view, _ = color_graph(pg, order, ColorConfig(**CCFG))
     rcfg = RecolorConfig(max_colors=MC)
     kw = dict(base_perm="nd", rand_every=3, seed=5)
     v_host, h_host = recolor_iterations(pg, np.asarray(view), 6, rcfg,
@@ -101,7 +101,7 @@ def test_adaptive_stop_fires_on_plateau():
     pcfg = PipelineConfig(color=ColorConfig(**CCFG),
                           recolor=RecolorConfig(max_colors=MC),
                           n_iters=16, base_perm="nd", patience=2)
-    view, res = pipeline_sim(pg, order, pcfg)
+    view, res = run_pipeline(pg, order, pcfg)
     assert res["n_iters_run"] < 16
     assert len(res["history"]) == res["n_iters_run"]
     cs = [h["n_colors_distinct"] for h in res["history"]]
@@ -111,7 +111,7 @@ def test_adaptive_stop_fires_on_plateau():
     pcfg_full = PipelineConfig(color=ColorConfig(**CCFG),
                                recolor=RecolorConfig(max_colors=MC),
                                n_iters=16, base_perm="nd")
-    _, res_full = pipeline_sim(pg, order, pcfg_full)
+    _, res_full = run_pipeline(pg, order, pcfg_full)
     assert res["history"] == res_full["history"][: res["n_iters_run"]]
 
 
@@ -123,7 +123,7 @@ def test_pipeline_smoke_rmat_adaptive():
     pcfg = PipelineConfig(color=ColorConfig(max_colors=1024, superstep=64),
                           recolor=RecolorConfig(max_colors=1024),
                           n_iters=4, patience=2)
-    view, res = pipeline_sim(pg, order, pcfg)
+    view, res = run_pipeline(pg, order, pcfg)
     colors = colors_from_views(pg, np.asarray(view))
     st = check_coloring(g, colors)
     assert st["valid"], st
@@ -147,7 +147,7 @@ def test_pipeline_partial_marked():
         color=ColorConfig(max_colors=MC, superstep=64, tile=16,
                           max_rounds=256, distance=2, partial=True),
         recolor=RecolorConfig(max_colors=MC, distance=2), n_iters=2)
-    view, res = pipeline_sim(pg, order, pcfg, marked=marked)
+    view, res = run_pipeline(pg, order, pcfg, marked=marked)
     colors = colors_from_views(pg, np.asarray(view))
     assert (colors[~marked_g] == 0).all()
     chk = check_coloring(g, colors, distance=2, marked=marked_g)
@@ -177,7 +177,7 @@ def test_check_coloring_gapfree_unchanged():
     g = _graph()
     pg = partition_graph(g, 4)
     order = compute_order(pg, ordering.NATURAL)
-    view, stats = color_graph_sim(pg, order, ColorConfig(**CCFG))
+    view, stats = color_graph(pg, order, ColorConfig(**CCFG))
     st = check_coloring(g, colors_from_views(pg, np.asarray(view)))
     assert st["n_colors"] == st["max_color_id"] == stats["n_colors"]
     assert stats["n_colors_distinct"] == st["n_colors"]
@@ -189,7 +189,7 @@ def test_color_stats_distinct_on_staggered_gaps():
     g = _graph()
     pg = partition_graph(g, 4)
     order = compute_order(pg, ordering.NATURAL)
-    view, stats = color_graph_sim(
+    view, stats = color_graph(
         pg, order, ColorConfig(max_colors=MC, superstep=64,
                                selection="staggered", seed=0))
     st = check_coloring(g, colors_from_views(pg, np.asarray(view)))
@@ -202,9 +202,9 @@ def test_class_sizes_masks_out_of_range():
     """A poisoned view must not inflate the last class (clip-mode scatter)."""
     mc, n_local, n_local_max = 32, 6, 8
     view = np.array([1, 1, mc + 7, -3, 2, mc - 1, 0, 0, 0], np.int32)
-    fn = lambda v: class_sizes(v, np.int32(n_local), n_local_max, mc,
-                               AxisComm())
-    sizes, n_oor = run_sim(fn, 1, (view[None],))
+    fn = lambda v, axis, lane_axes: class_sizes(
+        v, np.int32(n_local), n_local_max, mc, AxisComm(axis, lane_axes))
+    sizes, n_oor = run_spmd(fn, None, 1, (view[None],))
     sizes = np.asarray(sizes)[0]
     assert int(n_oor[0]) == 2                      # mc+7 and -3
     assert sizes[mc - 1] == 1                      # NOT silently 3
@@ -215,15 +215,15 @@ def test_class_sizes_masks_out_of_range():
 def test_recolor_out_of_range_stat_surfaces():
     pg = partition_graph(_graph(), 2)
     order = compute_order(pg, ordering.NATURAL)
-    view, _ = color_graph_sim(pg, order, ColorConfig(**CCFG))
+    view, _ = color_graph(pg, order, ColorConfig(**CCFG))
     poisoned = np.asarray(view).copy()
     poisoned[0, 0] = MC + 5
-    _, st = recolor_sim(pg, poisoned, "nd", RecolorConfig(max_colors=MC),
-                        key=jax.random.key(0))
+    _, st = recolor_step(pg, poisoned, "nd", RecolorConfig(max_colors=MC),
+                         key=jax.random.key(0))
     assert st["n_out_of_range"] == 1
-    _, st_ok = recolor_sim(pg, np.asarray(view), "nd",
-                           RecolorConfig(max_colors=MC),
-                           key=jax.random.key(0))
+    _, st_ok = recolor_step(pg, np.asarray(view), "nd",
+                            RecolorConfig(max_colors=MC),
+                            key=jax.random.key(0))
     assert st_ok["n_out_of_range"] == 0
 
 
@@ -231,16 +231,16 @@ def test_back_to_back_rand_iterations_differ():
     """Two manual RAND calls without keys must not replay one permutation."""
     pg = partition_graph(_graph(), 4)
     order = compute_order(pg, ordering.NATURAL)
-    view, _ = color_graph_sim(pg, order, ColorConfig(**CCFG))
+    view, _ = color_graph(pg, order, ColorConfig(**CCFG))
     cfg = RecolorConfig(max_colors=MC)
-    v1, _ = recolor_sim(pg, np.asarray(view), "rand", cfg)
-    v2, _ = recolor_sim(pg, np.asarray(view), "rand", cfg)
+    v1, _ = recolor_step(pg, np.asarray(view), "rand", cfg)
+    v2, _ = recolor_step(pg, np.asarray(view), "rand", cfg)
     assert (np.asarray(v1) != np.asarray(v2)).any()
     # explicit keys stay fully reproducible
-    v3, _ = recolor_sim(pg, np.asarray(view), "rand", cfg,
-                        key=jax.random.key(3))
-    v4, _ = recolor_sim(pg, np.asarray(view), "rand", cfg,
-                        key=jax.random.key(3))
+    v3, _ = recolor_step(pg, np.asarray(view), "rand", cfg,
+                         key=jax.random.key(3))
+    v4, _ = recolor_step(pg, np.asarray(view), "rand", cfg,
+                         key=jax.random.key(3))
     np.testing.assert_array_equal(np.asarray(v3), np.asarray(v4))
 
 
@@ -249,7 +249,7 @@ def test_arc_back_to_back_differs_and_explicit_key_reproduces():
     split — Random-X makes the repair stream observable in the output."""
     pg = partition_graph(_graph(), 4)
     order = compute_order(pg, ordering.NATURAL)
-    view, _ = color_graph_sim(pg, order, ColorConfig(**CCFG))
+    view, _ = color_graph(pg, order, ColorConfig(**CCFG))
     rcfg = RecolorConfig(max_colors=MC)
     scfg = ColorConfig(max_colors=MC, superstep=64, selection="random_x",
                        random_x=10)

@@ -14,7 +14,7 @@ from repro.core import (ColorConfig, PipelineConfig, RecolorConfig,
                         compute_order, partition_graph, plan_signature,
                         program_cache_clear, program_cache_contains,
                         program_cache_stats, rmat)
-from repro.core.pipeline import pipeline_sim
+from repro.core.pipeline import run_pipeline
 from repro.launch.serve_coloring import ColoringService, ServeConfig
 
 P = 4
@@ -60,8 +60,8 @@ def test_pipeline_sim_repeat_is_cache_hit():
     cfg = _cfg()
     order = compute_order(pg, "internal_first")
     program_cache_clear()
-    v1, _ = pipeline_sim(pg, order, cfg)
-    v2, _ = pipeline_sim(pg, order, cfg)
+    v1, _ = run_pipeline(pg, order, cfg)
+    v2, _ = run_pipeline(pg, order, cfg)
     st = program_cache_stats()
     assert (st["misses"], st["hits"], st["traces"]) == (1, 1, 1)
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))

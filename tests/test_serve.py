@@ -3,7 +3,7 @@
 The acceptance property: each graph of a batch — padded into its shape
 bucket and run on the bucket's shared (union) sparse round schedule — must
 be *bitwise identical*, view and every per-iteration stat including
-measured ``wire_bytes``, to a solo ``pipeline_sim`` run of the same padded
+measured ``wire_bytes``, to a solo ``run_pipeline`` run of the same padded
 member under its own comm plan with the same per-graph keys.  Swept across
 bucket boundaries, both exchange schemes, distance 1 and 2, randomized
 selection, and the per-graph adaptive stop (lanes stopping at different
@@ -16,7 +16,7 @@ import pytest
 from repro.core import (ColorConfig, PipelineConfig, RecolorConfig,
                         bucket_graphs, check_coloring, color_many,
                         compute_order, ordering, pad_partition,
-                        partition_graph, pipeline_sim, rmat)
+                        partition_graph, run_pipeline, rmat)
 from repro.launch.serve_coloring import (ColoringService, FakeClock,
                                          ServeConfig, default_config)
 
@@ -36,12 +36,12 @@ def _solo_keys(cfg, gi):
 
 
 def _assert_matches_solo(pgs, cfg, res, order_kind):
-    """Every batch lane == pipeline_sim on its padded member (own plan)."""
+    """Every batch lane == run_pipeline on its padded member (own plan)."""
     for bucket in bucket_graphs(pgs):
         for j, gi in enumerate(bucket.indices):
             m = bucket.members[j]
             ck, rk = _solo_keys(cfg, gi)
-            v, solo = pipeline_sim(m, compute_order(m, order_kind), cfg,
+            v, solo = run_pipeline(m, compute_order(m, order_kind), cfg,
                                    color_key=ck, recolor_key=rk)
             np.testing.assert_array_equal(res[gi]["view"], np.asarray(v))
             assert res[gi]["history"] == solo["history"]
@@ -126,7 +126,7 @@ def test_pad_partition_preserves_coloring():
         recolor=RecolorConfig(max_colors=MC, scheme="sparse"), n_iters=2)
     outs = []
     for q in (pg, padded):
-        v, r = pipeline_sim(q, compute_order(q, ordering.NATURAL), cfg)
+        v, r = run_pipeline(q, compute_order(q, ordering.NATURAL), cfg)
         colors = q.gather_global_colors(np.asarray(v)[:, :q.n_local_max])
         outs.append((colors, r))
     np.testing.assert_array_equal(outs[0][0], outs[1][0])

@@ -3,7 +3,7 @@
 The core invariant (the PR 5 lane-equality pin extended to the serving
 engine): under *arbitrary* admission interleavings — scripted or random
 arrivals, any lanes/chunk/SLO settings — every accepted job's engine
-result is bitwise-equal to a solo ``pipeline_sim`` run of the same
+result is bitwise-equal to a solo ``run_pipeline`` run of the same
 engine-padded member with the same request-id-folded keys.  Plus: future
 semantics, SLO shed / queue-bound determinism, per-lane fault isolation,
 stats-counter consistency, and retrace stability across scripts.
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.core import (ColorConfig, Graph, PipelineConfig, RecolorConfig,
-                        compute_order, pipeline_sim, program_cache_stats,
+                        compute_order, run_pipeline, program_cache_stats,
                         rmat)
 from repro.launch.serve_coloring import (ColoringService, FakeClock,
                                          JobError, ServeConfig, ShedError)
@@ -57,7 +57,7 @@ def _svc(cfg=None, *, validate=True, **serve_kw) -> ColoringService:
 
 
 def _assert_bitwise(svc: ColoringService, results: dict) -> int:
-    """Every engine-route result == solo pipeline_sim of its padded member
+    """Every engine-route result == solo run_pipeline of its padded member
     (same folded keys, same resolved config) — views, colors, history and
     iteration counts all bitwise."""
     n = 0
@@ -67,7 +67,7 @@ def _assert_bitwise(svc: ColoringService, results: dict) -> int:
         m, rcfg = r["member"], r["cfg"]
         ck = jax.random.fold_in(jax.random.key(rcfg.color.seed), jid)
         rk = jax.random.fold_in(jax.random.key(rcfg.seed), jid)
-        view, solo = pipeline_sim(m, compute_order(m, svc.order_kind), rcfg,
+        view, solo = run_pipeline(m, compute_order(m, svc.order_kind), rcfg,
                                   color_key=ck, recolor_key=rk)
         colors = m.gather_global_colors(
             np.asarray(view)[:, :m.n_local_max])

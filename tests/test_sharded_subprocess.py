@@ -27,24 +27,23 @@ def test_sharded_coloring_equals_sim():
     print(run_sub("""
         import numpy as np, jax
         from repro.core import (rmat, partition_graph, compute_order,
-                                ColorConfig, color_graph_sim,
-                                color_graph_sharded, RecolorConfig,
-                                recolor_sim, recolor_sharded,
-                                colors_from_views, assert_valid, ordering)
+                                ColorConfig, color_graph, RecolorConfig,
+                                recolor_step, colors_from_views,
+                                assert_valid, ordering)
         from jax.sharding import AxisType
         g = rmat.grid2d(32, 32, 9)
         pg = partition_graph(g, 8)
         order = compute_order(pg, ordering.SMALLEST_LAST)
         cfg = ColorConfig(max_colors=64, superstep=64)
-        v_sim, s_sim = color_graph_sim(pg, order, cfg)
+        v_sim, s_sim = color_graph(pg, order, cfg)
         mesh = jax.make_mesh((8,), ("workers",), axis_types=(AxisType.Auto,))
-        v_sh, s_sh = color_graph_sharded(pg, order, cfg, mesh)
+        v_sh, s_sh = color_graph(pg, order, cfg, mesh=mesh)
         assert (np.asarray(v_sim) == np.asarray(v_sh)).all(), "views differ"
         rcfg = RecolorConfig(max_colors=64)
         key = jax.random.key(5)
-        r_sim, _ = recolor_sim(pg, np.asarray(v_sim), "nd", rcfg, key=key)
-        r_sh, _ = recolor_sharded(pg, np.asarray(v_sh), "nd", rcfg, mesh,
-                                  key=key)
+        r_sim, _ = recolor_step(pg, np.asarray(v_sim), "nd", rcfg, key=key)
+        r_sh, _ = recolor_step(pg, np.asarray(v_sh), "nd", rcfg, key=key,
+                               mesh=mesh)
         assert (np.asarray(r_sim) == np.asarray(r_sh)).all(), "rc differs"
         assert_valid(g, colors_from_views(pg, np.asarray(r_sh)))
         print("sharded == sim OK")
@@ -58,8 +57,7 @@ def test_color_many_sharded_equals_sim():
     print(run_sub("""
         import numpy as np, jax
         from repro.core import (rmat, partition_graph, ColorConfig,
-                                RecolorConfig, PipelineConfig, color_many,
-                                color_many_sharded)
+                                RecolorConfig, PipelineConfig, color_many)
         from jax.sharding import AxisType
         graphs = [rmat.rmat_good(6, 8, seed=1), rmat.rmat_bad(6, 8, seed=2),
                   rmat.grid2d(16, 16, 9)]
@@ -69,7 +67,7 @@ def test_color_many_sharded_equals_sim():
                              n_iters=3, patience=1)
         sim = color_many(pgs, cfg)
         mesh = jax.make_mesh((8,), ("workers",), axis_types=(AxisType.Auto,))
-        sh = color_many_sharded(pgs, cfg, mesh)
+        sh = color_many(pgs, cfg, mesh)
         for a, b in zip(sim, sh):
             assert np.array_equal(a["view"], b["view"]), "views differ"
             assert np.array_equal(a["colors"], b["colors"])

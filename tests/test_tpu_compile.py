@@ -18,8 +18,9 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import ordering, partition_graph, presets, rmat
-from repro.core.pipeline import pipeline_sim_program, program_cache_clear
+from repro.core import (ordering, partition_graph, presets,
+                        program_cache_clear, rmat)
+from repro.core.pipeline import pipeline_program
 from repro.kernels import ops
 
 HBM_BYTES = 16 * 10**9       # one v5e chip
@@ -122,7 +123,7 @@ def test_conflict_kernel_compiles_narrow(chip_compile, width):
 
 
 def test_fused_pipeline_compiles(chip_compile):
-    """The whole ``pipeline_sim`` program (speculative Random-X coloring +
+    """The whole ``run_pipeline`` program (speculative Random-X coloring +
     K fused ND recoloring iterations, P=4 vmapped shards) on the kernels."""
     g = rmat.rmat_good(14, 8, seed=0)
     pg = partition_graph(g, 4)
@@ -134,7 +135,7 @@ def test_fused_pipeline_compiles(chip_compile):
     order = np.asarray(ordering.compute_order(pg, preset.ordering))
     program_cache_clear()
     try:
-        fn, args = pipeline_sim_program(pg, order, cfg)
+        fn, args = pipeline_program(pg, order, cfg)
         chip_compile(fn, *args)
     finally:
         program_cache_clear()    # the program was traced for the chip

@@ -19,8 +19,8 @@ import pytest
 from repro import tracing
 from repro.core import (ColorConfig, PipelineConfig, RecolorConfig,
                         compute_order, ordering, partition_graph,
-                        pipeline_sim, rmat)
-from repro.core.pipeline import pipeline_sim_program
+                        run_pipeline, rmat)
+from repro.core.pipeline import pipeline_program
 
 
 def _cfg(scheme, distance=1, backend="xla"):
@@ -48,7 +48,7 @@ def _components(op_name: str) -> set:
 @pytest.mark.parametrize("scheme", ["sparse", "allgather"])
 def test_lowered_program_carries_scopes(scheme):
     pg, order = _prepare()
-    fn, args = pipeline_sim_program(pg, order, _cfg(scheme))
+    fn, args = pipeline_program(pg, order, _cfg(scheme))
     lowered = fn.lower(*args)
     hlo = lowered.as_text(dialect="hlo", debug_info=True)
     found = set().union(*map(_components,
@@ -70,7 +70,7 @@ def test_two_hop_tiles_gather_in_nbr_gather():
     and stays in ``recolor``'s own time, as the CSR scan does at distance 1.
     """
     pg, order = _prepare(2)
-    fn, args = pipeline_sim_program(pg, order, _cfg("sparse", 2))
+    fn, args = pipeline_program(pg, order, _cfg("sparse", 2))
     hlo = fn.lower(*args).as_text(dialect="hlo", debug_info=True)
     tiles = [op for dims, op in re.findall(
         r'= \w+\[([\d,]+)\][^ ]* gather\(.*op_name="([^"]*)"', hlo)
@@ -115,11 +115,11 @@ def _parents(spans, child) -> set:
 def test_host_spans_nest(tmp_path):
     g = rmat.rmat_good(8, 8, seed=3)
     cfg = _cfg("sparse")
-    pipeline_sim(*_prepare(), cfg)                  # compiles outside
+    run_pipeline(*_prepare(), cfg)                  # compiles outside
     with jax.profiler.trace(str(tmp_path)):
         pg = partition_graph(g, 4, seed=12)
         order = compute_order(pg, ordering.INTERNAL_FIRST)
-        view, _ = pipeline_sim(pg, order, cfg)
+        view, _ = run_pipeline(pg, order, cfg)
         pg.gather_global_colors(np.asarray(view)[:, :pg.n_local_max])
     spans = _host_spans(tmp_path)
     names = [n for *_, n in spans]
@@ -176,7 +176,7 @@ COLORS_PIN = {
 @pytest.mark.parametrize("distance,scheme,backend", list(COLORS_PIN))
 def test_colors_bitwise_as_recorded(distance, scheme, backend):
     pg, order = _prepare(distance)
-    view, _ = pipeline_sim(pg, order, _cfg(scheme, distance, backend))
+    view, _ = run_pipeline(pg, order, _cfg(scheme, distance, backend))
     colors = pg.gather_global_colors(np.asarray(view)[:, :pg.n_local_max])
     digest = hashlib.sha256(colors.astype(np.int32).tobytes()).hexdigest()
     assert digest[:16] == COLORS_PIN[(distance, scheme, backend)]

@@ -17,7 +17,7 @@ import pytest
 from repro.core import ColorConfig, RecolorConfig, partition_graph, rmat
 from repro.core.comm import (AxisComm, CommConfig,
                              allgather_bytes_per_exchange, make_exchange,
-                             run_sim)
+                             run_spmd)
 
 P = 4
 
@@ -33,13 +33,13 @@ def _measure(pg, scheme, round_mask):
     views = jnp.ones((P, pg.n_slots), jnp.int32)
     mask = None if round_mask is None else jnp.asarray(round_mask)
 
-    def fn(a, v):
-        ex = make_exchange(a, pg.n_local_max, P, AxisComm(),
+    def fn(a, v, axis, lane_axes):
+        ex = make_exchange(a, pg.n_local_max, P, AxisComm(axis, lane_axes),
                            CommConfig(scheme=scheme), pg.comm_plan.static)
         _, b = ex(v, round_mask=mask)
         return b
 
-    out = jax.jit(lambda a, v: run_sim(fn, P, (a, v)))(arrs, views)
+    out = jax.jit(lambda a, v: run_spmd(fn, None, P, (a, v)))(arrs, views)
     out = np.asarray(out)
     assert (out == out[0]).all()          # shard-uniform by construction
     return int(out[0])
@@ -80,7 +80,7 @@ def test_quantized_plan_bitwise_equals_exact_plan(pgraph):
 
     from repro.core import build_comm_plan, compute_order
     from repro.core.graph import _ceil_pow2
-    from repro.core.pipeline import PipelineConfig, pipeline_sim
+    from repro.core.pipeline import PipelineConfig, run_pipeline
 
     plan_q = pgraph.comm_plan                      # quantized by default
     plan_e = build_comm_plan(pgraph, quantize=False)
@@ -103,8 +103,8 @@ def test_quantized_plan_bitwise_equals_exact_plan(pgraph):
         recolor=RecolorConfig(max_colors=64, scheme="sparse"),
         n_iters=2, patience=0)
     order = compute_order(pgraph, "internal_first")
-    v_q, res_q = pipeline_sim(pgraph, order, cfg)
-    v_e, res_e = pipeline_sim(pg_e, order, cfg)
+    v_q, res_q = run_pipeline(pgraph, order, cfg)
+    v_e, res_e = run_pipeline(pg_e, order, cfg)
     np.testing.assert_array_equal(np.asarray(v_q), np.asarray(v_e))
     assert res_q["color"]["wire_bytes"] == res_e["color"]["wire_bytes"]
     assert res_q["history"] == res_e["history"]    # every stat, bitwise
